@@ -8,7 +8,7 @@ disk parses alone) and greps cleanly, like the reference's per-rank
 profiling logs (VGG/allreducer.py:702-703) but machine-readable.
 
 Schema — the first record is always an environment header, so decision
-logs are comparable across containers/relays (the same tuner on jax
+logs are comparable across machines (the same tuner on jax
 0.4.x/CPU vs 0.9/TPU legitimately decides differently); subsequent
 events carry ``event`` and ``step``:
 

@@ -148,9 +148,8 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     # over (grad, residual) yields acc, the staging rows, the realised and
     # Newton-probe counts, and the threshold histogram — replacing the
     # separate add_residual / abs / mask / count / probe / pack passes
-    # below. The unfused path stays as the bit-parity oracle
-    # (tests/test_fused_select.py) and bench.py's degradation rung
-    # (cfg.fuse_select=False -> `oktopk_fused_failed`).
+    # below. The unfused path (cfg.fuse_select=False) stays as the
+    # bit-parity oracle (tests/test_fused_select.py).
     fuse = (up and cfg.fuse_select is not False
             and grad.dtype == jnp.float32)
     if not fuse:

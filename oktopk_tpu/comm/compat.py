@@ -1,87 +1,31 @@
-"""jax version compatibility seam.
+"""The jax spellings the package is written against, in one place.
 
-The framework is written against the current jax API (``jax.shard_map``,
-varying-manual-axes types via ``jax.typeof(x).vma`` / ``lax.pvary``,
-``lax.axis_size``). Older jax releases (0.4.x) expose the same machinery
-under different names — ``jax.experimental.shard_map.shard_map`` with a
-``check_rep`` flag instead of ``check_vma``, no VMA type tracking at all —
-so every use of a moved/renamed symbol goes through this module. Each
-helper resolves the capability once at import time; callers never branch
-on the jax version themselves.
-
-On pre-VMA jax the vma helpers degrade to inert values (``frozenset()`` /
-identity): the VMA discipline is a static type check, not a semantic
-transform, so dropping it preserves results. ``shard_map`` likewise maps
-``check_vma`` onto ``check_rep=False`` there — 0.4.x's replication checker
-predates the pvary-based typing discipline the algorithms are written
-with and rejects valid programs (e.g. ``lax.cond`` branches whose
-replication it cannot prove).
+The installation is jax 0.9.0: ``jax.shard_map``, varying-manual-axes types
+(``jax.typeof(x).vma``, ``lax.pcast``) and ``lax.axis_size`` all exist, so
+these are one-line names for them — kept because ~50 call sites use them,
+not because any of them branches on the jax version.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax import lax
 
-HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pvary")
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions (new kwarg ``check_vma``,
-    old ``jax.experimental.shard_map.shard_map`` kwarg ``check_rep``)."""
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # check_rep=False unconditionally: 0.4.x's static replication checker
-    # predates the pvary typing discipline (inert here) and rejects valid
-    # programs (e.g. the seq/pipe composition steps' out_specs). The cost
-    # is that loss-psum gradient transposes lose their replication
-    # bookkeeping on 0.4.x — the composed-mesh equivalence tests that
-    # compare such gradients against oracles document this (see
-    # ROADMAP.md open item "jax-version compat").
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+shard_map = jax.shard_map
+axis_size = lax.axis_size
 
 
 def typeof_vma(x) -> frozenset:
-    """The varying-manual-axes set of ``x``'s type (empty on pre-VMA jax,
-    or for non-traced values whose type carries no vma)."""
-    if not HAS_VMA:
-        return frozenset()
-    try:
-        return frozenset(jax.typeof(x).vma)
-    except Exception:
-        return frozenset()
+    """The varying-manual-axes set of ``x``'s type (empty outside
+    shard_map, and for concrete values)."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def pvary(x, axes):
-    """``lax.pvary`` where it exists; identity otherwise (pre-VMA jax has
-    no varying/invariant distinction to adjust)."""
+    """Mark ``x`` as varying over ``axes`` (no-op for an empty set)."""
     axes = tuple(axes)
-    if not axes:
-        return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if HAS_VMA:
-        return lax.pvary(x, axes)
-    return x
+    return lax.pcast(x, axes, to="varying") if axes else x
 
 
 def shape_dtype_struct(shape, dtype, vma=None) -> jax.ShapeDtypeStruct:
-    """``jax.ShapeDtypeStruct`` with the ``vma`` type argument when this
-    jax supports it (pre-VMA signatures reject the kwarg)."""
-    if HAS_VMA and vma is not None:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` across versions. ``lax.psum`` of a Python literal
-    is evaluated statically, so both forms give a concrete int usable to
-    build ppermute tables at trace time."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -155,9 +155,8 @@ class OkTopkConfig:
     # separate add_residual/abs/mask/count/probe/pack passes of
     # collectives/oktopk.py. None = auto (on whenever the Pallas backend
     # is active); False = force the unfused per-pass path (the parity
-    # oracle, and bench.py's degradation rung when the fused kernel fails
-    # to compile — `oktopk_fused_failed`); True = same as None (the kernel
-    # still requires use_pallas; it cannot run on the portable path).
+    # oracle); True = same as None (the kernel still requires use_pallas;
+    # it cannot run on the portable path).
     # oktopk only; f32 gradients only (as all Pallas selection paths).
     fuse_select: Optional[bool] = None
 

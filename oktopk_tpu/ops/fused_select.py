@@ -157,6 +157,7 @@ def _run_fused_stage(gp, rp, t, tp, rng, capb, nblocks, interpret, vma):
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="oktopk_fused_select",
     )(t, tp, rng, gp, rp)
     raw = cr[:, 0]
     hist = jnp.sum(h, axis=0).astype(jnp.int32)

@@ -25,7 +25,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from oktopk_tpu.collectives.registry import get_algorithm
 from oktopk_tpu.collectives.state import SparseState, init_state
@@ -149,6 +149,32 @@ def init_dist_state(params, model_state, optimizer, cfg: OkTopkConfig,
                                      if opt_state is None else opt_state),
                           sparse_state=s, local_momentum=mom,
                           health=health, quality=qual)
+
+
+def dist_state_specs(axis_name: str, momentum_correction: bool,
+                     has_health: bool, has_quality: bool) -> DistTrainState:
+    """How the step shards a :class:`DistTrainState`: per-worker leaves over
+    ``axis_name``, everything else replicated."""
+    return DistTrainState(
+        params=P(), model_state=P(), opt_state=P(),
+        sparse_state=P(axis_name),
+        local_momentum=P(axis_name) if momentum_correction else None,
+        health=P() if has_health else None,
+        quality=P(axis_name) if has_quality else None)
+
+
+def place_dist_state(state: DistTrainState, mesh: Mesh,
+                     axis_name: str = "data") -> DistTrainState:
+    """Put a fresh state where the step will leave it. ``init_dist_state``
+    builds every leaf on the default device — all P residual rows on chip
+    0 — and a step first called on that layout is compiled for it, then
+    compiled again for the sharded state it returned."""
+    specs = dist_state_specs(axis_name, state.local_momentum is not None,
+                             state.health is not None,
+                             state.quality is not None)
+    return jax.device_put(state, jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec), specs,
+        is_leaf=lambda x: isinstance(x, P)))
 
 
 def build_sparse_grad_step(
@@ -487,12 +513,8 @@ def build_sparse_grad_step(
             quality=quality_out)
         return new_state, metrics
 
-    state_specs = DistTrainState(
-        params=P(), model_state=P(), opt_state=P(),
-        sparse_state=P(axis_name),
-        local_momentum=P(axis_name) if momentum_correction else None,
-        health=P() if has_health else None,
-        quality=P(axis_name) if has_quality else None)
+    state_specs = dist_state_specs(axis_name, bool(momentum_correction),
+                                   has_health, has_quality)
     mapped = compat.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(state_specs, P(axis_name), P()),

@@ -3,7 +3,7 @@
 Same shape (and writer) as the autotuner's decision journal
 (``autotune/journal.py``): line-delimited JSON, append-only, one
 environment header record first so logs are comparable across
-containers/relays. Events (all carry ``event`` and ``step``):
+machines. Events (all carry ``event`` and ``step``):
 
   {"event": "header", "jax": "0.4.37", "jaxlib": ..., "device_kind": ...,
    "platform": "cpu", "world_size": 8}

@@ -107,7 +107,10 @@ def main(argv=None):
     from oktopk_tpu.config import OkTopkConfig, TrainConfig
     from oktopk_tpu.data import make_dataset
     from oktopk_tpu.train.trainer import Trainer
+    from oktopk_tpu.utils.compile_cache import ensure_compile_cache
     from oktopk_tpu.utils.logging import get_logger
+
+    ensure_compile_cache()
 
     if args.pipeline_stages > 1:
         return run_pipeline(args)

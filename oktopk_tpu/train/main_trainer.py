@@ -195,7 +195,10 @@ def main(argv=None):
     from oktopk_tpu.config import OkTopkConfig, TrainConfig
     from oktopk_tpu.data import make_dataset
     from oktopk_tpu.train.trainer import Trainer
+    from oktopk_tpu.utils.compile_cache import ensure_compile_cache
     from oktopk_tpu.utils.logging import get_logger
+
+    cache_dir = ensure_compile_cache()
 
     cfg = TrainConfig(
         dnn=args.dnn, dataset=args.dataset, batch_size=args.batch_size,
@@ -249,6 +252,7 @@ def main(argv=None):
         "oktopk_tpu",
         os.path.join(args.logdir, slug, f"rank{jax.process_index()}.log"))
     logger.info("experiment %s on %d devices", slug, len(jax.devices()))
+    logger.info("compile cache: %s", cache_dir)
 
     algo_cfg = OkTopkConfig(sigma_scale=args.sigma_scale,
                             wire_dtype=args.wire_dtype)

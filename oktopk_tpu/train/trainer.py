@@ -33,6 +33,7 @@ from oktopk_tpu.optim.distributed import (
     build_sparse_grad_step,
     flat_size,
     init_dist_state,
+    place_dist_state,
 )
 from oktopk_tpu.train import losses
 from oktopk_tpu.comm.mesh import get_mesh
@@ -219,12 +220,12 @@ class Trainer:
         self.retune_events = 0     # forced re-calibrations executed
         self._fake_ms = None       # remembered trial-timing injector
 
-        self.state = init_dist_state(
+        self.state = place_dist_state(init_dist_state(
             params, self.model_state, self.optimizer, self.algo_cfg,
             momentum_correction=bool(self._mc_factor),
             num_buckets=cfg.num_buckets,
             with_health=self._with_health,
-            quality=self._quality_cfg)
+            quality=self._quality_cfg), self.mesh, axis_name)
         self.autotuner = None      # built lazily by autotune()
         self._plans = None         # per-bucket BucketPlan list, or None
         self.step_fn = self._build_step()
@@ -829,12 +830,12 @@ class Trainer:
             (self.state.params, self.state.model_state, self.state.opt_state))
         old_health = (jax.device_get(self.state.health)
                       if self.state.health is not None else None)
-        self.state = init_dist_state(
+        self.state = place_dist_state(init_dist_state(
             old[0], old[1], self.optimizer, self.algo_cfg,
             momentum_correction=bool(self._mc_factor), opt_state=old[2],
             num_buckets=self.cfg.num_buckets,
             with_health=self._with_health,
-            quality=self._quality_cfg)
+            quality=self._quality_cfg), new_mesh, self.axis_name)
         carried = ["params", "model_state", "opt_state"]
         reinit = ["sparse_state", "local_momentum", "autotuner"]
         if self._quality_cfg is not None:

@@ -9,9 +9,9 @@ density selection (VGG/utils.py:86-134):
             + T_comm(analytic wire bytes, fabric alpha-beta)
 
 Measured inputs (each cited in the output record):
-  * single-chip VGG-16 step times from the newest BENCH_r*.json /
-    logs/bench_capture.json that carries them (dense_ms, oktopk_ms, and
-    their bs-256 variants when present);
+  * single-chip VGG-16 step times from the newest BENCH_r*.json that
+    carries them (dense_ms, oktopk_ms, and their bs-256 variants when
+    present);
   * the oktopk steady-state volume calibration from the same records:
     volume_elems / k at the probe's (n=2^20, d=0.01) operating point —
     the paper's "<6k" property measured on the repo's own collective;
@@ -88,22 +88,6 @@ def load_bench_records():
         except (ValueError, OSError):
             continue
     recs = list(reversed(recs))
-    # the loose in-round capture ranks BELOW every driver-stamped
-    # BENCH_r*.json: the driver writes BENCH_r{N} from bench.py stdout at
-    # round end, strictly after any capture logged during the round — a
-    # stale capture (round 5: portable-path 387 ms vs the official
-    # kernel-path 178 ms) must not shadow the newer official record
-    cap = os.path.join(REPO, "logs", "bench_capture.json")
-    if os.path.exists(cap):
-        try:
-            with open(cap) as f:
-                lines = [ln for ln in f.read().splitlines()
-                         if ln.startswith("{")]
-            if lines:
-                recs.append(("logs/bench_capture.json",
-                             json.loads(lines[-1])))
-        except (ValueError, OSError):
-            pass
     # oldest fallback: the round-3 on-chip session measurements (PERF.md
     # prose, recorded machine-readably with provenance)
     chip = os.path.join(REPO, "logs", "chip_measurements.json")

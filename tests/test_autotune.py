@@ -200,7 +200,7 @@ class TestJournal:
                 json.loads(line)                 # every line parses alone
         entries = read_journal(path)
         # every journal leads with the environment header so decision
-        # logs are comparable across containers/relays
+        # logs are comparable across machines
         assert entries[0]["event"] == "header"
         assert {"jax", "jaxlib", "device_kind", "world_size"} \
             <= set(entries[0])

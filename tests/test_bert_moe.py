@@ -19,16 +19,6 @@ from oktopk_tpu.parallel.bert_moe import (MoEConfig, build_moe_loss,
                                           experts_from_dense, make_moe_mesh)
 from oktopk_tpu.train import losses
 
-# The composed-mesh gradient-equivalence oracles below need shard_map's
-# replication bookkeeping for loss-psum gradient transposes; jax < 0.5
-# runs shard_map with check_rep=False (comm/compat.py) whose old
-# psum-transpose semantics break them — known-red on the 0.4.x
-# container, green on current jax (ROADMAP "jax-version compat").
-_PRE_VMA_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-_PRE_VMA_SKIP = pytest.mark.skipif(
-    _PRE_VMA_JAX,
-    reason="jax < 0.5 shard_map(check_rep=False) psum-transpose semantics")
-
 B, T = 8, 16
 E = 4
 
@@ -208,7 +198,6 @@ class TestMoESparseComposition:
         pstack = (stack_replicas(moe, dp), stack_replicas(shared, dp))
         return step, pstack, sstates, opts, (moe, shared), mcfg, opt
 
-    @_PRE_VMA_SKIP
     def test_dense_composition_matches_expert_only_step(self, cfg, params):
         """Equal per-row mask counts: mean-of-row gradients == global
         gradient, so the composed dense step must land on the same params

@@ -19,16 +19,6 @@ from oktopk_tpu.parallel.bert_pipeline import (build_pipeline_loss,
                                                init_pipeline_opt_state,
                                                make_pipeline_mesh)
 
-# The composed-mesh gradient-equivalence oracles below need shard_map's
-# replication bookkeeping for loss-psum gradient transposes; jax < 0.5
-# runs shard_map with check_rep=False (comm/compat.py) whose old
-# psum-transpose semantics break them — known-red on the 0.4.x
-# container, green on current jax (ROADMAP "jax-version compat").
-_PRE_VMA_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-_PRE_VMA_SKIP = pytest.mark.skipif(
-    _PRE_VMA_JAX,
-    reason="jax < 0.5 shard_map(check_rep=False) psum-transpose semantics")
-
 B, T = 8, 16
 
 
@@ -82,7 +72,6 @@ class TestPipelineEquivalence:
         assert np.isfinite(got)
         np.testing.assert_allclose(got, want, rtol=2e-5)
 
-    @_PRE_VMA_SKIP
     def test_gradients_match_single_module(self, staged, params):
         """Pipeline backward == single-module backward (same math, the
         ppermute/psum transposes must be exact)."""
@@ -183,7 +172,6 @@ class TestPipelineSparseComposition:
         return (step, (pstack, pshared), (stage_ss, shared_ss),
                 opt_states, opt, mesh, M, dp)
 
-    @_PRE_VMA_SKIP
     def test_dense_composition_matches_global_step(self, staged, params):
         """With equal per-example mask counts, mean-of-row-gradients ==
         gradient of the global weighted loss, so the composed dense step

@@ -11,16 +11,6 @@ from oktopk_tpu.models.bert import BertConfig, BertForPreTraining
 from oktopk_tpu.parallel.bert_seq import build_seq_loss, make_seq_mesh
 from oktopk_tpu.train import losses
 
-# The composed-mesh gradient-equivalence oracles below need shard_map's
-# replication bookkeeping for loss-psum gradient transposes; jax < 0.5
-# runs shard_map with check_rep=False (comm/compat.py) whose old
-# psum-transpose semantics break them — known-red on the 0.4.x
-# container, green on current jax (ROADMAP "jax-version compat").
-_PRE_VMA_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-_PRE_VMA_SKIP = pytest.mark.skipif(
-    _PRE_VMA_JAX,
-    reason="jax < 0.5 shard_map(check_rep=False) psum-transpose semantics")
-
 B, T = 4, 32
 
 
@@ -138,7 +128,6 @@ class TestSeqSparseComposition:
         sstate = stack_replicas(init_state(acfg), dp)
         return step, sstate, opt, acfg, dp
 
-    @_PRE_VMA_SKIP
     def test_dense_composition_matches_per_row_oracle(self, cfg, params):
         """compressor='dense': the composed step must equal mean-of-
         per-data-row gradients (each row = the single-module loss on its

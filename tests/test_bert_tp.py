@@ -18,16 +18,6 @@ from oktopk_tpu.parallel.bert_tp import (build_tp_loss,
                                          make_tp_mesh, merge_tp, split_tp)
 from oktopk_tpu.train import losses
 
-# The composed-mesh gradient-equivalence oracles below need shard_map's
-# replication bookkeeping for loss-psum gradient transposes; jax < 0.5
-# runs shard_map with check_rep=False (comm/compat.py) whose old
-# psum-transpose semantics break them — known-red on the 0.4.x
-# container, green on current jax (ROADMAP "jax-version compat").
-_PRE_VMA_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-_PRE_VMA_SKIP = pytest.mark.skipif(
-    _PRE_VMA_JAX,
-    reason="jax < 0.5 shard_map(check_rep=False) psum-transpose semantics")
-
 B, T = 4, 16
 
 
@@ -102,7 +92,6 @@ class TestBertTensorParallel:
                 np.asarray(a), np.asarray(b), atol=5e-5,
                 err_msg=jax.tree_util.keystr(pa))
 
-    @_PRE_VMA_SKIP
     def test_train_step_matches_single_module(self, cfg, params):
         """Two SGD-momentum steps through the TP step == two oracle steps
         on the merged module (elementwise optimizer: sharded moments are
@@ -135,7 +124,6 @@ class TestBertTensorParallel:
                 err_msg=jax.tree_util.keystr(pa))
         assert np.isfinite(float(loss)) and np.isfinite(ref_loss)
 
-    @_PRE_VMA_SKIP
     def test_sparse_dp_tp_full_density_matches_dense_oracle(self, cfg,
                                                             params,
                                                             devices):

@@ -1,8 +1,8 @@
 """Parity tests for the Pallas stream-compaction fast path
 (ops/compaction.py) against the portable ops/select.py implementation.
 
-Runs the kernel in interpret mode on CPU (the real-TPU path is exercised by
-bench.py / scripts/profile_tpu.py on hardware); the contract is identical:
+Runs the kernel in interpret mode on CPU (the compiled path is exercised by
+tests/test_tpu_hw.py and chip_smoke.py on hardware); the contract is identical:
 (values[cap], indices[cap], count), ascending index order, sentinel n,
 overflow dropped lowest-index-first (plus the documented per-block CAPB
 bound)."""
@@ -210,19 +210,21 @@ class TestPackRegionsParity:
         np.testing.assert_array_equal(gv, wv)
 
 
-def _run_oktopk_both_paths(mesh8, cfg0, base, steps):
+def _run_oktopk_both_paths(mesh8, cfg0, base, steps, check_vma=None):
     """Run the full oktopk step for use_pallas False/True on the same data;
-    returns ({use_pallas: [per-step results]}, {use_pallas: final state})."""
+    returns ({use_pallas: [per-step results]}, {use_pallas: final state}).
+    ``check_vma=None``: off for the Pallas path — the interpreter cannot
+    mix VMA-tracked operands (tests/test_tpu_hw.py passes True: compiled
+    through Mosaic it can)."""
     from oktopk_tpu.collectives.api import (batched_init_state,
                                             build_allreduce_step)
 
     outs, states = {}, {}
     for up in (False, True):
         cfg = cfg0.replace(use_pallas=up)
-        # check_vma=False: the Pallas interpreter cannot mix VMA-tracked
-        # operands (real-TPU compiles through Mosaic instead)
-        step = build_allreduce_step("oktopk", cfg, mesh8, warmup=False,
-                                    check_vma=not up)
+        step = build_allreduce_step(
+            "oktopk", cfg, mesh8, warmup=False,
+            check_vma=(not up) if check_vma is None else check_vma)
         state = batched_init_state(cfg)
         rs = []
         for _ in range(steps):

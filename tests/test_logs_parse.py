@@ -21,8 +21,8 @@ def _parses(path: str) -> bool:
         return True
     except ValueError:
         pass
-    # JSONL: every non-empty line parses alone (bench_capture.json and the
-    # autotune decision journals are line-delimited)
+    # JSONL: every non-empty line parses alone (the autotune decision
+    # journals are line-delimited)
     lines = [ln for ln in text.splitlines() if ln.strip()]
     if not lines:
         return False
