@@ -35,7 +35,15 @@ from jax import lax
 
 from oktopk_tpu.collectives.state import SparseState, bump
 from oktopk_tpu.comm import all_gather, all_to_all, axis_rank, psum
-from oktopk_tpu.obs.anatomy import phase_scope
+from oktopk_tpu.obs.anatomy import (
+    SUB_FEEDBACK,
+    SUB_FINALIZE,
+    SUB_GLOBAL,
+    SUB_REPARTITION,
+    SUB_SWEEP,
+    SUB_THRESHOLD,
+    phase_scope,
+)
 from oktopk_tpu.comm.primitives import pvary_like
 from oktopk_tpu.config import OkTopkConfig, scheduled_k
 from oktopk_tpu.ops import (
@@ -153,7 +161,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     fuse = (up and cfg.fuse_select is not False
             and grad.dtype == jnp.float32)
     if not fuse:
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_SWEEP):
             acc = add_residual(grad, state.residual)
             abs_acc = jnp.abs(acc)
 
@@ -200,7 +208,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
             return k2threshold_hist(_abs_acc_branch(),
                                     tkl).astype(grad.dtype)
 
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_THRESHOLD):
             lt = lax.cond(first_sparse, lt_prime,
                           lambda: prev_lt * state.drift)
         drift = state.drift   # re-measured from the histogram below
@@ -232,7 +240,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         def lt_predicted():
             return prev_lt * state.drift, state.drift, state.last_exact_lt
 
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_THRESHOLD):
             lt, drift, last_exact_lt = lax.cond(recompute_local, lt_exact,
                                                 lt_predicted)
 
@@ -244,16 +252,17 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     # finalize — repartition's extra |acc| sweep prices only its cadence.
     repart = (state.step % cfg.repartition_every == 0) | first_sparse
     if fuse:
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_SWEEP):
             st = fused_select_stage(grad, state.residual, lt,
                                     lt * cfg.probe_ratio)
             acc = st.acc
-        with phase_scope("stage", bkt):
+        with phase_scope("stage", bkt, sub=SUB_REPARTITION):
             boundaries = lax.cond(
                 repart,
                 lambda: _repartition(jnp.abs(acc), lt, cfg, axis_name),
                 lambda: state.boundaries)
-            s_vals, s_idx, s_counts = fused_pack_finalize(
+        with phase_scope("stage", bkt, sub=SUB_FINALIZE):
+            s_vals, s_idx, s_counts, branch_a = fused_pack_finalize(
                 st, boundaries, P, cfg.cap_pair)
         local_count = st.local_count
         local_probe = st.probe_count
@@ -266,20 +275,20 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         # the first exact recompute.
         mask = jnp.abs(acc) >= lt
     else:
-        with phase_scope("stage", bkt):
+        with phase_scope("stage", bkt, sub=SUB_REPARTITION):
             boundaries = lax.cond(
                 repart,
                 lambda: _repartition(abs_acc, lt, cfg, axis_name),
                 lambda: state.boundaries)
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_SWEEP):
             mask = abs_acc >= lt
             local_count = jnp.sum(mask)
-        with phase_scope("stage", bkt):
-            s_vals, s_idx, s_counts = pack_by_region(
+        with phase_scope("stage", bkt, sub=SUB_FINALIZE):
+            s_vals, s_idx, s_counts, branch_a = pack_by_region(
                 acc, mask, boundaries, P, cfg.cap_pair, thresh=lt,
-                use_pallas=up)
+                use_pallas=up, with_branch=True)
         # threshold feedback probe (fuses into the same pass over abs_acc)
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_SWEEP):
             local_probe = jnp.sum(abs_acc >= lt * cfg.probe_ratio)
         # "hist" standalone pays its one histogram pass lazily, inside the
         # recompute cond below (the fused kernel emits it for free)
@@ -325,11 +334,11 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
                                   target=tkl),
                     state.drift, state.last_exact_lt)
 
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_FEEDBACK):
             lt_next, drift, last_exact_lt = lax.cond(recompute_local,
                                                      lt_measured, lt_adapted)
     else:
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_FEEDBACK):
             lt_next = _newton_adapt(lt, local_count, local_probe, k, cfg,
                                     target=tkl)
 
@@ -347,18 +356,20 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         # paper's volume O(k), not O(kP)) selected by a sort-free
         # per-region threshold; the k-th value of the gathered pool becomes
         # the new global threshold. No O(n log n) sort anywhere.
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_GLOBAL):
             t_cand = k2threshold_method(jnp.abs(reduced), k_cand,
                                         cfg.threshold_method,
                                         cfg.bisect_iters)
             if up:
                 # the kernel's min-normal clamp already excludes zeros
-                vals, idx, cand_count = select_by_threshold(
-                    reduced, t_cand, k_cand, use_pallas=True)
+                vals, idx, cand_count, branch_b = select_by_threshold(
+                    reduced, t_cand, k_cand, use_pallas=True,
+                    with_branch=True)
             else:
                 cand_mask = (jnp.abs(reduced) >= t_cand) & (reduced != 0.0)
                 vals, idx, cand_count = select_mask(reduced, cand_mask,
                                                     k_cand)
+                branch_b = jnp.zeros((2,), jnp.int32)
         with phase_scope("exchange", bkt):
             gv = all_gather(_on_wire(vals, cfg, state.step), axis_name) \
                 .astype(acc.dtype)                     # [P, k_cand]
@@ -368,7 +379,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         # (count-based, traced-k-capable)
         k_pool = (min(k, P * k_cand) if isinstance(k, int)
                   else jnp.minimum(k, P * k_cand))
-        with phase_scope("select", bkt):
+        with phase_scope("select", bkt, sub=SUB_GLOBAL):
             gt = k2threshold_method(jnp.abs(gv).reshape(-1), k_pool,
                                     cfg.threshold_method,
                                     cfg.bisect_iters).astype(acc.dtype)
@@ -383,7 +394,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         g_count = jnp.sum(keep)
         total_c = psum(cand_count, axis_name)
         vol = 2.0 * cand_count + 2.0 * (total_c - cand_count)
-        return pvary_like((result, gt, g_count, vol), acc)
+        return pvary_like((result, gt, g_count, vol, branch_b), acc)
 
     def predicted_branch():
         # Otherwise: threshold-select own region, fixed-capacity allgather,
@@ -394,9 +405,9 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         # drift rate (see the local-threshold block above) at zero comm
         # cost.
         gt_use = state.global_threshold * drift
-        with phase_scope("select", bkt):
-            gvals, gidx, gcount = select_by_threshold(reduced, gt_use,
-                                                      cap_g, use_pallas=up)
+        with phase_scope("select", bkt, sub=SUB_GLOBAL):
+            gvals, gidx, gcount, branch_b = select_by_threshold(
+                reduced, gt_use, cap_g, use_pallas=up, with_branch=True)
         with phase_scope("exchange", bkt):
             gv = all_gather(_on_wire(gvals, cfg, state.step), axis_name) \
                 .astype(acc.dtype)                     # [P, cap_g]
@@ -416,9 +427,9 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
                                 k, cfg, band_hi=cfg.band_hi_global,
                                 target=_target_k(k, n, cfg.global_k_target))
         vol = 2.0 * gcount + 2.0 * (total_g - gcount)
-        return pvary_like((result, gt_next, total_g, vol), acc)
+        return pvary_like((result, gt_next, total_g, vol, branch_b), acc)
 
-    result, gt_next, g_count, vol_b = lax.cond(
+    result, gt_next, g_count, vol_b, branch_b = lax.cond(
         recompute_global, exact_branch, predicted_branch)
 
     # ---- residual: zero only at indices that made the global result
@@ -443,4 +454,8 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
                         local_threshold=lt_next, global_threshold=gt_next,
                         boundaries=boundaries, drift=drift,
                         last_exact_lt=last_exact_lt,
-                        local_count=local_count, global_count=g_count)
+                        local_count=local_count, global_count=g_count,
+                        # what this step did, in BRANCH_COUNTERS' order
+                        counters=(branch_a[0], branch_a[1], branch_b[0],
+                                  branch_b[1], recompute_local,
+                                  recompute_global, repart))

@@ -17,6 +17,28 @@ import jax.numpy as jnp
 
 from oktopk_tpu.config import OkTopkConfig
 
+# The order of a step's ``metrics["counters"]`` (i32[len(COUNTERS)],
+# replicated like every other metric; what the step did, not how long it
+# took). The first ``len(BRANCH_COUNTERS)`` entries are written per bucket
+# by the collective into ``SparseState.last_counters``;
+# ``optim/distributed.py`` takes the worst branch and the sum of everything
+# else over the buckets, then the largest over the workers (the step is as
+# slow as its slowest worker: one chip in the wide branch holds all of
+# them), and appends the realised counts of the ``local_k``/``global_k``
+# metrics. A branch entry holds the place of its name in ``BRANCHES``
+# (ops/compaction.py); a dense step leaves everything it does not do at 0.
+BRANCHES = ("fast", "repair", "wide")
+BRANCH_COUNTERS = (
+    "stage_branch",            # staging (phase a) overflow dispatch
+    "stage_overflow_blocks",   # blocks over the fast staging width there
+    "select_branch",           # phase-(b) threshold select's dispatch
+    "select_overflow_blocks",  # blocks that mattered there
+    "recompute_local",         # local threshold recomputed exactly
+    "recompute_global",        # phase (b) took the exact branch
+    "repartition",             # region boundaries recomputed
+)
+COUNTERS = BRANCH_COUNTERS + ("local_k", "global_k")
+
 
 @flax.struct.dataclass
 class SparseState:
@@ -62,6 +84,8 @@ class SparseState:
     # settings.PROFILING, VGG/allreducer.py:702-703)
     last_local_count: jnp.ndarray     # i32
     last_global_count: jnp.ndarray    # i32
+    # what the last step did: i32[len(BRANCH_COUNTERS)], in that order
+    last_counters: jnp.ndarray
 
 
 def init_state(cfg: OkTopkConfig, dtype=jnp.float32) -> SparseState:
@@ -92,15 +116,18 @@ def init_state(cfg: OkTopkConfig, dtype=jnp.float32) -> SparseState:
         last_wire_bytes_inter=jnp.asarray(0.0, jnp.float32),
         last_local_count=jnp.asarray(0, jnp.int32),
         last_global_count=jnp.asarray(0, jnp.int32),
+        last_counters=jnp.zeros((len(BRANCH_COUNTERS),), jnp.int32),
     )
 
 
 def bump(state: SparseState, *, volume, wire_bytes=None, local_count=None,
-         global_count=None, **updates) -> SparseState:
+         global_count=None, counters=None, **updates) -> SparseState:
     """Advance the step counter and record per-step accounting.
 
     ``wire_bytes`` is the step's realised wire-level byte count (None —
-    external callers predating the counter — records 0 for the step)."""
+    external callers predating the counter — records 0 for the step).
+    ``counters`` are the step's ``BRANCH_COUNTERS`` (None: a step that took
+    none of those branches records zeros)."""
     vol = jnp.asarray(volume, jnp.float32)
     wb = jnp.asarray(0.0 if wire_bytes is None else wire_bytes, jnp.float32)
     kw = dict(
@@ -110,6 +137,12 @@ def bump(state: SparseState, *, volume, wire_bytes=None, local_count=None,
         wire_bytes=state.wire_bytes + wb,
         last_wire_bytes=wb,
     )
+    # "* 0 +": the new vector varies over the mesh axes the carried one
+    # does, whichever branch of a lax.cond writes it
+    kw["last_counters"] = state.last_counters * 0
+    if counters is not None:
+        kw["last_counters"] += jnp.stack(
+            [jnp.asarray(c, jnp.int32) for c in counters])
     if local_count is not None:
         kw["last_local_count"] = jnp.asarray(local_count, jnp.int32)
     if global_count is not None:

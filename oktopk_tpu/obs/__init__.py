@@ -14,8 +14,7 @@ callers import the submodules directly:
   - :mod:`oktopk_tpu.obs.volume`  — per-algorithm analytic wire-byte
     budgets and conformance ratios.
   - :mod:`oktopk_tpu.obs.tracing` — :class:`AnomalyTracer` (bounded
-    ``jax.profiler`` windows armed by guard trips) and
-    :class:`ChromeTraceSink` (host-phase Chrome trace export).
+    ``jax.profiler`` windows armed by guard trips).
   - :mod:`oktopk_tpu.obs.regress` — step-time regression detection
     against the repo's BENCH_r*.json trajectory (plus quality-summary
     watching and baseline-gap warnings).

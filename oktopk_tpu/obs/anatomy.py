@@ -21,8 +21,8 @@ it. This module gives the step a time-domain anatomy in three pieces:
 2. **Trace analyzer** — parses captured profiler output (the perfetto
    trace-event JSON ``jax.profiler.start_trace(...,
    create_perfetto_trace=True)`` writes, or any Chrome trace-event
-   file incl. ChromeTraceSink's, plus checked-in synthetic fixtures in
-   CI) into per-(bucket, phase) durations, classifies events into
+   file, plus checked-in synthetic fixtures in CI) into per-(bucket,
+   phase) durations, classifies events into
    compute vs collective lanes, computes the compute/comm overlap
    ratio and a time-sweep critical-path attribution of the measured
    span.
@@ -55,6 +55,22 @@ SCOPE_PREFIX = "anat"
 
 # the phase vocabulary of the collectives pipeline, in pipeline order
 PHASES = ("fwd_bwd", "select", "stage", "exchange", "combine", "optimizer")
+
+# Named steps of the algorithm inside ``select`` and ``stage``: plain
+# ``jax.named_scope``s UNDER a phase frame (``anat/b000/select/threshold``),
+# not contract frames of their own, so ``parse_scope`` and every reader of
+# the phase go on answering ``select`` / ``stage`` for the ops inside.
+# Every op of those two phases lies in exactly one (collectives/oktopk.py).
+SUB_THRESHOLD = "threshold"      # select: local threshold, exact or predicted
+SUB_SWEEP = "sweep"              # select: the n-scale sweep and its wrapper
+SUB_REPARTITION = "repartition"  # stage: region boundaries
+SUB_FINALIZE = "finalize"        # stage: census, prefix, branch, gathers
+SUB_GLOBAL = "global"            # select: phase-(b) winner selection
+SUB_FEEDBACK = "feedback"        # select: controller feedback
+SUB_SCOPES = {
+    "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
+    "stage": (SUB_REPARTITION, SUB_FINALIZE),
+}
 
 # phases whose time is wire time; everything else in the contract is
 # compute. Raw op names matching _COLLECTIVE_OPS inside a contract
@@ -108,14 +124,21 @@ def scope_name(phase: Optional[str] = None,
 
 
 def phase_scope(phase: Optional[str] = None, bucket: Optional[int] = None,
-                level: Optional[int] = None):
+                level: Optional[int] = None, sub: Optional[str] = None):
     """``jax.named_scope`` bearing the contract name (nullcontext when
     annotations are disabled). Pure metadata — usable inside jit,
-    shard_map and ``lax.cond`` branches."""
+    shard_map and ``lax.cond`` branches. ``sub`` (one of
+    ``SUB_SCOPES[phase]``) names the step of the algorithm inside the
+    phase: ``anat/b000/select/threshold``."""
     if not _ENABLED:
         return nullcontext()
     import jax
-    return jax.named_scope(scope_name(phase, bucket, level))
+    name = scope_name(phase, bucket, level)
+    if sub is not None:
+        if sub not in SUB_SCOPES.get(phase, ()):
+            raise ValueError(f"{sub!r} is no sub-scope of phase {phase!r}")
+        name = f"{name}/{sub}"
+    return jax.named_scope(name)
 
 
 @contextmanager

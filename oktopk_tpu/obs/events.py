@@ -169,7 +169,14 @@ EVENT_SCHEMAS: Dict[str, Dict[str, Dict[str, tuple]]] = {
     "trace_captured": {
         "required": {"step": _NUM, "start_step": _NUM,
                      "num_steps": _NUM, "trigger": _STR},
-        "optional": {"logdir": _OPT_STR},
+        # counters: [[step, collectives/state.COUNTERS vector], ...]
+        "optional": {"logdir": _OPT_STR, "counters": _LIST},
+    },
+    # a back-end compile in a later call of a step function than its
+    # first (trainer.py train_step, utils/compile_cache.py)
+    "recompile": {
+        "required": {"step": _NUM, "seconds": _NUM},
+        "optional": {},
     },
     # end-of-run per-bucket wire-volume conformance (trainer.py +
     # obs/volume.py). Two-level runs emit one report per level plus a

@@ -9,22 +9,25 @@ that ties the capture back to its trigger (``"guard_trip@step12"``).
 The expensive instrument therefore runs only when something is already
 wrong — the steady-state overhead is one predicate per step.
 
+The event also carries what the captured steps did: the program's
+``counters`` vectors (``collectives/state.COUNTERS``) of the steps inside
+the window, when the tracer was given their source.
+
 Capture count is capped (``max_captures``): a flapping guard must not
 fill the disk with traces. Profiler failures are tolerated — the
 window is journalled with ``logdir: null`` rather than raising, since
 observability must never take down training (some backends/platforms
 cannot start a trace at all).
 
-``ChromeTraceSink`` collects host-phase samples (utils/profiling.py
-``PhaseTimers``) as Chrome trace-event ``"X"`` (complete) events for
-``chrome://tracing`` / Perfetto.
+The window is the profiler's own trace: it holds the program's host spans
+(``utils/profiling.span``) beside the device planes, on one clock.
 """
 
 from __future__ import annotations
 
-import json
+import logging
 import os
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 _TRIGGERS = ("guard_trip", "fallback", "quality_rollup")
 
@@ -33,11 +36,14 @@ class AnomalyTracer:
     """Arms on anomaly events, captures a bounded trace window."""
 
     def __init__(self, logdir: str, bus=None, num_steps: int = 3,
-                 max_captures: int = 3):
+                 max_captures: int = 3,
+                 step_counters: Optional[Callable[[], list]] = None):
         self.logdir = logdir
         self.bus = bus
         self.num_steps = max(1, int(num_steps))
         self.max_captures = max(0, int(max_captures))
+        # () -> [(step, counters array), ...]: Trainer.step_counters
+        self.step_counters = step_counters
         self.captures: List[Dict[str, Any]] = []
         self._armed: Optional[str] = None      # trigger description
         self._start_step: Optional[int] = None
@@ -96,6 +102,16 @@ class AnomalyTracer:
                "num_steps": int(step - self._start_step),
                "logdir": self._active_dir,
                "trigger": self._armed or "unknown"}
+        if self.step_counters is not None:
+            try:
+                from oktopk_tpu.utils.profiling import fetch_counters
+                cap["counters"] = fetch_counters(
+                    [(s, c) for s, c in self.step_counters()
+                     if self._start_step <= s < step])
+            except Exception:
+                # a device fetch: journal the window without them
+                logging.getLogger(__name__).exception(
+                    "counters of the captured steps not fetched")
         self.captures.append(cap)
         self._armed = None
         self._start_step = None
@@ -108,57 +124,3 @@ class AnomalyTracer:
         """Force-close any open window (end of train())."""
         if self.active:
             self._stop(int(step))
-
-
-class ChromeTraceSink:
-    """Collects host phase samples as Chrome trace-event JSON.
-
-    Each bucket/phase family gets its own tid (first-seen order), so
-    Perfetto renders one row per family instead of interleaving every
-    sample on a single track; ``write()`` prepends trace metadata
-    ("M") events naming the process and each lane. Output stays
-    backward-readable: the "X" events carry the same fields as before
-    (plus distinct tids) and old consumers that only scan "X" events
-    see an identical payload shape.
-    """
-
-    def __init__(self):
-        self.events: List[Dict[str, Any]] = []
-        self._lanes: Dict[str, int] = {}
-
-    def _lane(self, name: str) -> str:
-        """Lane key for one sample: anatomy-contract names group by
-        (bucket, phase) family; anything else gets its own row."""
-        from oktopk_tpu.obs.anatomy import parse_scope, scope_name
-        parsed = parse_scope(name)
-        if parsed is not None and parsed != (None, None):
-            return scope_name(*parsed)
-        return name
-
-    def add(self, name: str, ts_s: float, dur_s: float):
-        """One complete ("X") event; times in seconds (host clock)."""
-        tid = self._lanes.setdefault(self._lane(name), len(self._lanes))
-        self.events.append({
-            "name": name, "ph": "X", "pid": 0, "tid": tid,
-            "ts": float(ts_s) * 1e6, "dur": float(dur_s) * 1e6,
-        })
-
-    def _metadata_events(self) -> List[Dict[str, Any]]:
-        meta: List[Dict[str, Any]] = [{
-            "name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-            "args": {"name": "oktopk host phases"},
-        }]
-        for lane, tid in sorted(self._lanes.items(), key=lambda kv: kv[1]):
-            meta.append({"name": "thread_name", "ph": "M", "pid": 0,
-                         "tid": tid, "args": {"name": lane}})
-            meta.append({"name": "thread_sort_index", "ph": "M", "pid": 0,
-                         "tid": tid, "args": {"sort_index": tid}})
-        return meta
-
-    def write(self, path: str) -> str:
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": self._metadata_events() + self.events,
-                       "displayTimeUnit": "ms"}, f)
-        return path

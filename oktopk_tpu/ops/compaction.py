@@ -445,12 +445,14 @@ def select_by_threshold_pallas(x: jnp.ndarray, thresh, cap: int,
                                interpret: bool | None = None):
     """Fixed-capacity threshold select, Pallas TPU fast path.
 
-    Same contract as ops.select.select_by_threshold: returns
-    ``(values[cap], indices[cap], count)`` with slots >= count holding
+    Same contract as ops.select.select_by_threshold, and what the kernels
+    did: returns ``(values[cap], indices[cap], count, branch)`` with slots
+    >= count holding
     value 0 / index n, elements packed in ascending index order, overflow
     beyond ``cap`` dropped with lowest-index-first retention (identical to
     the portable path). ``lo``/``hi`` restrict selection to the element
-    range [lo, hi).
+    range [lo, hi). ``branch`` is i32[2]: which of fast / repair / wide
+    ran (0 / 1 / 2) and the count of overflowing blocks that decided it.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -504,11 +506,13 @@ def select_by_threshold_pallas(x: jnp.ndarray, thresh, cap: int,
         sel = ((novf > 0).astype(jnp.int32)
                + (novf > ncap).astype(jnp.int32))
         values, indices = jax.lax.switch(sel, [fast, repair, wide], None)
+        branch = jnp.stack([sel, novf.astype(jnp.int32)])
     else:
         # drops beyond capb have in-block position >= capb >= cap, hence
         # global position >= cap: they can never make the first-cap prefix
         values, indices = _post(w_f, stored_f, capb_f)
-    return values, indices, count
+        branch = jnp.zeros((2,), jnp.int32)
+    return values, indices, count, branch
 
 
 def pack_by_region_pallas(x: jnp.ndarray, thresh, boundaries,
@@ -527,7 +531,8 @@ def pack_by_region_pallas(x: jnp.ndarray, thresh, boundaries,
     reference asserts the same: sum of region sizes == n,
     VGG/allreducer.py:648); callers with concrete boundaries get a cheap
     host-side check. Returns ``(values [R, cap], indices [R, cap],
-    counts [R])`` with the same contract as the portable path. The
+    counts [R], branch)``: the portable path's contract, and ``branch`` as
+    ``select_by_threshold_pallas`` gives it. The
     ascending-index staging is already region-grouped (regions are
     contiguous index ranges); all region arithmetic happens in the
     cap-scale post-processing.
@@ -576,7 +581,9 @@ def _pack_finalize(xp, xflat, t, rng, bnd, R, cap, nblocks, n, interpret,
                    vma, w_f, stored_f, raw):
     """Cap-scale region post-processing shared by ``pack_by_region_pallas``
     and the fused selection front-end (ops/fused_select.py): overflow
-    census -> fast/repair/wide dispatch over already-staged fast rows."""
+    census -> fast/repair/wide dispatch over already-staged fast rows.
+    Returns ``(values, indices, counts, branch)``; ``branch`` is i32[2], the
+    branch taken and the census that chose it."""
     # Region reconstruction requires every survivor staged (fast rows when
     # nothing overflowed, repaired rows for the <= ncap overflow blocks,
     # or the capb=BLK kernel otherwise). _region_counts is nb-scale — the
@@ -624,7 +631,8 @@ def _pack_finalize(xp, xflat, t, rng, bnd, R, cap, nblocks, n, interpret,
             w_w, xflat, c, o, BLK, cap, ct, n))
 
     sel = (novf > 0).astype(jnp.int32) + (novf > ncap).astype(jnp.int32)
-    return jax.lax.switch(sel, [fast, repair, wide], None)
+    values, indices, counts = jax.lax.switch(sel, [fast, repair, wide], None)
+    return values, indices, counts, jnp.stack([sel, novf.astype(jnp.int32)])
 
 
 def mesh_supports_pallas(mesh) -> bool:

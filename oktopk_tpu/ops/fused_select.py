@@ -57,7 +57,7 @@ from oktopk_tpu.ops.compaction import (
     _stage_tile,
     _vma_of,
 )
-from oktopk_tpu.obs.anatomy import phase_scope
+from oktopk_tpu.obs.anatomy import SUB_FINALIZE, SUB_SWEEP, phase_scope
 from oktopk_tpu.ops.hist_threshold import HIST_BINS, log2_bins, log2_hist
 
 
@@ -202,7 +202,7 @@ def fused_select_stage(grad: jnp.ndarray, residual: jnp.ndarray, thresh,
     # the anatomy scope lives INSIDE the jitted wrapper so the contract
     # name reaches this program's own op metadata (a caller-side scope
     # stops at the nested pjit call op)
-    with phase_scope("select"):
+    with phase_scope("select", sub=SUB_SWEEP):
         return _fused_select_stage_impl(grad, residual, thresh,
                                         probe_thresh, interpret)
 
@@ -238,18 +238,20 @@ def _fused_select_stage_impl(grad, residual, thresh, probe_thresh,
                    static_argnames=("num_regions", "cap", "interpret"))
 def fused_pack_finalize(st: FusedStage, boundaries, num_regions: int,
                         cap: int, interpret: bool | None = None):
-    """Per-region (values, indices, counts) from an already-run fused
-    stage — the cap-scale half of ``pack_by_region_pallas``, shared
+    """Per-region (values, indices, counts, branch) from an already-run
+    fused stage — the cap-scale half of ``pack_by_region_pallas``, shared
     verbatim (``_pack_finalize``): overflowing blocks are re-staged from
     the kernel's own acc output by the repair/wide kernels, so overflow
-    costs extra passes only when it happens, exactly as before."""
+    costs extra passes only when it happens, exactly as before. ``branch``
+    (i32[2]) says which of fast / repair / wide ran and the overflow census
+    that chose it."""
     if interpret is None:
         interpret = _interpret_default()
     n = st.acc.size
     nblocks = st.w_f.shape[0]
     bnd = jnp.asarray(boundaries, jnp.int32)
     vma = _vma_of(st.accp)
-    with phase_scope("stage"):
+    with phase_scope("stage", sub=SUB_FINALIZE):
         return _pack_finalize(st.accp, st.accflat, st.t, st.rng, bnd,
                               num_regions, cap, nblocks, n, interpret, vma,
                               st.w_f, st.stored_f, st.raw)
@@ -268,7 +270,7 @@ def fused_select_pallas(grad: jnp.ndarray, residual: jnp.ndarray, thresh,
     """
     st = fused_select_stage(grad, residual, thresh, probe_thresh,
                             interpret=interpret)
-    values, indices, counts = fused_pack_finalize(
+    values, indices, counts, _branch = fused_pack_finalize(
         st, boundaries, num_regions, cap, interpret=interpret)
     return (st.acc, values, indices, counts, st.local_count,
             st.probe_count, st.hist)

@@ -28,8 +28,18 @@ def count_by_threshold(x: jnp.ndarray, thresh) -> jnp.ndarray:
     return jnp.sum(jnp.abs(x) >= thresh)
 
 
+def _branch_if(out, with_branch: bool):
+    """``(values, indices, count[, branch])`` as the caller asked for it:
+    the kernel wrappers always say which branch ran, the portable paths
+    have one (``[0, 0]``)."""
+    if not with_branch:
+        return out[:3]
+    return out if len(out) == 4 else (*out, jnp.zeros((2,), jnp.int32))
+
+
 def select_by_threshold(x: jnp.ndarray, thresh, cap: int,
-                        use_pallas: bool = False):
+                        use_pallas: bool = False,
+                        with_branch: bool = False):
     """Pack elements with |x| >= thresh into a fixed-capacity triple.
 
     Replaces reference ``compressbythreshold`` (VGG/compression.py:122-142),
@@ -43,12 +53,15 @@ def select_by_threshold(x: jnp.ndarray, thresh, cap: int,
     ``use_pallas`` selects the TPU stream-compaction kernel
     (ops/compaction.py) instead of the portable cumsum+scatter, which
     serialises on TPU. Resolved from the mesh backend by the step builders
-    (OkTopkConfig.use_pallas).
+    (OkTopkConfig.use_pallas). ``with_branch`` appends the kernel wrapper's
+    ``branch`` (i32[2]: overflow branch taken, overflowing blocks).
     """
     if use_pallas and x.dtype == jnp.float32:   # kernel is f32-only
         from oktopk_tpu.ops.compaction import select_by_threshold_pallas
-        return select_by_threshold_pallas(x, thresh, cap)
-    return select_mask(x, jnp.abs(x) >= thresh, cap)
+        return _branch_if(select_by_threshold_pallas(x, thresh, cap),
+                          with_branch)
+    return _branch_if(select_mask(x, jnp.abs(x) >= thresh, cap),
+                      with_branch)
 
 
 def select_mask(x: jnp.ndarray, mask: jnp.ndarray, cap: int):
@@ -76,7 +89,7 @@ def select_nonzero(x: jnp.ndarray, cap: int, use_pallas: bool = False):
     """
     if use_pallas and x.dtype == jnp.float32:   # kernel is f32-only
         from oktopk_tpu.ops.compaction import select_by_threshold_pallas
-        return select_by_threshold_pallas(x, 0.0, cap)
+        return select_by_threshold_pallas(x, 0.0, cap)[:3]
     return select_mask(x, x != 0.0, cap)
 
 
@@ -95,7 +108,8 @@ def scatter_sparse(n: int, values: jnp.ndarray, indices: jnp.ndarray,
 
 def pack_by_region(x: jnp.ndarray, mask: jnp.ndarray,
                    boundaries: jnp.ndarray, num_regions: int, cap: int,
-                   thresh=None, use_pallas: bool = False):
+                   thresh=None, use_pallas: bool = False,
+                   with_branch: bool = False):
     """Pack masked elements of ``x`` into per-region fixed-capacity buffers.
 
     This is the TPU form of oktopk phase (a)'s send-side: the reference
@@ -120,13 +134,14 @@ def pack_by_region(x: jnp.ndarray, mask: jnp.ndarray,
 
     Returns:
       (values [num_regions, cap], indices [num_regions, cap] with global
-      element ids, counts [num_regions] clipped to cap).
+      element ids, counts [num_regions] clipped to cap); with
+      ``with_branch`` also the kernel wrapper's ``branch``.
     """
     n = x.size
     if use_pallas and thresh is not None and x.dtype == jnp.float32:
         from oktopk_tpu.ops.compaction import pack_by_region_pallas
-        return pack_by_region_pallas(x, thresh, boundaries, num_regions,
-                                     cap)
+        return _branch_if(pack_by_region_pallas(
+            x, thresh, boundaries, num_regions, cap), with_branch)
     ids = jnp.arange(n, dtype=jnp.int32)
     # region id per element; boundaries[1:-1] are the interior cut points.
     rid = jnp.searchsorted(boundaries[1:-1], ids, side="right").astype(jnp.int32)
@@ -146,7 +161,7 @@ def pack_by_region(x: jnp.ndarray, mask: jnp.ndarray,
     ends = boundaries[1:]
     end_counts = jnp.where(ends > 0, csum[jnp.maximum(ends - 1, 0)], 0)
     counts = jnp.minimum(end_counts - start_counts, cap)
-    return values, indices, counts
+    return _branch_if((values, indices, counts), with_branch)
 
 
 def region_mask(n: int, boundaries: jnp.ndarray, region: jnp.ndarray):

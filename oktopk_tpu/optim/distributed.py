@@ -28,7 +28,11 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from oktopk_tpu.collectives.registry import get_algorithm
-from oktopk_tpu.collectives.state import SparseState, init_state
+from oktopk_tpu.collectives.state import (
+    BRANCH_COUNTERS,
+    SparseState,
+    init_state,
+)
 from oktopk_tpu.comm import compat
 from oktopk_tpu.config import OkTopkConfig
 from oktopk_tpu.obs.anatomy import phase_scope
@@ -337,7 +341,7 @@ def build_sparse_grad_step(
                     if has_quality else None)
         results = [None] * len(leaves)
         sp_olds, sp_news, new_moms, bad_counts = [], [], [], []
-        absmaxes, qual_taps = [], []
+        absmaxes, qual_taps, step_counters = [], [], []
         vol = lk = gk = wbytes = jnp.asarray(0.0, jnp.float32)
         eps_num = eps_den = jnp.asarray(0.0, jnp.float32)
         for bi, idxs in enumerate(buckets):
@@ -406,6 +410,7 @@ def build_sparse_grad_step(
             wbytes = wbytes + sp_new.last_wire_bytes
             lk = lk + sp_new.last_local_count
             gk = gk + sp_new.last_global_count
+            step_counters.append(sp_new.last_counters)
             if profile_norm:
                 dense = lax.pmean(flat, axis_name)
                 eps_num = eps_num + jnp.sum((dense - reduced) ** 2)
@@ -440,6 +445,17 @@ def build_sparse_grad_step(
         }
         if eps is not None:
             metrics["eps_vs_dense"] = eps
+        # what the step did, one i32 vector in collectives/state.COUNTERS'
+        # order: the worst branch over the buckets, everything else summed,
+        # then the largest over the workers (the step is as slow as its
+        # slowest worker: one chip in the wide branch holds all of them)
+        per_bucket = jnp.stack(step_counters)        # [buckets, branches]
+        is_branch = jnp.asarray([nm.endswith("_branch")
+                                 for nm in BRANCH_COUNTERS])
+        metrics["counters"] = jnp.concatenate([
+            lax.pmax(jnp.where(is_branch, jnp.max(per_bucket, axis=0),
+                               jnp.sum(per_bucket, axis=0)), axis_name),
+            jnp.stack([lk, gk]).astype(jnp.int32)])
 
         # --- in-step anomaly guard (resilience/guard.py): agree on a
         # global skip flag, then make the whole step a training no-op —
@@ -467,7 +483,8 @@ def build_sparse_grad_step(
                                 wire_bytes=new.wire_bytes,
                                 last_wire_bytes=new.last_wire_bytes,
                                 last_local_count=new.last_local_count,
-                                last_global_count=new.last_global_count),
+                                last_global_count=new.last_global_count,
+                                last_counters=new.last_counters),
                     new)
                 for old, new in zip(sp_olds, sp_news)]
             health = _guard_mod.advance(health, any_bad, flags)

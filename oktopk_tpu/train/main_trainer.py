@@ -142,9 +142,10 @@ def parse_args(argv=None):
                         "step (0 = off); view with xprof/tensorboard")
     p.add_argument("--trace-steps", type=int, default=3)
     p.add_argument("--phase-timers", action="store_true",
-                   help="log data-wait vs device-step phase table every "
-                        "--log-every steps (reference _print_profiling, "
-                        "VGG/allreducer.py:379-439)")
+                   help="record the loop's host spans and log their table "
+                        "every --log-every steps (reference "
+                        "_print_profiling, VGG/allreducer.py:379-439); "
+                        "adds no wait for the device")
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--ckpt-every", type=int, default=0,
                    help="checkpoint every N iterations (0 = off)")
@@ -302,7 +303,8 @@ def main(argv=None):
     logger.info("training %d iterations (%d/epoch)", total, iters_per_epoch)
 
     from oktopk_tpu.utils.profiling import (MetricWriter, PhaseTimers,
-                                            TraceWindow, device_memory_stats)
+                                            TraceWindow, device_memory_stats,
+                                            attach, dump, span)
     rundir = os.path.join(args.logdir, slug)
     checkpointer = None
     if is_rank0 and args.ckpt_dir and args.ckpt_every and args.ckpt_async:
@@ -315,6 +317,9 @@ def main(argv=None):
             on_failure=trainer.note_ckpt_failure)
     writer = MetricWriter(rundir) if is_rank0 else None
     timers = PhaseTimers(every=args.log_every) if args.phase_timers else None
+    # attached here and not only inside train(): the checkpoint hand-off
+    # between chunks is a span too, and the snapshot at the end reads it
+    attach(timers)
     trace = (TraceWindow(os.path.join(rundir, "trace"), args.trace_at,
                          args.trace_steps) if args.trace_at and is_rank0
              else None)
@@ -354,22 +359,25 @@ def main(argv=None):
                 mem.get("bytes_in_use", 0) / 2**20)
             if (is_rank0 and args.ckpt_dir and args.ckpt_every
                     and done % args.ckpt_every == 0):
-                if checkpointer is not None:
-                    path = checkpointer.save(
-                        trainer.state, done,
-                        extra=trainer.supervisor_extra(),
-                        qualified=trainer.checkpoint_qualified)
-                else:
-                    from oktopk_tpu.train.checkpoint import save_checkpoint
-                    path = save_checkpoint(
-                        args.ckpt_dir, trainer.state, done,
-                        extra=trainer.supervisor_extra(),
-                        qualified=trainer.checkpoint_qualified)
-                    if args.ckpt_keep:
-                        from oktopk_tpu.train.durable import apply_retention
-                        apply_retention(args.ckpt_dir,
-                                        keep_last=args.ckpt_keep)
-                trainer.note_checkpoint(path, done)
+                with span("oktopk/checkpoint", step=done):
+                    if checkpointer is not None:
+                        path = checkpointer.save(
+                            trainer.state, done,
+                            extra=trainer.supervisor_extra(),
+                            qualified=trainer.checkpoint_qualified)
+                    else:
+                        from oktopk_tpu.train.checkpoint import (
+                            save_checkpoint)
+                        path = save_checkpoint(
+                            args.ckpt_dir, trainer.state, done,
+                            extra=trainer.supervisor_extra(),
+                            qualified=trainer.checkpoint_qualified)
+                        if args.ckpt_keep:
+                            from oktopk_tpu.train.durable import (
+                                apply_retention)
+                            apply_retention(args.ckpt_dir,
+                                            keep_last=args.ckpt_keep)
+                    trainer.note_checkpoint(path, done)
     finally:
         if writer is not None:
             writer.close()
@@ -379,6 +387,16 @@ def main(argv=None):
             # with a preemption handler the epilogue drains instead (an
             # async save in flight must publish whole before exit)
             checkpointer.close(timeout=300.0)
+        if is_rank0:
+            # what the run recorded about itself: set-up and loop spans,
+            # compile seconds by step, the last steps' counters. Last, and
+            # guarded: it fetches from the device, which may be what
+            # failed, and must not hide the exception that ended training
+            try:
+                dump(os.path.join(rundir, "profile_snapshot.json"))
+            except Exception:
+                logger.exception("profile snapshot not written")
+        attach(None)
 
     if preempt is not None:
         # park-state/requeue (or clear on success) — reference

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
+from collections import deque
 from typing import Any, Dict, Iterable, Optional
 
 import jax
@@ -37,6 +38,12 @@ from oktopk_tpu.optim.distributed import (
 )
 from oktopk_tpu.train import losses
 from oktopk_tpu.comm.mesh import get_mesh
+from oktopk_tpu.utils import profiling
+from oktopk_tpu.utils.compile_cache import compile_counters
+from oktopk_tpu.utils.profiling import span
+
+# steps whose ``metrics["counters"]`` vector a Trainer keeps, unsynced
+KEPT_COUNTERS = 512
 
 CNN_DNNS = {"vgg16", "vgg19", "resnet20", "resnet56", "resnet110",
             "resnet50", "alexnet", "mnistnet"}
@@ -83,10 +90,11 @@ class Trainer:
         self.model, example_fn = create_model(cfg.dnn, **mk)
         self.example_fn = example_fn
 
-        rng = jax.random.PRNGKey(cfg.seed)
-        init_batch = self._example_batch(2)
-        variables = self._init_variables(rng, init_batch)
-        params = variables.pop("params")
+        with span("oktopk/setup/model_init", recorder=profiling.SETUP):
+            rng = jax.random.PRNGKey(cfg.seed)
+            init_batch = self._example_batch(2)
+            variables = self._init_variables(rng, init_batch)
+            params = variables.pop("params")
         self.model_state = dict(variables)
 
         n = flat_size(params)
@@ -162,7 +170,8 @@ class Trainer:
                             else tempfile.mkdtemp(prefix="oktopk_traces_"))
                 self.tracer = AnomalyTracer(
                     tdir, bus=self.bus, num_steps=cfg.obs_trace_steps,
-                    max_captures=cfg.obs_max_traces)
+                    max_captures=cfg.obs_max_traces,
+                    step_counters=self.step_counters)
             if cfg.obs_regress_key:
                 from oktopk_tpu.obs.regress import RegressionDetector
                 self.regress = RegressionDetector.from_bench_records(
@@ -220,17 +229,26 @@ class Trainer:
         self.retune_events = 0     # forced re-calibrations executed
         self._fake_ms = None       # remembered trial-timing injector
 
-        self.state = place_dist_state(init_dist_state(
-            params, self.model_state, self.optimizer, self.algo_cfg,
-            momentum_correction=bool(self._mc_factor),
-            num_buckets=cfg.num_buckets,
-            with_health=self._with_health,
-            quality=self._quality_cfg), self.mesh, axis_name)
+        with span("oktopk/setup/state", recorder=profiling.SETUP):
+            self.state = place_dist_state(init_dist_state(
+                params, self.model_state, self.optimizer, self.algo_cfg,
+                momentum_correction=bool(self._mc_factor),
+                num_buckets=cfg.num_buckets,
+                with_health=self._with_health,
+                quality=self._quality_cfg), self.mesh, axis_name)
         self.autotuner = None      # built lazily by autotune()
         self._plans = None         # per-bucket BucketPlan list, or None
-        self.step_fn = self._build_step()
+        with span("oktopk/setup/build_step", recorder=profiling.SETUP):
+            self.step_fn = self._build_step()
         self._rng = jax.random.PRNGKey(cfg.seed + 1)
         self.metrics_history = []
+        # ---- what each step did (utils/profiling.py) ------------------
+        self.step_num = 0          # host step counter: every record of
+        # one step (spans, counters, compile seconds) carries it
+        self._counters = deque(maxlen=KEPT_COUNTERS)
+        self._compiles = compile_counters()
+        self._stepped_fn = None    # the step_fn that has run at least once
+        profiling.register(self)
 
     @property
     def _with_health(self) -> bool:
@@ -629,9 +647,36 @@ class Trainer:
     # ---- loops --------------------------------------------------------
 
     def train_step(self, batch):
-        self._rng, rng = jax.random.split(self._rng)
-        self.state, metrics = self.step_fn(self.state, batch, rng)
+        self.step_num += 1
+        with span("oktopk/step", step=self.step_num):
+            with span("oktopk/rng"):
+                self._rng, rng = jax.random.split(self._rng)
+            compiled = self._compiles.counts["compile"]
+            seconds = self._compiles.seconds["compile"]
+            with span("oktopk/dispatch"):
+                self.state, metrics = self.step_fn(self.state, batch, rng)
+            if self._stepped_fn is not self.step_fn:
+                self._stepped_fn = self.step_fn    # its first call compiles
+            elif self._compiles.counts["compile"] != compiled:
+                self._note_recompile(
+                    self._compiles.seconds["compile"] - seconds)
+        # device arrays, not fetched until somebody asks (step_counters)
+        self._counters.append((self.step_num, metrics["counters"]))
         return metrics
+
+    def _note_recompile(self, seconds: float) -> None:
+        """A back-end compile in a later call of a step function than its
+        first: new input shapes or shardings, at full compile cost."""
+        self._compiles.note_recompile(self.step_num, seconds)
+        if self.bus is not None:
+            self.bus.emit("recompile", step=self.step_num,
+                          seconds=float(seconds))
+
+    def step_counters(self):
+        """The retained ``(step_num, counters)`` pairs, oldest first; each
+        ``counters`` is the step's device vector in
+        ``collectives/state.COUNTERS`` order."""
+        return list(self._counters)
 
     def train(self, data_iter: Iterable, num_iters: int,
               log_every: int = 50, logger=None, metric_writer=None,
@@ -642,9 +687,24 @@ class Trainer:
 
         Optional observability hooks (SURVEY.md §5.1): ``metric_writer``
         (utils.profiling.MetricWriter) records per-step scalars,
-        ``timers`` (PhaseTimers) splits data-wait vs device-step time,
+        ``timers`` (PhaseTimers) is attached as the recorder of the
+        loop's host spans (``oktopk/data``, ``oktopk/step`` and one round
+        each place that may wait for the device) and tabulates the loop as
+        it runs: it adds no wait of its own,
         ``trace`` (TraceWindow) captures a bounded jax.profiler trace.
         """
+        if timers is not None:
+            prev = profiling.attach(timers)
+        try:
+            return self._train(data_iter, num_iters, log_every, logger,
+                               metric_writer, timers, trace, start_step,
+                               should_stop)
+        finally:
+            if timers is not None:
+                profiling.attach(prev)
+
+    def _train(self, data_iter, num_iters, log_every, logger, metric_writer,
+               timers, trace, start_step, should_stop):
         metrics = {}
         pending = []  # (step, device-metrics) — flushed on the log cadence
         # so the writer never forces a per-step device sync
@@ -653,8 +713,7 @@ class Trainer:
 
         def flush_pending():
             for s, dm in pending:
-                host = {k: float(np.asarray(v).mean())
-                        for k, v in dm.items()}
+                host = self._host_scalars(dm)
                 if metric_writer is not None:
                     metric_writer.write(s, host)
                 if self.bus is not None:
@@ -670,10 +729,13 @@ class Trainer:
                 break
             step = start_step + i + 1
             self.last_step = step
+            self.step_num = step - 1     # train_step counts it up to step
             # plan (or re-plan) the per-bucket collectives before the step
             # runs; a no-change verdict leaves step_fn (and its compiled
             # program) untouched
-            self.maybe_autotune(step)
+            if self.cfg.autotune:
+                with span("oktopk/autotune", step=step):
+                    self.maybe_autotune(step)
             if trace is not None:
                 trace.on_step(step)
             if self.tracer is not None:
@@ -681,26 +743,22 @@ class Trainer:
                 # here on the step after a guard_trip/fallback event,
                 # closes num_steps later with a trace_captured event
                 self.tracer.on_step(step)
-            if timers is not None:
-                with timers.phase("data"):
-                    batch = next(data_iter)
-                with timers.phase("step"):
-                    metrics = self.train_step(batch)
-                    jax.block_until_ready(metrics["loss"])
-            else:
+            with span("oktopk/data", step=step):
                 batch = next(data_iter)
-                metrics = self.train_step(batch)
+            metrics = self.train_step(batch)
             if (self.supervisor is not None
                     and step % max(1, self.cfg.resilience_check_every) == 0):
                 # reacting to guard trips costs a device sync on the
                 # check cadence; escalation may rebuild step_fn or
                 # restore state before the next iteration
-                self.supervise(step, metrics)
+                with span("oktopk/supervise", step=step):
+                    self.supervise(step, metrics)
             if (self._quality_cfg is not None
                     and step % self._quality_cfg.every == 0):
                 # drain the device metric rings on the flush cadence —
                 # steady state between flushes adds zero host syncs
-                self._flush_quality(step)
+                with span("oktopk/flush_quality", step=step):
+                    self._flush_quality(step)
             if self.feedback is not None:
                 # fault→autotune feedback: a passing window vote forces
                 # a re-calibrate + re-tune (host-side list ops only
@@ -711,8 +769,12 @@ class Trainer:
             if "grad_nonfinite" in metrics:
                 nf_window.append(metrics["grad_nonfinite"])
             if (i + 1) % log_every == 0:
-                if pending:
-                    flush_pending()
+                # the log cadence's one wait for the device
+                with span("oktopk/log_flush", step=step):
+                    if pending:
+                        flush_pending()
+                    if logger:
+                        loss = float(metrics["loss"])
                 dt = (time.time() - t0) / log_every
                 if self.regress is not None:
                     self.regress.observe(step, dt * 1e3)
@@ -721,8 +783,7 @@ class Trainer:
                     # resume the log must agree with scalars.csv/checkpoints
                     logger.info(
                         "iter %d loss %.4f vol %.0f %.3fs/it", step,
-                        float(metrics["loss"]),
-                        float(metrics["comm_volume"]), dt)
+                        loss, float(metrics["comm_volume"]), dt)
                     nf = sum(float(x) for x in nf_window)
                     if nf:
                         # the reference warns on NaN gradient sparsity
@@ -737,8 +798,16 @@ class Trainer:
                     self.bus.emit("phase", step=step, phases=phase_summary)
                     if self.regress is not None:
                         # host-phase durations vs configured phase limits
-                        # (key="phase:<name>" regressions on the bus)
-                        self.regress.observe_phases(step, phase_summary)
+                        # (key="phase:<name>" regressions on the bus).
+                        # The limits' old keys keep their meaning: "data"
+                        # is the wait in next(data_iter); "step" was the
+                        # blocked device step, which no span holds now
+                        # that nothing blocks: the window's wall time a
+                        # step is what the device sets in a steady loop
+                        self.regress.observe_phases(step, {
+                            **phase_summary,
+                            "data": phase_summary.get("oktopk/data"),
+                            "step": dt * 1e3})
                 t0 = time.time()
             if timers is not None and logger is not None:
                 timers.maybe_log(step, logger)
@@ -751,9 +820,21 @@ class Trainer:
             self._flush_quality(self.last_step)
         if self.bus is not None:
             self._emit_volume_report()
-        self.metrics_history.append(
-            {k: float(np.asarray(v).mean()) for k, v in metrics.items()})
+        self.metrics_history.append(self._host_scalars(metrics))
         return metrics
+
+    @staticmethod
+    def _host_scalars(metrics) -> Dict[str, float]:
+        """One step's device metrics as host floats; the ``counters``
+        vector is spread under its entries' names (the realised counts are
+        ``local_k``/``global_k`` already)."""
+        from oktopk_tpu.collectives.state import BRANCH_COUNTERS
+        host = {k: float(np.asarray(v).mean())
+                for k, v in metrics.items() if k != "counters"}
+        if "counters" in metrics:
+            host.update(zip(BRANCH_COUNTERS, map(
+                float, np.asarray(metrics["counters"]))))
+        return host
 
     def _bucket_plan(self):
         """Per-bucket (algo name, density) after autotune plans and forced

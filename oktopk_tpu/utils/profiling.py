@@ -8,74 +8,149 @@ by ``_print_profiling`` (VGG/allreducer.py:379-439), plus TensorBoard scalars
 (VGG/dl_trainer.py:697-699).
 
 TPU-native reality: the compression/collective phases fuse into ONE XLA
-program, so intra-step phase timing moves to (a) coarse host-side phases
-(data wait / step / eval), (b) analytic counters carried in SparseState
-(selection counts, comm volume), and (c) ``jax.profiler`` traces for
-op-level attribution in xprof. This module provides all three:
+program, so the program says what a step did in three kinds of record,
+all collected here:
 
-- :class:`PhaseTimers` — host-side phase accounting with the reference's
-  every-N-steps table dump;
-- :class:`MetricWriter` — per-step scalar log (CSV; the reference's
-  tensorboardX writer equivalent, gated to stay dependency-free);
-- :func:`trace_window` / :class:`TraceWindow` — a bounded
-  ``jax.profiler`` trace around chosen steps;
-- :func:`device_memory_stats` — HBM in-use/limit (the
-  ``torch.cuda.memory_allocated`` analogue).
+- **host spans** — :func:`span` is the one host-side primitive: a
+  ``jax.profiler.TraceAnnotation`` (so the span lands in the profiler's own
+  ``.xplane.pb``, on the clock of the device planes, and costs a flag test
+  when no profiler runs) and, when a recorder is attached
+  (:func:`attach`) or handed in, one ``(name, start_ns, end_ns, step,
+  parent)`` record in a :class:`PhaseTimers`. The device-side twin is
+  ``obs/anatomy.phase_scope``;
+- **host counters** — the compile listener of ``utils/compile_cache.py``;
+- **device counters** — the ``metrics["counters"]`` vector of every step
+  (``collectives/state.COUNTERS``), kept unsynced by the ``Trainer``.
+
+:func:`snapshot` / :func:`dump` are the one way out for all three. Also
+here: :class:`MetricWriter` (per-step scalar CSV), :class:`TraceWindow` /
+:func:`trace_window` (bounded ``jax.profiler`` captures) and
+:func:`device_memory_stats`.
 """
 
 from __future__ import annotations
 
 import csv
+import json
 import os
+import threading
 import time
-from collections import defaultdict
+import weakref
+from collections import defaultdict, deque
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Any, Dict, List, Optional
+
+SPAN_PREFIX = "oktopk/"
+MAX_RECORDS = 8192     # span records a recorder keeps (a ring)
+
+_recorder: Optional["PhaseTimers"] = None
+_open = threading.local()          # the stack of open spans, a thread
+_step = 0                          # host step of the last stepped span
+_sources: List[weakref.ref] = []   # live objects with step_counters()
+
+
+def attach(recorder: Optional["PhaseTimers"]) -> Optional["PhaseTimers"]:
+    """Make ``recorder`` the in-memory sink of every span (None detaches);
+    returns the one attached before."""
+    global _recorder
+    prev, _recorder = _recorder, recorder
+    return prev
+
+
+def current_step() -> int:
+    """The host step counter of the innermost span that carried one."""
+    return _step
+
+
+class span:
+    """``with span("oktopk/dispatch"):`` — one host span.
+
+    Always a ``TraceAnnotation`` (``step`` becomes its ``step_num`` stat);
+    with a recorder (``recorder=`` or the attached one) also one record.
+    A span without ``step`` inherits its parent's, so every record of one
+    step shares the identifier."""
+
+    __slots__ = ("name", "step", "recorder", "_ann", "_t0", "_parent")
+
+    def __init__(self, name: str, step: Optional[int] = None,
+                 recorder: Optional["PhaseTimers"] = None):
+        self.name, self.step, self.recorder = name, step, recorder
+
+    def __enter__(self):
+        global _step
+        import jax
+        if self.step is not None:
+            _step = self.step
+            self._ann = jax.profiler.TraceAnnotation(self.name,
+                                                     step_num=self.step)
+        else:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self.recorder is None:
+            self.recorder = _recorder
+        if self.recorder is not None:
+            stack = _open.__dict__.setdefault("stack", [])
+            self._parent = stack[-1] if stack else None
+            if self.step is None and self._parent is not None:
+                self.step = self._parent.step
+            stack.append(self)
+            self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if self.recorder is not None:
+            t1 = time.time_ns()
+            _open.stack.pop()
+            self.recorder.record(
+                self.name, self._t0, t1, self.step,
+                self._parent.name if self._parent is not None else None)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class PhaseTimers:
-    """Rolling per-phase wall-clock accounting.
+    """The in-memory sink of :func:`span`: every record as it came
+    (``records``, a ring of ``MAX_RECORDS``) and rolling per-name
+    durations.
 
-    ``with timers.phase("step"): ...`` accumulates a sample; ``table()``
-    renders the reference-style mean/total dump (VGG/allreducer.py:379-439),
-    and ``maybe_log(step, logger)`` prints it every ``every`` steps then
-    resets, like the reference's 50-step cadence.
+    ``table()`` renders the reference-style mean/total dump
+    (VGG/allreducer.py:379-439), ``summary()`` its machine-readable form,
+    and ``maybe_log(step, logger)`` prints the table every ``every`` steps
+    then resets, like the reference's 50-step cadence. ``phase(name)`` is a
+    span recorded here whether or not this recorder is the attached one.
     """
 
-    def __init__(self, every: int = 50, sink=None):
+    def __init__(self, every: int = 50):
         self.every = every
-        # optional obs.tracing.ChromeTraceSink (anything with
-        # add(name, ts_s, dur_s)): every phase sample also becomes a
-        # Chrome trace-event for chrome://tracing / Perfetto
-        self.sink = sink
         self._samples: Dict[str, list] = defaultdict(list)
+        # (name, start_ns, end_ns, step, parent); wall-clock nanoseconds,
+        # the clock the profiler stamps its host events with
+        self.records: deque = deque(maxlen=MAX_RECORDS)
 
-    @contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            self._samples[name].append(dur)
-            if self.sink is not None:
-                self.sink.add(name, t0, dur)
+    def phase(self, name: str) -> span:
+        return span(name, recorder=self)
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               step: Optional[int] = None,
+               parent: Optional[str] = None) -> None:
+        self._samples[name].append((end_ns - start_ns) * 1e-9)
+        self.records.append((name, start_ns, end_ns, step, parent))
 
     def add(self, name: str, seconds: float) -> None:
         self._samples[name].append(seconds)
 
     def table(self) -> str:
-        rows = [f"{'phase':<14}{'mean_ms':>10}{'total_s':>10}{'count':>8}"]
+        rows = [f"{'phase':<22}{'mean_ms':>10}{'total_s':>10}{'count':>8}"]
         for name in sorted(self._samples):
             s = self._samples[name]
             if not s:
                 # defaultdict access can register a phase with no
                 # samples; render it instead of dividing by zero
-                rows.append(f"{name:<14}{'-':>10}{'-':>10}{0:>8d}")
+                rows.append(f"{name:<22}{'-':>10}{'-':>10}{0:>8d}")
                 continue
             mean = sum(s) / len(s)
             rows.append(
-                f"{name:<14}{mean * 1e3:>10.2f}{sum(s):>10.3f}{len(s):>8d}")
+                f"{name:<22}{mean * 1e3:>10.2f}{sum(s):>10.3f}{len(s):>8d}")
         return "\n".join(rows)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
@@ -259,3 +334,69 @@ def host_memory_stats() -> Dict[str, float]:
     except OSError:
         pass
     return {}
+
+
+# ---- one way out ------------------------------------------------------
+
+# set-up spans (``oktopk/setup/...``) are recorded here always: a dozen
+# records a process
+SETUP = PhaseTimers(every=0)
+
+
+def register(source) -> None:
+    """Weakly register an object whose ``step_counters()`` returns
+    ``[(step_num, counters array), ...]`` (a ``Trainer`` does at
+    construction), so that :func:`snapshot` finds it."""
+    _sources[:] = [r for r in _sources if r() is not None]
+    _sources.append(weakref.ref(source))
+
+
+def fetch_counters(pairs) -> List[List[Any]]:
+    """``[(step, counters device array), ...]`` as ``[[step, [ints]],
+    ...]``, with ONE ``device_get``."""
+    import jax
+    host = jax.device_get([c for _, c in pairs])
+    return [[int(s), [int(v) for v in c]]
+            for (s, _), c in zip(pairs, host)]
+
+
+def snapshot() -> Dict[str, Any]:
+    """Everything the program recorded about itself, as plain data:
+
+    - ``spans``: the set-up spans and the attached recorder's records;
+    - ``host_counters``: the compile listener's totals, its seconds by
+      host step and the recompiles it saw (utils/compile_cache.py);
+    - ``step_counters``: the retained ``(step, counters)`` pairs of every
+      live registered source, fetched with ONE ``device_get``; the order
+      of a vector is ``counter_names``, a branch entry's value its place
+      in ``branch_names``;
+    - ``sub_scopes``: the named steps under the ``select`` and ``stage``
+      phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
+    """
+    from oktopk_tpu.collectives.state import BRANCHES, COUNTERS
+    from oktopk_tpu.obs.anatomy import SUB_SCOPES
+    from oktopk_tpu.utils.compile_cache import compile_counters
+
+    recs = list(SETUP.records)
+    if _recorder is not None and _recorder is not SETUP:
+        recs += list(_recorder.records)
+    pairs = [p for r in _sources if (src := r()) is not None
+             for p in src.step_counters()]
+    return {
+        "spans": [{"name": n, "start_ns": a, "end_ns": b, "step": s,
+                   "parent": p} for n, a, b, s, p in recs],
+        "host_counters": compile_counters().as_dict(),
+        "counter_names": list(COUNTERS),
+        "branch_names": list(BRANCHES),
+        "sub_scopes": {ph: list(subs) for ph, subs in SUB_SCOPES.items()},
+        "step_counters": [{"step": s, "counters": c}
+                          for s, c in fetch_counters(pairs)],
+    }
+
+
+def dump(path: str) -> str:
+    """:func:`snapshot` as JSON at ``path``."""
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(snapshot(), f)
+    return path

@@ -229,29 +229,18 @@ class TestLowering:
         assert "anat/b000" not in text
 
 
-class TestChromeTraceSinkLanes:
-    def test_contract_names_share_family_lane(self, tmp_path):
-        from oktopk_tpu.obs.tracing import ChromeTraceSink
-        sink = ChromeTraceSink()
-        sink.add("anat/b000/select", 0.0, 0.010)
-        sink.add("anat/b000/select", 0.020, 0.010)   # same family
-        sink.add("anat/b001/select", 0.000, 0.005)   # other bucket
-        sink.add("data_wait", 0.000, 0.001)          # non-contract name
-        tids = {ev["name"]: ev["tid"] for ev in sink.events}
-        assert sink.events[0]["tid"] == sink.events[1]["tid"]
-        assert tids["anat/b001/select"] != tids["anat/b000/select"]
-        assert tids["data_wait"] not in (tids["anat/b000/select"],
-                                         tids["anat/b001/select"])
-        path = str(tmp_path / "t.trace.json")
-        sink.write(path)
-        with open(path) as f:
-            doc = json.load(f)
-        meta = [ev for ev in doc["traceEvents"] if ev["ph"] == "M"]
-        lane_names = {ev["args"]["name"] for ev in meta
-                      if ev["name"] == "thread_name"}
-        assert {"anat/b000/select", "anat/b001/select",
-                "data_wait"} <= lane_names
-        assert any(ev["name"] == "process_name" for ev in meta)
+class TestSubScopesKeepThePhase:
+    def test_sub_scope_names_parse_as_their_phase(self):
+        # took the place of the ChromeTraceSink lane test: what groups a
+        # name into its (bucket, phase) family is parse_scope, and it has
+        # to go on doing so under the sub-scopes of select and stage
+        for phase, subs in anatomy.SUB_SCOPES.items():
+            for sub in subs:
+                name = f"jit(step)/anat/b001/anat/b001/{phase}/{sub}/gather"
+                assert anatomy.parse_scope(name) == (phase, 1)
+        assert anatomy.parse_scope("data_wait") is None
+        with pytest.raises(ValueError):
+            anatomy.phase_scope("stage", 0, sub="threshold")
 
 
 class TestSummaryPercentiles:

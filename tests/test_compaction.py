@@ -22,7 +22,7 @@ pytestmark = pytest.mark.kernels
 
 def run_both(x, thresh, cap):
     got = select_by_threshold_pallas(jnp.asarray(x), thresh, cap,
-                                     interpret=True)
+                                     interpret=True)[:3]
     want = select_by_threshold(jnp.asarray(x), thresh, cap)
     return [np.asarray(g) for g in got], [np.asarray(w) for w in want]
 
@@ -64,9 +64,9 @@ class TestCompactionParity:
 
     def test_empty_selection(self):
         x = np.zeros(2 * BLK, np.float32)
-        gv, gi, gc = [np.asarray(a) for a in
-                      select_by_threshold_pallas(jnp.asarray(x), 1.0, 128,
-                                                 interpret=True)]
+        gv, gi, gc, _ = [np.asarray(a) for a in
+                         select_by_threshold_pallas(jnp.asarray(x), 1.0, 128,
+                                                    interpret=True)]
         assert gc == 0
         assert (gi == x.size).all()
         assert (gv == 0).all()
@@ -128,8 +128,8 @@ class TestCompactionParity:
         rng = np.random.RandomState(3)
         x = rng.randn(3 * BLK).astype(np.float32)
         lo, hi = BLK // 2, 2 * BLK + 17
-        gv, gi, gc = [np.asarray(a) for a in
-                      select_by_threshold_pallas(
+        gv, gi, gc, _ = [np.asarray(a) for a in
+                         select_by_threshold_pallas(
                           jnp.asarray(x), 2.0, 512,
                           lo=jnp.int32(lo), hi=jnp.int32(hi),
                           interpret=True)]
@@ -159,7 +159,7 @@ class TestPackRegionsParity:
         t, cap = 1.0, 256
         R = len(bounds) - 1
         b = jnp.asarray(bounds, jnp.int32)
-        gv, gi, gc = [np.asarray(a) for a in pack_by_region_pallas(
+        gv, gi, gc, _ = [np.asarray(a) for a in pack_by_region_pallas(
             jnp.asarray(x), t, b, R, cap, interpret=True)]
         wv, wi, wc = [np.asarray(a) for a in pack_by_region(
             jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= t, b, R, cap)]
@@ -185,7 +185,7 @@ class TestPackRegionsParity:
         assert 0 < int((raw > CAPB_FAST).sum()) <= _novf_cap(16)
         # boundary inside the dense block, past the 128 fast-staged slots
         b = jnp.asarray([0, 5 * BLK + 700, n], jnp.int32)
-        gv, gi, gc = [np.asarray(a) for a in pack_by_region_pallas(
+        gv, gi, gc, _ = [np.asarray(a) for a in pack_by_region_pallas(
             jnp.asarray(x), 1.0, b, 2, 2 * BLK, interpret=True)]
         wv, wi, wc = [np.asarray(a) for a in pack_by_region(
             jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= 1.0, b, 2, 2 * BLK)]
@@ -201,7 +201,7 @@ class TestPackRegionsParity:
         rng = np.random.RandomState(6)
         x = rng.randn(n).astype(np.float32)
         b = jnp.asarray([0, n // 2, n], jnp.int32)
-        gv, gi, gc = [np.asarray(a) for a in pack_by_region_pallas(
+        gv, gi, gc, _ = [np.asarray(a) for a in pack_by_region_pallas(
             jnp.asarray(x), 0.3, b, 2, 64, interpret=True)]  # far over cap
         wv, wi, wc = [np.asarray(a) for a in pack_by_region(
             jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= 0.3, b, 2, 64)]

@@ -45,7 +45,7 @@ def test_select_parity_on_chip(tpu_dev):
     x = rng.randn(n).astype(np.float32)
     cap = 4096
     with jax.default_device(tpu_dev):
-        gv, gi, gc = select_by_threshold_pallas(jnp.asarray(x), 2.0, cap,
+        gv, gi, gc, _ = select_by_threshold_pallas(jnp.asarray(x), 2.0, cap,
                                                 interpret=False)
         gv, gi, gc = map(np.asarray, (gv, gi, gc))
     wv, wi, wc = map(np.asarray,
@@ -62,7 +62,7 @@ def test_pack_by_region_parity_on_chip(tpu_dev):
     bounds = np.array([0, n // 3, n // 2, n], np.int32)
     cap = 2048
     with jax.default_device(tpu_dev):
-        gv, gi, gc = pack_by_region_pallas(jnp.asarray(x), 1.5,
+        gv, gi, gc, _ = pack_by_region_pallas(jnp.asarray(x), 1.5,
                                            jnp.asarray(bounds), 3, cap,
                                            interpret=False)
         gv, gi, gc = map(np.asarray, (gv, gi, gc))
@@ -94,7 +94,7 @@ def test_select_repair_branch_parity_on_chip(tpu_dev):
     novf = int(((raw > CAPB_FAST) & (excl + CAPB_FAST < cap)).sum())
     assert 0 < novf <= _novf_cap(64)
     with jax.default_device(tpu_dev):
-        gv, gi, gc = select_by_threshold_pallas(jnp.asarray(x), 1.0, cap,
+        gv, gi, gc, _ = select_by_threshold_pallas(jnp.asarray(x), 1.0, cap,
                                                 interpret=False)
         gv, gi, gc = map(np.asarray, (gv, gi, gc))
     wv, wi, wc = map(np.asarray,
@@ -119,7 +119,7 @@ def test_pack_repair_branch_straddling_boundary_on_chip(tpu_dev):
     assert 0 < int((raw > CAPB_FAST).sum()) <= _novf_cap(16)
     bounds = np.asarray([0, 5 * BLK + 700, n], np.int32)
     with jax.default_device(tpu_dev):
-        gv, gi, gc = pack_by_region_pallas(jnp.asarray(x), 1.0,
+        gv, gi, gc, _ = pack_by_region_pallas(jnp.asarray(x), 1.0,
                                            jnp.asarray(bounds), 2, 2 * BLK,
                                            interpret=False)
         gv, gi, gc = map(np.asarray, (gv, gi, gc))
@@ -234,7 +234,7 @@ def test_pack_wide_branch_parity_on_chip(tpu_dev):
     assert (raw > CAPB_FAST).sum() > _novf_cap(16)
     bounds = np.asarray([0, 7 * BLK + 300, n], np.int32)
     with jax.default_device(tpu_dev):
-        gv, gi, gc = pack_by_region_pallas(jnp.asarray(x), 1.0,
+        gv, gi, gc, _ = pack_by_region_pallas(jnp.asarray(x), 1.0,
                                            jnp.asarray(bounds), 2, n,
                                            interpret=False)
         gv, gi, gc = map(np.asarray, (gv, gi, gc))
