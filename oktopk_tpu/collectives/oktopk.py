@@ -154,9 +154,9 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     hist_mode = cfg.threshold_method == "hist"
     # Fused selection front-end (ops/fused_select.py): ONE Pallas sweep
     # over (grad, residual) yields acc, the staging rows, the realised and
-    # Newton-probe counts, and the threshold histogram — replacing the
-    # separate add_residual / abs / mask / count / probe / pack passes
-    # below. The unfused path (cfg.fuse_select=False) stays as the
+    # Newton-probe counts, and — under hist_mode only, the one reader —
+    # the threshold histogram, replacing the separate add_residual / abs /
+    # mask / count / probe / pack passes below. The unfused path (cfg.fuse_select=False) stays as the
     # bit-parity oracle (tests/test_fused_select.py).
     fuse = (up and cfg.fuse_select is not False
             and grad.dtype == jnp.float32)
@@ -254,7 +254,8 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     if fuse:
         with phase_scope("select", bkt, sub=SUB_SWEEP):
             st = fused_select_stage(grad, state.residual, lt,
-                                    lt * cfg.probe_ratio)
+                                    lt * cfg.probe_ratio,
+                                    with_hist=hist_mode)
             acc = st.acc
         with phase_scope("stage", bkt, sub=SUB_REPARTITION):
             boundaries = lax.cond(

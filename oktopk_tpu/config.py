@@ -129,8 +129,12 @@ class OkTopkConfig:
     # "sort": exact lax.top_k (reference-faithful; fine on CPU/small n).
     # "hist": one-pass 256-bin log2-magnitude histogram cumsum read
     #   (ops/hist_threshold.py) — 1-bit within-octave resolution, but ONE
-    #   data pass standalone and ZERO extra passes when the fused selection
-    #   kernel emits the histogram as a byproduct (ops/fused_select.py).
+    #   data pass standalone and no pass of its own when the fused selection
+    #   kernel emits the histogram (ops/fused_select.py). No extra pass is
+    #   not no cost: the histogram is 16 of that kernel's 24 one-hot tiles
+    #   a block, so "hist" buys its recompute with ~66 ms in EVERY step at
+    #   n = 66 M on a v5e (105.6 ms a call against 39.2; PERF.md, PR 25);
+    #   the kernel builds it under this method only.
     #   oktopk under "hist" uses LAGGED local recomputes: each step selects
     #   with the carried drift-predicted threshold while the exact level is
     #   read from the histogram that same selection pass produced, becoming
@@ -151,7 +155,8 @@ class OkTopkConfig:
 
     # Fused selection front-end (ops/fused_select.py): ONE Pallas sweep
     # over (grad, residual) computes acc, the staging rows, the realised +
-    # Newton-probe counts and the threshold histogram, replacing the
+    # Newton-probe counts and (threshold_method="hist" only) the threshold
+    # histogram, replacing the
     # separate add_residual/abs/mask/count/probe/pack passes of
     # collectives/oktopk.py. None = auto (on whenever the Pallas backend
     # is active); False = force the unfused per-pass path (the parity

@@ -39,8 +39,11 @@ dispatches (``lax.switch``) on the overflow census:
   * no overflow that matters  -> fast rows alone (the common small-n case);
   * <= ``_novf_cap`` blocks   -> a *repair* kernel re-stages only the
     overflowing blocks at full 1024 width (their ids scalar-prefetched
-    into the input index_map), ~nblocks/8 block-stagings instead of
-    nblocks — measured 9 ms vs the 69 ms full-wide re-stage on v5e;
+    into the input index_map). Its grid is the static list length
+    (nblocks/8), but its cost is the live entries and, inside each, the
+    128-slot pages its survivors reach: a padded entry does nothing and a
+    block with 300 survivors stages 3 pages of 8 (8 one-hot tiles a page,
+    PERF.md section 5) — nobody addresses the rest (``_run_repair``);
     ``_materialize_het`` then reads the mixed 128/1024-wide layout via
     one extra telescoping accumulator (the per-slot source block);
   * more                       -> the capb=1024 kernel over everything
@@ -233,45 +236,71 @@ def _novf_cap(nblocks: int) -> int:
     return max((nblocks + 7) // 8, 8)
 
 
-def _repair_kernel(t_ref, r_ref, bl_ref, x_ref, w_ref):
+def _repair_kernel(t_ref, r_ref, bl_ref, nv_ref, x_ref, w_ref):
     """Re-stage ONE overflowing block (id scalar-prefetched via ``bl_ref``)
     at full 1024 width, written as eight 128-wide *pages* (page p holds
     packed slots [128p, 128(p+1))) — a [1, 1024] staging row would need a
     cross-lane reshape Mosaic rejects; pages keep every store a [1, 128]
-    lane row. Row-major [8, 128] flatten == the 1024-wide row layout."""
+    lane row. Row-major [8, 128] flatten == the 1024-wide row layout.
+
+    Only what a consumer can address is staged: a grid step at or past the
+    live count ``nv_ref[0]`` (a padded list entry) does nothing, and a live
+    step stages page p only when the block's own survivor count reaches
+    into it — slots at or past a block's count are never read (see
+    ``_run_repair``)."""
     import jax.experimental.pallas as pl
 
     i = pl.program_id(0)
-    b = bl_ref[i]
-    x = x_ref[:]                                          # [8, 128]
-    woff = (jax.lax.broadcasted_iota(jnp.int32, (BLK_ROWS, BLK_COLS), 0)
-            * BLK_COLS
-            + jax.lax.broadcasted_iota(jnp.int32, (BLK_ROWS, BLK_COLS), 1))
-    gidx = b * BLK + woff
-    mask = ((jnp.abs(x) >= t_ref[0])
-            & (gidx >= r_ref[0]) & (gidx < r_ref[1]))
-    pos, _raw = _block_prefix(mask.astype(jnp.int32))
-    for p in range(BLK_ROWS):
-        kept_p = mask & (pos >= p * BLK_COLS) & (pos < (p + 1) * BLK_COLS)
-        sel_p = jnp.where(kept_p, pos - p * BLK_COLS, BLK_COLS)
-        w_ref[p:p + 1, :] = _stage_tile(jnp.where(kept_p, woff, 0), sel_p,
-                                        BLK_COLS)
+
+    @pl.when(i < nv_ref[0])
+    def _():
+        b = bl_ref[i]
+        x = x_ref[:]                                      # [8, 128]
+        woff = (jax.lax.broadcasted_iota(jnp.int32, (BLK_ROWS, BLK_COLS), 0)
+                * BLK_COLS
+                + jax.lax.broadcasted_iota(jnp.int32, (BLK_ROWS, BLK_COLS),
+                                           1))
+        gidx = b * BLK + woff
+        mask = ((jnp.abs(x) >= t_ref[0])
+                & (gidx >= r_ref[0]) & (gidx < r_ref[1]))
+        pos, raw = _block_prefix(mask.astype(jnp.int32))
+        for p in range(BLK_ROWS):
+            @pl.when(raw > p * BLK_COLS)
+            def _(p=p):
+                kept_p = (mask & (pos >= p * BLK_COLS)
+                          & (pos < (p + 1) * BLK_COLS))
+                sel_p = jnp.where(kept_p, pos - p * BLK_COLS, BLK_COLS)
+                w_ref[p:p + 1, :] = _stage_tile(
+                    jnp.where(kept_p, woff, 0), sel_p, BLK_COLS)
 
 
-def _run_repair(xp, t, rng, bl, novf_cap, interpret, vma):
+def _run_repair(xp, t, rng, bl, novf, novf_cap, interpret, vma):
     """pallas_call wrapper: w_rep [novf_cap * 8, 128] f32 staging pages for
-    the blocks listed in ``bl`` (padded entries re-stage block 0; their
-    rows are never addressed — see ``_materialize_het``)."""
+    the first ``novf`` blocks listed in ``bl``.
+
+    The invariant the kernel's skipping rests on: ``w_rep`` is defined only
+    in the slots below each listed block's survivor count. The page rows of
+    the padded entries (``i >= novf``; they repeat block 0, so the pipeline
+    fetches nothing new for them) and a listed block's slots at or past its
+    count are written back unwritten and hold whatever VMEM held.
+    ``_materialize_het`` and ``_region_counts`` address a block's row below
+    its ``stored_v`` only — that count for a listed block; a padded row is
+    no block's — and mask every other slot they gather
+    (tests/test_compaction.py::TestRepairSkipInvariant poisons the rest).
+    """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    nv = jnp.reshape(novf, (1,)).astype(jnp.int32)
+    if vma:
+        bl, nv = _pvary_to(bl, vma), _pvary_to(nv, vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(novf_cap,),
         in_specs=[pl.BlockSpec((BLK_ROWS, BLK_COLS),
-                               lambda i, t, r, bl: (bl[i], 0))],
+                               lambda i, t, r, bl, nv: (bl[i], 0))],
         out_specs=[pl.BlockSpec((BLK_ROWS, BLK_COLS),
-                                lambda i, t, r, bl: (i, 0))],
+                                lambda i, t, r, bl, nv: (i, 0))],
     )
     (w,) = pl.pallas_call(
         _repair_kernel,
@@ -280,7 +309,7 @@ def _run_repair(xp, t, rng, bl, novf_cap, interpret, vma):
                                              jnp.float32, vma=vma)],
         interpret=interpret,
         name="oktopk_repair",
-    )(t, rng, bl, xp)
+    )(t, rng, bl, nv, xp)
     return w
 
 
@@ -490,8 +519,7 @@ def select_by_threshold_pallas(x: jnp.ndarray, thresh, cap: int,
             return _post(w_f, stored_f, capb_f)
 
         def repair(_):
-            blv = _pvary_to(bl, vma) if vma else bl
-            w_rep = _run_repair(xp, t, rng, blv, ncap, interpret, vma)
+            w_rep = _run_repair(xp, t, rng, bl, novf, ncap, interpret, vma)
             stored_v = jnp.where(matters, raw, stored_f)
             values, indices = _materialize_het(
                 w_f, w_rep, matters, xflat, stored_v[:, None], None,
@@ -610,8 +638,7 @@ def _pack_finalize(xp, xflat, t, rng, bnd, R, cap, nblocks, n, interpret,
             w_f, xflat, c, o, CAPB_FAST, cap, ct, n))
 
     def repair(_):
-        blv = _pvary_to(bl, vma) if vma else bl
-        w_rep = _run_repair(xp, t, rng, blv, ncap, interpret, vma)
+        w_rep = _run_repair(xp, t, rng, bl, novf, ncap, interpret, vma)
         stored_v = jnp.where(ovf, raw, stored_f)
         rank = jnp.cumsum(ovf.astype(jnp.int32)) - ovf
         phys_base = jnp.where(ovf, nblocks * CAPB_FAST + rank * BLK,

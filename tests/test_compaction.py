@@ -12,7 +12,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from oktopk_tpu.ops.compaction import BLK, select_by_threshold_pallas
+from oktopk_tpu.ops.compaction import (BLK, CAPB_FAST, _novf_cap,
+                                       select_by_threshold_pallas)
 from oktopk_tpu.ops.select import select_by_threshold
 
 # `pytest -m kernels` runs the Pallas parity suites standalone during
@@ -25,6 +26,48 @@ def run_both(x, thresh, cap):
                                      interpret=True)[:3]
     want = select_by_threshold(jnp.asarray(x), thresh, cap)
     return [np.asarray(g) for g in got], [np.asarray(w) for w in want]
+
+
+# Survivor counts of the overflowing blocks of one vector, keyed by how many
+# there are (novf): one page and one survivor (129), three pages (300), five
+# (640) and all eight (1,024) — what the repair kernel's page gate splits
+# on. 64 blocks make the repair list eight entries long, so 8 fills it.
+REPAIR_NBLOCKS = 64
+REPAIR_SURVIVORS = {
+    1: (300,),
+    2: (129, 1024),
+    4: (129, 300, 640, 1024),
+    8: (129, 300, 640, 1024, 1024, 640, 300, 129),
+}
+
+
+def overflow_vector(survivors, seed=21):
+    """(x, blocks): ``survivors[i]`` elements of magnitude >= 5 at random
+    places of block ``blocks[i]`` (block 0, which a padded list entry
+    repeats, and the last block among them), three in every other block,
+    the rest under 1: at threshold 1.0 exactly ``len(survivors)`` blocks
+    overflow the fast staging width."""
+    rng = np.random.RandomState(seed)
+    nb = REPAIR_NBLOCKS
+    assert _novf_cap(nb) == 8 and min(survivors) > CAPB_FAST
+    x = (rng.rand(nb, BLK).astype(np.float32) - 0.5)
+    blocks = np.linspace(0, nb - 1, len(survivors)).astype(int)
+    count = np.full(nb, 3)
+    count[blocks] = survivors
+    for b in range(nb):
+        at = rng.choice(BLK, count[b], replace=False)
+        x[b, at] = (5.0 + rng.rand(count[b])) * rng.choice([-1.0, 1.0],
+                                                           count[b])
+    raw = (np.abs(x) >= 1.0).sum(axis=1)
+    assert int((raw > CAPB_FAST).sum()) == len(survivors)
+    return x.reshape(-1), blocks
+
+
+def straddling_bounds(blocks):
+    """Two regions whose boundary lies inside the last overflowing block,
+    past its fast-staged slots."""
+    return np.asarray([0, blocks[-1] * BLK + 700, REPAIR_NBLOCKS * BLK],
+                      np.int32)
 
 
 class TestCompactionParity:
@@ -99,6 +142,23 @@ class TestCompactionParity:
         novf = int(((raw > CAPB_FAST) & (excl + CAPB_FAST < cap)).sum())
         assert 0 < novf <= _novf_cap(64)
         (gv, gi, gc), (wv, wi, wc) = run_both(x, 1.0, cap)
+        assert gc == wc
+        np.testing.assert_array_equal(gi, wi)
+        np.testing.assert_array_equal(gv, wv)
+
+    @pytest.mark.parametrize("novf", sorted(REPAIR_SURVIVORS))
+    def test_repair_branch_pages_and_list_lengths(self, novf):
+        """Repair branch with 1, 2, 4 and exactly ``_novf_cap`` listed
+        blocks holding 129 to 1,024 survivors: the kernel skips the padded
+        list entries and the pages past a block's count."""
+        x, _ = overflow_vector(REPAIR_SURVIVORS[novf])
+        gv, gi, gc, branch = [np.asarray(a) for a in
+                              select_by_threshold_pallas(
+                                  jnp.asarray(x), 1.0, x.size,
+                                  interpret=True)]
+        wv, wi, wc = [np.asarray(a) for a in
+                      select_by_threshold(jnp.asarray(x), 1.0, x.size)]
+        np.testing.assert_array_equal(branch, [1, novf])
         assert gc == wc
         np.testing.assert_array_equal(gi, wi)
         np.testing.assert_array_equal(gv, wv)
@@ -193,6 +253,25 @@ class TestPackRegionsParity:
         np.testing.assert_array_equal(gi, wi)
         np.testing.assert_array_equal(gv, wv)
 
+    @pytest.mark.parametrize("novf", sorted(REPAIR_SURVIVORS))
+    def test_repair_branch_pages_and_list_lengths(self, novf):
+        """As the select test of the same name, through the region
+        finalisation, with the boundary inside an overflowing block."""
+        from oktopk_tpu.ops.compaction import pack_by_region_pallas
+        from oktopk_tpu.ops.select import pack_by_region
+
+        x, blocks = overflow_vector(REPAIR_SURVIVORS[novf])
+        b = jnp.asarray(straddling_bounds(blocks))
+        gv, gi, gc, branch = [np.asarray(a) for a in pack_by_region_pallas(
+            jnp.asarray(x), 1.0, b, 2, x.size // 2, interpret=True)]
+        wv, wi, wc = [np.asarray(a) for a in pack_by_region(
+            jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= 1.0, b, 2,
+            x.size // 2)]
+        np.testing.assert_array_equal(branch, [1, novf])
+        np.testing.assert_array_equal(gc, wc)
+        np.testing.assert_array_equal(gi, wi)
+        np.testing.assert_array_equal(gv, wv)
+
     def test_cap_overflow_per_region(self):
         from oktopk_tpu.ops.compaction import pack_by_region_pallas
         from oktopk_tpu.ops.select import pack_by_region
@@ -208,6 +287,66 @@ class TestPackRegionsParity:
         np.testing.assert_array_equal(gc, wc)
         np.testing.assert_array_equal(gi, wi)
         np.testing.assert_array_equal(gv, wv)
+
+
+class TestRepairSkipInvariant:
+    """What the repair kernel's skipping rests on (``_run_repair``): the
+    consumers of ``w_rep`` read a listed block's row below its survivor
+    count only, so the rows of padded list entries and the slots at or past
+    a count may hold anything."""
+
+    @pytest.mark.parametrize("novf", [2, 4])
+    def test_unaddressed_slots_may_hold_anything(self, novf):
+        from oktopk_tpu.ops.compaction import (
+            BLK_COLS, _materialize_het, _prep, _region_counts, _run_repair,
+            _run_stage, _vma_of)
+        from oktopk_tpu.ops.select import pack_by_region
+
+        x, blocks = overflow_vector(REPAIR_SURVIVORS[novf])
+        R, cap = 2, x.size // 2
+        bnd = jnp.asarray(straddling_bounds(blocks))
+        xp, xflat, t, rng, n, nb = _prep(jnp.asarray(x), 1.0, None, None)
+        vma = _vma_of(xp)
+        w_f, stored_f, raw = _run_stage(xp, t, rng, CAPB_FAST, nb, True, vma)
+        ovf = raw > CAPB_FAST
+        ncap = _novf_cap(nb)
+        bl = jnp.nonzero(ovf, size=ncap, fill_value=0)[0].astype(jnp.int32)
+        w_rep = _run_repair(xp, t, rng, bl, jnp.sum(ovf), ncap, True, vma)
+
+        # NaN wherever the invariant says nobody looks
+        listed = np.arange(ncap) < novf
+        count = np.where(listed, np.asarray(raw)[np.asarray(bl)], 0)
+        dead = np.arange(BLK)[None, :] >= count[:, None]   # [ncap, 1024]
+        assert dead[~listed].all() and not dead[listed].all()
+        poisoned = np.where(dead, np.nan,
+                            np.asarray(w_rep).reshape(ncap, BLK))
+        poisoned = jnp.asarray(poisoned.reshape(-1, BLK_COLS), jnp.float32)
+
+        def finalize(w_rep):
+            # _pack_finalize's repair branch, from w_rep on
+            stored_v = jnp.where(ovf, raw, stored_f)
+            rank = jnp.cumsum(ovf.astype(jnp.int32)) - ovf
+            phys_base = jnp.where(
+                ovf, nb * CAPB_FAST + rank * BLK,
+                jnp.arange(nb, dtype=jnp.int32) * CAPB_FAST)
+            stage_all = jnp.concatenate([w_f.reshape(-1),
+                                         w_rep.reshape(-1)])
+            cnt_rb = _region_counts(stage_all, phys_base, stored_v, BLK,
+                                    bnd, R, nb)
+            off_rb = jnp.cumsum(cnt_rb, axis=1) - cnt_rb
+            counts = jnp.minimum(jnp.sum(cnt_rb, axis=0), cap)
+            values, indices = _materialize_het(
+                w_f, w_rep, ovf, xflat, cnt_rb, off_rb, CAPB_FAST, cap,
+                counts, n)
+            return [np.asarray(a) for a in (values, indices, counts)]
+
+        want = [np.asarray(a) for a in pack_by_region(
+            jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= 1.0, bnd, R, cap)]
+        for nm, clean, dirty, w in zip(("values", "indices", "counts"),
+                                       finalize(w_rep), finalize(poisoned),
+                                       want):
+            np.testing.assert_array_equal(dirty, clean, err_msg=nm)
+            np.testing.assert_array_equal(dirty, w, err_msg=nm)
 
 
 def _run_oktopk_both_paths(mesh8, cfg0, base, steps, check_vma=None):

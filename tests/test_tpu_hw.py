@@ -30,6 +30,12 @@ from oktopk_tpu.ops.compaction import (  # noqa: E402
     mesh_supports_pallas, pack_by_region_pallas, select_by_threshold_pallas)
 from oktopk_tpu.ops.select import pack_by_region, select_by_threshold  # noqa: E402
 
+from test_compaction import (  # noqa: E402
+    REPAIR_SURVIVORS, overflow_vector, straddling_bounds)
+from test_fused_select import (  # noqa: E402
+    assert_all_equal as fused_assert_all_equal, both_forms,
+    run_both as fused_run_both)
+
 
 @pytest.fixture(scope="module")
 def tpu_dev():
@@ -138,31 +144,23 @@ def test_mesh_supports_pallas_on_hw(tpu_dev):
     assert mesh_supports_pallas(mesh)
 
 
-def test_fused_select_parity_on_chip(tpu_dev):
+@both_forms
+def test_fused_select_parity_on_chip(tpu_dev, with_hist):
     """Mirror of tests/test_fused_select.py fast-branch parity on silicon:
     the fused residual+select+stage kernel (ops/fused_select.py) compiled
     through Mosaic must reproduce the portable separate-pass outputs —
     acc, staged regions, realised count, unclamped probe count, and the
-    MXU one-hot histogram — bit-for-bit."""
-    from oktopk_tpu.ops.fused_select import (fused_select_pallas,
-                                             fused_select_reference)
-
+    MXU one-hot histogram — bit-for-bit; and the form without the
+    histogram output everything else, staging rows and branch included
+    (``run_both``)."""
     rng = np.random.RandomState(21)
     n = 1 << 18
     g = rng.randn(n).astype(np.float32)
     r = (0.1 * rng.randn(n)).astype(np.float32)
-    bounds = np.array([0, n // 3, n], np.int32)
     with jax.default_device(tpu_dev):
-        got = fused_select_pallas(jnp.asarray(g), jnp.asarray(r), 2.0, 2.5,
-                                  jnp.asarray(bounds), 2, 4096,
-                                  interpret=False)
-        got = [np.asarray(a) for a in got]
-    want = [np.asarray(a) for a in
-            fused_select_reference(jnp.asarray(g), jnp.asarray(r), 2.0, 2.5,
-                                   jnp.asarray(bounds), 2, 4096)]
-    for nm, a, b in zip(("acc", "values", "indices", "counts",
-                         "local_count", "probe_count", "hist"), got, want):
-        np.testing.assert_array_equal(a, b, err_msg=nm)
+        got, want = fused_run_both(g, r, 2.0, [0, n // 3, n], 2, 4096,
+                                   with_hist, interpret=False)
+    fused_assert_all_equal(got, want)
 
 
 def test_fused_hist_bins_bitcast_on_chip(tpu_dev):
@@ -187,15 +185,14 @@ def test_fused_hist_bins_bitcast_on_chip(tpu_dev):
     np.testing.assert_array_equal(hist, np.asarray(log2_hist(jnp.asarray(g))))
 
 
-def test_fused_repair_branch_parity_on_chip(tpu_dev):
+@both_forms
+def test_fused_repair_branch_parity_on_chip(tpu_dev, with_hist):
     """Mirror of tests/test_fused_select.py::test_repair_branch on silicon:
     scattered dense blocks overflow CAPB_FAST so the shared _pack_finalize
     repair kernel re-stages them from the FUSED kernel's own acc output —
     the handoff between the fused staging layout and the repair path under
-    Mosaic."""
+    Mosaic, in both forms of the fused kernel."""
     from oktopk_tpu.ops.compaction import BLK, CAPB_FAST, _novf_cap
-    from oktopk_tpu.ops.fused_select import (fused_select_pallas,
-                                             fused_select_reference)
 
     rng = np.random.RandomState(23)
     n = 64 * BLK
@@ -205,18 +202,39 @@ def test_fused_repair_branch_parity_on_chip(tpu_dev):
     r = (0.01 * rng.randn(n)).astype(np.float32)
     raw = (np.abs(g + r).reshape(-1, BLK) >= 1.0).sum(axis=1)
     assert 0 < int((raw > CAPB_FAST).sum()) <= _novf_cap(64)
-    bounds = np.array([0, n // 2, n], np.int32)
     with jax.default_device(tpu_dev):
-        got = fused_select_pallas(jnp.asarray(g), jnp.asarray(r), 1.0, 1.25,
-                                  jnp.asarray(bounds), 2, 8 * BLK,
-                                  interpret=False)
-        got = [np.asarray(a) for a in got]
-    want = [np.asarray(a) for a in
-            fused_select_reference(jnp.asarray(g), jnp.asarray(r), 1.0, 1.25,
-                                   jnp.asarray(bounds), 2, 8 * BLK)]
-    for nm, a, b in zip(("acc", "values", "indices", "counts",
-                         "local_count", "probe_count", "hist"), got, want):
-        np.testing.assert_array_equal(a, b, err_msg=nm)
+        got, want = fused_run_both(g, r, 1.0, [0, n // 2, n], 2, 8 * BLK,
+                                   with_hist, interpret=False)
+    fused_assert_all_equal(got, want)
+
+
+@pytest.mark.parametrize("novf", sorted(REPAIR_SURVIVORS))
+@pytest.mark.parametrize("form", ["select", "pack"])
+def test_repair_pages_and_list_lengths_on_chip(tpu_dev, form, novf):
+    """Mirror of tests/test_compaction.py::test_repair_branch_pages_and_
+    list_lengths (both classes) on silicon: the repair kernel's two gates
+    under Mosaic — grid steps at or past the live count skipped (1, 2, 4
+    and all 8 list entries live), pages past a block's survivor count not
+    staged (129, 300, 640 and 1,024 survivors). The skipped rows hold what
+    VMEM held; the results may not show it."""
+    x, blocks = overflow_vector(REPAIR_SURVIVORS[novf])
+    xj = jnp.asarray(x)
+    with jax.default_device(tpu_dev):
+        if form == "select":
+            *got, branch = select_by_threshold_pallas(xj, 1.0, x.size,
+                                                      interpret=False)
+            want = select_by_threshold(xj, 1.0, x.size)
+        else:
+            bnd = jnp.asarray(straddling_bounds(blocks))
+            *got, branch = pack_by_region_pallas(xj, 1.0, bnd, 2,
+                                                 x.size // 2,
+                                                 interpret=False)
+            want = pack_by_region(xj, jnp.abs(xj) >= 1.0, bnd, 2,
+                                  x.size // 2)
+        np.testing.assert_array_equal(np.asarray(branch), [1, novf])
+        for nm, a, b in zip(("values", "indices", "counts"), got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=nm)
 
 
 def test_pack_wide_branch_parity_on_chip(tpu_dev):
