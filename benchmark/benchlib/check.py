@@ -149,9 +149,10 @@ def _sub(a, b):
 
 
 def worker_grads(ref, spec, opt, precision: Optional[str]):
-    """A function (params, global batch, workers, step key) -> the workers'
-    losses and gradients, each on its own rows, clipped where the
-    configuration clips, as the optimizer's collective gets them."""
+    """A function (params, global batch, workers, step key, worker) -> that
+    worker's loss and gradient on its own rows, clipped where the
+    configuration clips, as the optimizer's collective gets it. One worker
+    a call, so that the caller holds no more gradients than it needs."""
     clip = opt.get("grad_clip")
 
     def one(params, batch, extra):
@@ -164,18 +165,28 @@ def worker_grads(ref, spec, opt, precision: Optional[str]):
 
     jitted = jax.jit(one)
 
-    def all_workers(params, batch, workers: int, skey):
+    def one_worker(params, batch, workers: int, skey, w: int):
         rows = len(next(iter(batch.values()))) // workers
-        out = []
         with jax.default_matmul_precision(precision or "default"):
-            for w in range(workers):
-                shard = {k: jnp.asarray(v[w * rows:(w + 1) * rows])
-                         for k, v in batch.items()}
-                extra = ref.extras(spec, shard, worker_key(skey, w))
-                out.append(jitted(params, shard, extra))
-        return out
+            shard = {k: jnp.asarray(v[w * rows:(w + 1) * rows])
+                     for k, v in batch.items()}
+            extra = ref.extras(spec, shard, worker_key(skey, w))
+            return jitted(params, shard, extra)
 
-    return all_workers
+    return one_worker
+
+
+def _host(x) -> np.ndarray:
+    return np.asarray(jax.device_get(x))
+
+
+def _accumulate(total: List[Any], new: List[Any]) -> None:
+    """``total[i] += new[i]``, a leaf at a time; ``new`` is emptied as it
+    is read, so that a leaf's two terms and its sum are all that is held
+    beside the two lists."""
+    for i in range(len(total)):
+        total[i] = jnp.add(total[i], new[i])
+        new[i] = None
 
 
 def follow(ref, spec, opt, batches, workers: int, key0, snaps: Snapshots,
@@ -185,28 +196,52 @@ def follow(ref, spec, opt, batches, workers: int, key0, snaps: Snapshots,
     chaotic: a copy that runs free parts from the program by rounding
     alone, and then measures the chaos). Returns the losses, what the
     optimizer got at step 1, and the sum of the three changes of the
-    parameters."""
+    parameters.
+
+    What it holds on the device (the reference runs beside the program's
+    own state, and a model that fills the chip leaves little room): the
+    parameters of the step in hand and the running sum of the workers'
+    gradients, two parameter-sized trees at every gradient call, a third
+    (the call's own output) until it is added in. The optimizer's algebra
+    then runs a leaf at a time, each result taken to the host as it is
+    made and each term freed as it is read: float32 on the device, the
+    same operations in the same order as on whole trees."""
     lr, m, wd = float(opt["lr"]), float(opt["momentum"]), float(
         opt["weight_decay"])
-    grads = worker_grads(ref, spec, opt, precision)
+    one_worker = worker_grads(ref, spec, opt, precision)
     losses, d1, change = [], None, None
+    treedef = jax.tree.structure(snaps.states[0][0])
     for t, (batch, skey) in enumerate(
             zip(batches, step_keys(key0, len(batches))), start=1):
-        p, buf = jax.tree.map(jnp.asarray, snaps.states[t - 1])
-        per = grads(p, batch, workers, skey)
-        losses.append(sum(float(l) for l, _ in per) / workers)
-        gsum = per[0][1]
-        for _, g in per[1:]:
-            gsum = jax.tree.map(jnp.add, gsum, g)
-        ghat = jax.tree.map(lambda x: x / workers, gsum)
-        d = jax.tree.map(lambda g, x: g + wd * x, ghat, p)
-        step = jax.tree.map(lambda b, x: m * b + x, buf, d) if m else d
+        p_host, buf_host = snaps.states[t - 1]
+        p = jax.tree.map(jnp.asarray, p_host)
+        loss, gsum = 0, None
+        for w in range(workers):
+            l, g = one_worker(p, batch, workers, skey, w)
+            loss += float(l)
+            if gsum is None:
+                gsum = jax.tree.leaves(g)
+            else:
+                _accumulate(gsum, jax.tree.leaves(g))
+            del g
+        losses.append(loss / workers)
+        p = jax.tree.leaves(p)
+        buf = jax.tree.leaves(buf_host) if m else None
+        d_host, delta_host = [], []
+        for i in range(len(p)):
+            d = gsum[i] / workers + wd * p[i]
+            gsum[i] = p[i] = None
+            if t == 1:
+                d_host.append(_host(d))
+            delta = -lr * (m * jnp.asarray(buf[i]) + d if m else d)
+            if change is not None:
+                delta = jnp.add(jnp.asarray(change[i]), delta)
+            delta_host.append(_host(delta))
+        del d, delta    # a leaf each: the next gradient call need not find them
+        change = delta_host
         if t == 1:
-            d1 = jax.device_get(d)
-        delta = jax.tree.map(lambda b: -lr * b, step)
-        change = delta if change is None else jax.tree.map(
-            jnp.add, change, delta)
-    return losses, d1, jax.device_get(change)
+            d1 = jax.tree.unflatten(treedef, d_host)
+    return losses, d1, jax.tree.unflatten(treedef, change)
 
 
 def compare(ref, spec, opt, batches, workers: int, key0, snaps: Snapshots,
@@ -259,20 +294,27 @@ def recovered_ghat(before, after, opt):
 def compare_exchange(ref, spec, opt, workers: int, key0,
                      snap: ExchangeSnapshot, precision: Optional[str]):
     """``benchlib/exchange.py``'s numbers for the step of ``snap``, the
-    worst over its buckets, and the count of capacities passed."""
+    worst over its buckets, and the count of capacities passed. Each
+    worker's gradient goes to the host as it is made (the parameters are
+    all that the gradient calls find on the device), and comes back a
+    bucket at a time."""
     from benchlib import exchange
     skey = step_keys(key0, snap.step_index + 1)[-1]
-    per = worker_grads(ref, spec, opt, precision)(
-        jax.tree.map(jnp.asarray, snap.before[0]), snap.batch, workers, skey)
-    grads = [jax.tree.leaves(g) for _, g in per]
+    one_worker = worker_grads(ref, spec, opt, precision)
+    p = jax.tree.map(jnp.asarray, snap.before[0])
+    grads = []
+    for w in range(workers):
+        g = jax.tree.leaves(one_worker(p, snap.batch, workers, skey, w)[1])
+        grads.append([_host(g.pop(0)) for _ in range(len(g))])
+    del p
     theirs = jax.tree.leaves(recovered_ghat(snap.before, snap.after, opt))
     numbers: Dict[str, float] = {}
     over = 0
     for b in snap.buckets:
-        flat = lambda leaves: jnp.concatenate(
-            [jnp.asarray(leaves[i], jnp.float32).ravel() for i in b.leaves])
-        acc = jnp.stack([flat(g) for g in grads]) + jnp.asarray(
-            b.residual_before)
+        flat = lambda leaves: np.concatenate(
+            [np.asarray(leaves[i], np.float32).ravel() for i in b.leaves])
+        acc = jnp.stack([jnp.asarray(flat(g)) + jnp.asarray(r)
+                         for g, r in zip(grads, b.residual_before)])
         lt = (np.asarray(b.local_threshold, np.float32)
               * np.asarray(b.drift, np.float32))
         gt = (np.asarray(b.global_threshold, np.float32)
@@ -280,6 +322,7 @@ def compare_exchange(ref, spec, opt, workers: int, key0,
         got, o = exchange.compare(
             flat(theirs), b.residual_after, acc, lt, gt, b.boundaries,
             snap.wire_dtype, b.cap_pair, b.cap_gather)
+        del acc
         over += o
         for k, v in got.items():
             numbers[k] = max(v, numbers.get(k, 0.0))

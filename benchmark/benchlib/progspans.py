@@ -118,24 +118,13 @@ def program_snapshot(ctx) -> dict:
 
 
 def step_runs(trace: xtrace.Trace, steps: List[Span]):
-    """Executions of the step program on the first chip: of the programs
-    of ``XLA Modules``, the one that took most of the window (the key
-    split runs as often as the step, so a count cannot tell them apart).
-    In the sandbox's stand-in (no line of programs) the extent of the
-    operations that start under each ``oktopk/step`` span or after it, up
-    to the next."""
+    """Executions of the step program on the first chip
+    (``xtrace.Chip.step_runs``). In the sandbox's stand-in (no line of
+    programs) the extent of the operations that start under each
+    ``oktopk/step`` span or after it, up to the next."""
     chip = trace.chips[0]
-    lo, hi = trace.window
-    took: Dict[str, float] = {}
-    for name, s, e in chip.modules:
-        if lo <= s < hi:
-            took[name] = took.get(name, 0.0) + e - s
-    if took:
-        top = max(took, key=took.get)
-        return [(s, e) for name, s, e in chip.modules
-                if name == top and lo <= s < hi]
-    runs = []
-    if not steps:
+    runs = chip.step_runs(trace.window)
+    if runs or not steps:
         return runs
     edges = [s.start for s in steps] + [trace.window[1]]
     for lo, hi in zip(edges, edges[1:]):

@@ -4,7 +4,9 @@ The program and the plain reference are both handed these, so the
 reference takes nothing that the program made. The rule is by a leaf's
 name, as the flax parameter tree spells it: a ``kernel`` is normal with
 variance 1/fan_in (fan_in = all axes but the last), an ``embedding``
-normal with variance 1/width, a ``scale`` ones, a ``bias`` zeros.
+normal with variance 1/width, a ``scale`` ones, a ``bias`` zeros; and
+``experts``, a stack of kernels with the expert axis first, is normal with
+variance 1/shape[-2], each expert's own fan_in.
 """
 
 from __future__ import annotations
@@ -20,6 +22,8 @@ def _leaf(path, shape, dtype, key):
     if name == "kernel":
         std = 1.0 / math.sqrt(max(1, math.prod(shape[:-1])))
         return std * jax.random.normal(key, shape, dtype)
+    if name == "experts":
+        return jax.random.normal(key, shape, dtype) / math.sqrt(shape[-2])
     if name == "embedding":
         return jax.random.normal(key, shape, dtype) / math.sqrt(shape[-1])
     if name == "scale":

@@ -108,16 +108,19 @@ class Chip:
     async_collectives: List[Op] = dataclasses.field(default_factory=list)
 
     def step_runs(self, window) -> List[Tuple[float, float]]:
-        """Executions, inside the window, of the program that ran most
-        often there: the train step. Without a line of programs, the
-        top-level ops stand in."""
+        """Executions, inside the window, of the program that took most
+        of it: the train step (the key split runs as often, so a count
+        cannot tell the two apart). Empty without a line of programs."""
         lo, hi = window
-        inside = [(n, s, e) for n, s, e in self.modules if lo <= s < hi]
-        if not inside:
+        took: Dict[str, float] = {}
+        for name, s, e in self.modules:
+            if lo <= s < hi:
+                took[name] = took.get(name, 0.0) + e - s
+        if not took:
             return []
-        names = [n for n, _, _ in inside]
-        top = max(set(names), key=names.count)
-        return [(s, e) for n, s, e in inside if n == top]
+        top = max(took, key=took.get)
+        return [(s, e) for name, s, e in self.modules
+                if name == top and lo <= s < hi]
 
     def leaves(self, pred: Callable[[Op], bool] = lambda o: True) -> List[Op]:
         return [o for o in self.ops if not o.container and pred(o)]

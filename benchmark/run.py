@@ -220,6 +220,10 @@ def main(argv=None) -> int:
         result["breakdown"] = trace.breakdown()
     if args.rehearse:
         result["rehearsal"] = True
+    # the driver's record of a run that is not correct keeps the end of
+    # standard error: each number beside its limit goes there too, last
+    print("\n".join(tag + line for line in lines), file=sys.stderr,
+          flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -40,7 +40,9 @@ def short_settling(monkeypatch):
         lambda self, name: dict(real(self, name), settle_steps=SETTLE))
 
 
-def numbers(cell, seed, over=None, window_steps=0):
+def settled(cell, seed, over=None):
+    """The harness of ``cell`` at the rehearsal's sizes, past its first
+    three steps and its settling; with the files it was built from."""
     bench = discover.Bench()
     if isinstance(cell, str):
         cell = bench.cell(cell)
@@ -52,6 +54,11 @@ def numbers(cell, seed, over=None, window_steps=0):
     h.seed_state(seed)
     h.first_steps()
     h.settle()
+    return bench, config, h
+
+
+def numbers(cell, seed, over=None, window_steps=0):
+    bench, config, h = settled(cell, seed, over)
     win = h.window(0.0, max_steps=window_steps) if window_steps else None
     return h.numbers(bench.reference(config["reference"]), win,
                      config.get("reference_precision"))
@@ -63,6 +70,7 @@ def test_reference_agrees_with_the_program(cell):
     got = numbers(cell, seed=2 ** 31 + 7)
     sparse = "delivered_gap" in got
     assert sparse == (cell is X4 or "oktopk" in str(cell))
+    assert ("replica_gap" in got) == (cell is X4)
     for name, limit in dict(CPU_SOUND, **(CPU_SOUND_EXCHANGE if sparse
                                           else {})).items():
         assert got[name] < limit, (name, got)
@@ -202,3 +210,74 @@ def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch, fault):
                          "half_the_batch": half_the_batch}[fault])
     rc, result, out = run_main(capsys, "vgg16_dense_x1")
     assert rc == 0 and result["correct"] is False, out
+
+
+@pytest.fixture
+def scratch_x4(monkeypatch):
+    """``X4`` as a cell that ``run.py`` finds: no cell of BENCHMARK.json
+    has four workers, so the scratch cell borrows the sparse cell's limits
+    and holds its replicas equal."""
+    cell, limits = discover.Bench.cell, discover.Bench.limits
+    monkeypatch.setattr(
+        discover.Bench, "cell",
+        lambda self, name: X4 if name == X4["name"] else cell(self, name))
+    monkeypatch.setattr(
+        discover.Bench, "limits",
+        lambda self, name: dict(limits(self, "lstm_ptb_oktopk_x1"),
+                                replica_gap=0.0)
+        if name == X4["name"] else limits(self, name))
+
+
+FAULTS_X4 = ["dropped_payload", "replica_off_by_an_ulp",
+             "a_workers_rows_left_out"]
+
+
+@pytest.mark.parametrize("fault", ["none"] + FAULTS_X4)
+def test_a_broken_path_on_four_workers_is_not_correct(capsys, monkeypatch,
+                                                      scratch_x4, fault):
+    """The rest of a run on four workers, sound and then with the path
+    broken underneath: one worker's values zeroed on the wire, one
+    replica's parameters an ulp off after a step, the last worker's rows
+    replaced by the first's."""
+    from oktopk_tpu.train import trainer as trainer_mod
+    real = trainer_mod.Trainer.train_step
+    state = {"steps": 0}
+
+    def replica_off_by_an_ulp(self, batch):
+        metrics = real(self, batch)
+        state["steps"] += 1
+        if state["steps"] == 5:
+            def nudge(x):
+                shards = [np.asarray(s.data) for s in x.addressable_shards]
+                shards[1] = np.nextafter(shards[1], np.float32(np.inf))
+                return jax.make_array_from_single_device_arrays(
+                    x.shape, x.sharding,
+                    [jax.device_put(a, s.device) for a, s in
+                     zip(shards, x.addressable_shards)])
+            leaves, treedef = jax.tree.flatten(self.state.params)
+            leaves[0] = nudge(leaves[0])
+            self.state = self.state.replace(
+                params=jax.tree.unflatten(treedef, leaves))
+        return metrics
+
+    def a_workers_rows_left_out(self, batch):
+        rows = len(batch["label"]) // 4
+        return real(self, {k: np.concatenate([v[:3 * rows], v[:rows]])
+                           for k, v in batch.items()})
+
+    want = {"none": None, "dropped_payload": "delivered_gap",
+            "replica_off_by_an_ulp": "replica_gap",
+            "a_workers_rows_left_out": "_gap"}[fault]
+    if fault == "dropped_payload":
+        from oktopk_tpu.collectives import wire
+        monkeypatch.setattr(wire, "_WIRE_FAULT", drop_one_workers_payload)
+    elif fault != "none":
+        monkeypatch.setattr(trainer_mod.Trainer, "train_step", locals()[fault])
+    rc, result, out = run_main(capsys, X4["name"], "1")
+    bad = [l for l in out if "NOT ok" in l]
+    if fault == "none":
+        assert rc == 0 and result["correct"] is True, out
+        assert any("replica_gap" in l for l in out) and not bad, out
+        return
+    assert rc == 0 and result["correct"] is False, out
+    assert any(want in l for l in bad), bad

@@ -132,3 +132,25 @@ def test_breakdown_names_kernels_and_labels_gaps():
     assert gaps["none"] == pytest.approx(ms)        # 7-8 ms, no host span
     assert intervals.length([(o.start, o.end) for o in ops]) == pytest.approx(
         5 * ms)
+
+
+def test_the_step_program_is_picked_by_time_not_by_count():
+    """The key split runs once a step too, a few microseconds each, and a
+    third program once: the step is the one that took most of the window,
+    whatever the order the names come in."""
+    ms = 1e-3
+    for first in ("jit__threefry_split", "jit_step"):
+        second = ({"jit__threefry_split", "jit_step"} - {first}).pop()
+        span = lambda name, s: (name, s, s + (100 * ms if name == "jit_step"
+                                              else 0.005 * ms))
+        modules = [span(n, i * 120 * ms + (0 if n == first else 101 * ms))
+                   for i in range(4) for n in (first, second)]
+        modules.append(("jit_other", 490 * ms, 495 * ms))
+        chip = xtrace.Chip("c", [], modules)
+        runs = chip.step_runs((0.0, 500 * ms))
+        assert len(runs) == 4, first
+        assert all(e - s == pytest.approx(100 * ms) for s, e in runs)
+        assert xtrace.Chip("c", []).step_runs((0.0, 1.0)) == []
+        # an execution that starts before the window is not of it
+        assert len(chip.step_runs((50 * ms, 500 * ms))) == (
+            3 if first == "jit_step" else 4)
