@@ -25,8 +25,9 @@ from oktopk_tpu.config import OkTopkConfig
 # else over the buckets, then the largest over the workers (the step is as
 # slow as its slowest worker: one chip in the wide branch holds all of
 # them), and appends the realised counts of the ``local_k``/``global_k``
-# metrics. A branch entry holds the place of its name in ``BRANCHES``
-# (ops/compaction.py); a dense step leaves everything it does not do at 0.
+# metrics, then ``MODEL_COUNTERS``. A branch entry holds the place of its
+# name in ``BRANCHES`` (ops/compaction.py); a dense step leaves everything
+# it does not do at 0.
 BRANCHES = ("fast", "repair", "wide")
 BRANCH_COUNTERS = (
     "stage_branch",            # staging (phase a) overflow dispatch
@@ -37,7 +38,14 @@ BRANCH_COUNTERS = (
     "recompute_global",        # phase (b) took the exact branch
     "repartition",             # region boundaries recomputed
 )
-COUNTERS = BRANCH_COUNTERS + ("local_k", "global_k")
+# What the model did, from the loss function's ``aux["counters"]`` (zeros
+# for a model that reports none): a name that ends in ``_max`` is the
+# largest over micro-steps and workers, every other the sum.
+MODEL_COUNTERS = (
+    "expert_rows",      # token-expert pairs computed at held experts
+    "expert_rows_max",  # ... at the busiest held expert of any layer
+)
+COUNTERS = BRANCH_COUNTERS + ("local_k", "global_k") + MODEL_COUNTERS
 
 
 @flax.struct.dataclass

@@ -212,10 +212,12 @@ def load_ptb(path: str, split: str = "train", num_steps: int = 35):
 def make_dataset(dataset: str, dnn: str, batch_size: int,
                  path: Optional[str] = None, split: str = "train",
                  seed: int = 0,
-                 seq_len: Optional[int] = None) -> Tuple[Iterator, Dict]:
+                 seq_len: Optional[int] = None,
+                 vocab: Optional[int] = None) -> Tuple[Iterator, Dict]:
     """Build a batch iterator for (dataset, dnn). Falls back to synthetic
     data when files are absent. ``seq_len`` overrides the per-model default
-    token length (BERT long-context runs)."""
+    token length (BERT long-context runs); ``vocab`` the vocabulary that
+    synthetic tokens are drawn from (a model built over a slice of it)."""
     path = path or os.environ.get("OKTOPK_DATA_DIR", "./data")
     try:
         if dataset == "wikipedia":
@@ -280,5 +282,6 @@ def make_dataset(dataset: str, dnn: str, batch_size: int,
                 {"synthetic": False,
                  "num_examples": len(arrays["label"])})
     except (FileNotFoundError, OSError):
-        return (synthetic_iterator(dnn, batch_size, seed, seq_len=seq_len),
+        return (synthetic_iterator(dnn, batch_size, seed, seq_len=seq_len,
+                                   vocab=vocab),
                 {"synthetic": True, "num_examples": 50000})

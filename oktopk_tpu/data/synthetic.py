@@ -10,10 +10,14 @@ import numpy as np
 
 
 def synthetic_batch(dnn: str, batch_size: int, rng: np.random.RandomState,
-                    seq_len: int = None) -> Dict[str, np.ndarray]:
-    if dnn in ("lstm", "lstm_tiny"):
-        t = seq_len or 35
-        vocab = 1024 if dnn == "lstm_tiny" else 10000
+                    seq_len: int = None,
+                    vocab: int = None) -> Dict[str, np.ndarray]:
+    """``vocab`` overrides a token language model's vocabulary (a model
+    built over a slice of it, by ``model_kwargs``)."""
+    from oktopk_tpu.models.registry import TOKEN_LMS
+    if dnn in TOKEN_LMS:
+        t = seq_len or TOKEN_LMS[dnn][0]
+        vocab = vocab or TOKEN_LMS[dnn][1]
         # Bigram-structured sequences (fixed random successor table, 10%
         # uniform noise): uniform-random tokens carry no learnable signal
         # beyond rote memorization, which makes LM loss curves useless for
@@ -89,10 +93,11 @@ def synthetic_batch(dnn: str, batch_size: int, rng: np.random.RandomState,
 
 
 def synthetic_iterator(dnn: str, batch_size: int, seed: int = 0,
-                       seq_len: int = None) -> Iterator[Dict[str, np.ndarray]]:
+                       seq_len: int = None,
+                       vocab: int = None) -> Iterator[Dict[str, np.ndarray]]:
     rng = np.random.RandomState(seed)
     while True:
-        yield synthetic_batch(dnn, batch_size, rng, seq_len)
+        yield synthetic_batch(dnn, batch_size, rng, seq_len, vocab)
 
 
 def finite_pool_iterator(dnn: str, batch_size: int, num_examples: int = 256,

@@ -14,6 +14,7 @@ from oktopk_tpu.models.densenet import DenseNet
 from oktopk_tpu.models.preresnet import PreResNet
 from oktopk_tpu.models.resnext import ResNeXt
 from oktopk_tpu.models.bert import BertConfig, BertForPreTraining
+from oktopk_tpu.models.deepseek_v2 import DeepseekV2, DeepseekV2Config
 from oktopk_tpu.models.deepspeech import DeepSpeech
 from oktopk_tpu.models.imagenet_resnet import ResNet50
 from oktopk_tpu.models.lstm import PTBLSTM
@@ -28,6 +29,18 @@ def _img(h, w, c):
 
 def _tokens(t, vocab):
     return lambda bs: jnp.zeros((bs, t), jnp.int32)
+
+
+# Token language models (next-token cross-entropy over ``tokens`` /
+# ``targets`` [B, T]; ``apply`` returns the logits first): the sequence
+# length and vocabulary of their data. The trainer's one branch for the
+# family and the synthetic data both read this.
+TOKEN_LMS: Dict[str, Tuple[int, int]] = {
+    "lstm": (35, 10000),
+    "lstm_tiny": (35, 1024),
+    "deepseek_v2_lite": (4096, 102400),
+    "deepseek_v2_tiny": (64, 512),
+}
 
 
 MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
@@ -46,7 +59,7 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
                                _img(32, 32, 3)),
     "caffe_cifar": lambda **kw: (CaffeCifar(**kw), _img(32, 32, 3)),
     "mnistnet": lambda **kw: (MnistNet(**kw), _img(28, 28, 1)),
-    "lstm": lambda **kw: (PTBLSTM(**kw), _tokens(35, 10000)),
+    "lstm": lambda **kw: (PTBLSTM(**kw), _tokens(*TOKEN_LMS["lstm"])),
     # CPU-mesh-sized PTB LSTM (convergence evidence for the LSTM family,
     # the role bert_tiny plays for BERT). No dropout: the convergence probe
     # memorizes a finite pool, where the reference's keep=0.35 (applied
@@ -55,7 +68,16 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
     "lstm_tiny": lambda **kw: (
         PTBLSTM(**{"vocab_size": 1024, "hidden_size": 192,
                    "dropout_keep": 1.0, **kw}),
-        _tokens(35, 1024)),
+        _tokens(*TOKEN_LMS["lstm_tiny"])),
+    # DeepSeek-V2-Lite at its published config.json; ``held_experts`` (and,
+    # for one chip's share of a job, ``num_hidden_layers``/``vocab_size``)
+    # come as model_kwargs. No parameter's shape follows the sequence
+    # length, so the example that initialises it is short.
+    "deepseek_v2_lite": lambda **kw: (
+        DeepseekV2(DeepseekV2Config(**kw)), _tokens(64, 102400)),
+    "deepseek_v2_tiny": lambda **kw: (
+        DeepseekV2(DeepseekV2Config.tiny(**kw)),
+        _tokens(*TOKEN_LMS["deepseek_v2_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

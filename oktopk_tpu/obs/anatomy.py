@@ -56,7 +56,7 @@ SCOPE_PREFIX = "anat"
 # the phase vocabulary of the collectives pipeline, in pipeline order
 PHASES = ("fwd_bwd", "select", "stage", "exchange", "combine", "optimizer")
 
-# Named steps of the algorithm inside ``select`` and ``stage``: plain
+# Named steps of the algorithm inside ``select``, ``stage``: plain
 # ``jax.named_scope``s UNDER a phase frame (``anat/b000/select/threshold``),
 # not contract frames of their own, so ``parse_scope`` and every reader of
 # the phase go on answering ``select`` / ``stage`` for the ops inside.
@@ -67,9 +67,13 @@ SUB_REPARTITION = "repartition"  # stage: region boundaries
 SUB_FINALIZE = "finalize"        # stage: census, prefix, branch, gathers
 SUB_GLOBAL = "global"            # select: phase-(b) winner selection
 SUB_FEEDBACK = "feedback"        # select: controller feedback
+# ... and inside ``fwd_bwd``, entered by the model itself
+# (models/deepseek_v2.py), so forward, recomputed and backward operations
+# alike carry them: a model that enters none leaves the phase unscoped.
 SUB_SCOPES = {
     "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
     "stage": (SUB_REPARTITION, SUB_FINALIZE),
+    "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head"),
 }
 
 # phases whose time is wire time; everything else in the contract is

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from oktopk_tpu.collectives.registry import get_algorithm
 from oktopk_tpu.collectives.state import (
     BRANCH_COUNTERS,
+    MODEL_COUNTERS,
     SparseState,
     init_state,
 )
@@ -291,28 +292,39 @@ def build_sparse_grad_step(
         rng = jax.random.fold_in(rng, lax.axis_index(axis_name))
 
         # --- local grads, with optional microbatch accumulation ---
+        # MODEL_COUNTERS: what the model says it did, where it says so
+        counts_max = jnp.asarray([nm.endswith("_max")
+                                  for nm in MODEL_COUNTERS])
+
         def micro(carry, mb):
-            acc_grads, acc_loss, model_state, rng = carry
+            acc_grads, acc_loss, model_state, rng, counts = carry
             rng, sub = jax.random.split(rng)
-            (loss, (model_state, _)), grads = jax.value_and_grad(
+            (loss, (model_state, aux)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, model_state, mb, sub)
             acc_grads = jax.tree.map(jnp.add, acc_grads, grads)
-            return (acc_grads, acc_loss + loss, model_state, rng), None
+            if isinstance(aux, dict) and "counters" in aux:
+                new = aux["counters"].astype(jnp.int32)
+                counts = jnp.where(counts_max, jnp.maximum(counts, new),
+                                   counts + new)
+            return (acc_grads, acc_loss + loss, model_state, rng,
+                    counts), None
 
         zero_grads = jax.tree.map(jnp.zeros_like, state.params)
+        zero_counts = jnp.zeros((len(MODEL_COUNTERS),), jnp.int32)
         with phase_scope("fwd_bwd"):
             if nsteps_update > 1:
                 mb_batch = jax.tree.map(
                     lambda x: x.reshape((nsteps_update, -1) + x.shape[1:]),
                     batch)
-                (grads, loss, model_state, rng), _ = lax.scan(
-                    micro, (zero_grads, 0.0, state.model_state, rng),
-                    mb_batch)
+                (grads, loss, model_state, rng, model_counts), _ = lax.scan(
+                    micro, (zero_grads, 0.0, state.model_state, rng,
+                            zero_counts), mb_batch)
                 grads = jax.tree.map(lambda g: g / nsteps_update, grads)
                 loss = loss / nsteps_update
             else:
-                (grads, loss, model_state, rng), _ = micro(
-                    (zero_grads, 0.0, state.model_state, rng), batch)
+                (grads, loss, model_state, rng, model_counts), _ = micro(
+                    (zero_grads, 0.0, state.model_state, rng, zero_counts),
+                    batch)
 
             if grad_clip is not None:
                 gnorm = jnp.sqrt(sum(jnp.sum(g ** 2)
@@ -448,14 +460,17 @@ def build_sparse_grad_step(
         # what the step did, one i32 vector in collectives/state.COUNTERS'
         # order: the worst branch over the buckets, everything else summed,
         # then the largest over the workers (the step is as slow as its
-        # slowest worker: one chip in the wide branch holds all of them)
+        # slowest worker: one chip in the wide branch holds all of them),
+        # the realised counts, and what the model counted
         per_bucket = jnp.stack(step_counters)        # [buckets, branches]
         is_branch = jnp.asarray([nm.endswith("_branch")
                                  for nm in BRANCH_COUNTERS])
         metrics["counters"] = jnp.concatenate([
             lax.pmax(jnp.where(is_branch, jnp.max(per_bucket, axis=0),
                                jnp.sum(per_bucket, axis=0)), axis_name),
-            jnp.stack([lk, gk]).astype(jnp.int32)])
+            jnp.stack([lk, gk]).astype(jnp.int32),
+            jnp.where(counts_max, lax.pmax(model_counts, axis_name),
+                      lax.psum(model_counts, axis_name))])
 
         # --- in-step anomaly guard (resilience/guard.py): agree on a
         # global skip flag, then make the whole step a training no-op —

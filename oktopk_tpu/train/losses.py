@@ -26,6 +26,18 @@ def lm_cross_entropy(logits, targets):
         logits.astype(jnp.float32), targets).mean()
 
 
+def model_counters(stats):
+    """What a token language model says of itself beside its logits, as the
+    loss function's ``{"counters": ...}`` in ``collectives/state.
+    MODEL_COUNTERS``' order: a routed-expert model gives ``{"expert_rows":
+    i32[expert layers, held experts]}``, the token-expert pairs each held
+    expert computed. ``{}`` for anything else (the LSTM's carry)."""
+    if not isinstance(stats, dict):
+        return {}
+    rows = stats["expert_rows"]
+    return {"counters": jnp.stack([jnp.sum(rows), jnp.max(rows, initial=0)])}
+
+
 def ctc_loss(logits, logit_lengths, labels, label_lengths, blank_id: int = 0):
     """CTC on per-frame logits [B, T, C] (replaces warpctc_pytorch).
 

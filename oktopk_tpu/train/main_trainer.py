@@ -15,6 +15,7 @@ Example:
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -131,6 +132,15 @@ def parse_args(argv=None):
     p.add_argument("--sigma-scale", type=float, default=2.5)
     p.add_argument("--grad-clip", type=float, default=None)
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--model-kwargs", default=None,
+                   help="JSON object of keyword arguments for the model "
+                        "(models/registry.py), e.g. the share of "
+                        "deepseek_v2_lite one chip holds: "
+                        '\'{"num_hidden_layers": 5, "vocab_size": 12800, '
+                        '"held_experts": [0, 1, 2, 3, 4, 5, 6, 7]}\'')
+    p.add_argument("--seq-len", type=int, default=None,
+                   help="tokens a sequence of the synthetic data "
+                        "(token models; default: the registry's)")
     p.add_argument("--warmup-steps", type=int, default=None,
                    help="dense warmup iterations (default: reference's 512)")
     p.add_argument("--fake-devices", type=int, default=0,
@@ -260,7 +270,8 @@ def main(argv=None):
     if args.warmup_steps is not None:
         algo_cfg = algo_cfg.replace(warmup_steps=args.warmup_steps)
 
-    trainer = Trainer(cfg, algo_cfg=algo_cfg)
+    model_kwargs = json.loads(args.model_kwargs) if args.model_kwargs else None
+    trainer = Trainer(cfg, algo_cfg=algo_cfg, model_kwargs=model_kwargs)
 
     preempt = None
     if args.handle_preemption:
@@ -292,8 +303,10 @@ def main(argv=None):
     # global batch = per-worker batch * workers * accumulation
     global_bs = (args.batch_size * trainer.algo_cfg.num_workers
                  * args.nsteps_update)
-    data_iter, meta = make_dataset(args.dataset, args.dnn, global_bs,
-                                   path=args.data_dir, seed=args.seed)
+    data_iter, meta = make_dataset(
+        args.dataset, args.dnn, global_bs, path=args.data_dir,
+        seed=args.seed, seq_len=args.seq_len,
+        vocab=(model_kwargs or {}).get("vocab_size"))
     if meta.get("synthetic"):
         logger.warning("dataset %s not found on disk: using synthetic data",
                        args.dataset)

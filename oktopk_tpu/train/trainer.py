@@ -28,6 +28,7 @@ from jax.sharding import Mesh
 
 from oktopk_tpu.config import OkTopkConfig, TrainConfig
 from oktopk_tpu.models import create_model
+from oktopk_tpu.models.registry import TOKEN_LMS
 from oktopk_tpu.optim import bert_adam, sgd
 from oktopk_tpu.optim.distributed import (
     DistTrainState,
@@ -576,8 +577,13 @@ class Trainer:
 
     def _init_variables(self, rng, batch):
         rngs = {"params": rng, "dropout": jax.random.fold_in(rng, 1)}
-        if self.cfg.dnn in ("lstm", "lstm_tiny"):
-            return self.model.init(rngs, batch["tokens"], train=False)
+        if self.cfg.dnn in TOKEN_LMS:
+            init = self.model.init
+            if getattr(self.model, "jit_init", False):
+                # one program instead of an eager pass: on the chip each
+                # small operation of an eager pass is a compilation
+                init = jax.jit(init, static_argnames=("train",))
+            return init(rngs, batch["tokens"], train=False)
         if self.cfg.dnn.startswith("bert"):
             return self.model.init(rngs, batch["input_ids"],
                                    batch["token_type_ids"],
@@ -589,10 +595,9 @@ class Trainer:
     def _example_batch(self, bs: int):
         """Zero-filled batch with the workload's shapes (for init/tracing)."""
         dnn = self.cfg.dnn
-        if dnn in ("lstm", "lstm_tiny"):
-            t = 35
-            return {"tokens": jnp.zeros((bs, t), jnp.int32),
-                    "targets": jnp.zeros((bs, t), jnp.int32)}
+        if dnn in TOKEN_LMS:
+            tokens = self.example_fn(bs)    # the registry's example shape
+            return {"tokens": tokens, "targets": jnp.zeros_like(tokens)}
         if dnn.startswith("bert"):
             t = 32 if dnn == "bert_tiny" else 128
             return {"input_ids": jnp.zeros((bs, t), jnp.int32),
@@ -615,12 +620,15 @@ class Trainer:
         mutable = [k for k in model_state]
         rngs = {"dropout": rng}
 
-        if dnn in ("lstm", "lstm_tiny"):
-            (logits, _), mut = self.model.apply(
+        if dnn in TOKEN_LMS:
+            # every token language model: ``apply`` gives the logits and
+            # one thing more (the LSTM its carry, dropped here as ever; a
+            # routed-expert model a dict of what it counted)
+            (logits, extra), mut = self.model.apply(
                 variables, batch["tokens"], train=True, mutable=mutable,
                 rngs=rngs)
             loss = losses.lm_cross_entropy(logits, batch["targets"])
-            return loss, (dict(mut), {})
+            return loss, (dict(mut), losses.model_counters(extra))
         if dnn.startswith("bert"):
             (mlm, nsp), mut = self.model.apply(
                 variables, batch["input_ids"], batch["token_type_ids"],
@@ -959,7 +967,7 @@ class Trainer:
         params = self.state.params
         variables = {"params": params, **self.state.model_state}
         dnn = self.cfg.dnn
-        if dnn in ("lstm", "lstm_tiny"):
+        if dnn in TOKEN_LMS:
             logits, _ = self.model.apply(variables, batch["tokens"],
                                          train=False)
             loss = losses.lm_cross_entropy(logits, batch["targets"])
