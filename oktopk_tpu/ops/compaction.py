@@ -15,13 +15,22 @@ work by what the hardware is good at:
    block writes its own staging row — standard blocked VMEM outputs, no
    cross-block sequencing, so the grid pipelines freely.
 2. Plain-XLA post-processing does the cap-scale work with *gathers* (the
-   measured costs on v5e: gather ~10 ns/elem/round, cap-operand scatter
-   ~4.7 ns/elem, n-operand scatter ~4700 ns/1000 elem): the per-output-slot
+   measured costs on v5e: gather 7-23 ns/elem/round, by how close
+   together it reads (below), cap-operand scatter ~4.7 ns/elem, n-operand
+   scatter ~4700 ns/1000 elem): the per-output-slot
    staging address and element base both *telescope* along the output axis
    (crossing a block's end advances them by fixed per-block jumps), so one
    small scatter-add of the jumps + a cap-scale cumsum replaces any
    searchsorted/base-gather, leaving exactly 2 cap-scale gather rounds
-   (the staged offset, then the value) — see ``_materialize``.
+   (the staged offset, then the value) — see ``_materialize``. Both rounds
+   run over the *live prefix* of the output only: slots at or past a
+   region's count are value 0 / index n by contract, so a loop whose trip
+   count follows the traced counts gathers ``CHUNK`` slots a trip and the
+   rest stay constants (``_gather_live``). A materialise costs two rounds x
+   live slots, not x capacity: at n = 66 M, capacity 2.6-3.3 M and 37-56 %
+   live (PERF.md, PR 29) the staged offset is 7 ns a slot (neighbouring
+   slots read neighbouring addresses) and the value 20-23 ns (one element
+   in ~45 of a 264 MB vector).
 
 Why not DMA-append inside the kernel (the round-3 first attempt): Mosaic
 cannot slice a tiled VMEM scratch per row, and 1-D memrefs — HBM included —
@@ -44,8 +53,11 @@ dispatches (``lax.switch``) on the overflow census:
     128-slot pages its survivors reach: a padded entry does nothing and a
     block with 300 survivors stages 3 pages of 8 (8 one-hot tiles a page,
     PERF.md section 5) — nobody addresses the rest (``_run_repair``);
-    ``_materialize_het`` then reads the mixed 128/1024-wide layout via
-    one extra telescoping accumulator (the per-slot source block);
+    ``_materialize_het`` then reads the mixed 128/1024-wide layout with
+    the same two gather rounds over the live prefix: the rows' physical
+    starts ride in the jumps, so the cumsum is the address in the
+    concatenated [fast | repaired] staging itself, and one extra
+    telescoping accumulator carries the per-slot source block;
   * more                       -> the capb=1024 kernel over everything
     (can never drop anything), as before.
 
@@ -95,6 +107,12 @@ BLK = BLK_ROWS * BLK_COLS
 SB = 8
 
 CAPB_FAST = 128       # staging width of the fast kernel (one lane row)
+
+# output slots of each region that one trip of the materialise's gather
+# loop fills (``_gather_live``), so its cost follows the live slots to
+# within a chunk. On a v5e at cap 2.6-3.3 M, 4,096 to 16,384 time alike and
+# 65,536 / 262,144 take 1 / 3 ms more a call (PERF.md, PR 29)
+CHUNK = 1 << 14
 
 
 def _shift_right(x, d, axis):
@@ -313,6 +331,66 @@ def _run_repair(xp, t, rng, bl, novf, novf_cap, interpret, vma):
     return w
 
 
+def _slot_bases(jump_rb, c_rb, cap, start):
+    """``[R, cap]`` per-output-slot base that *telescopes* along the slot
+    axis: ``start[r]`` plus every ``jump_rb[b, r]`` whose block ends at or
+    before the slot (block b of region r ends at output position
+    ``c_rb[b, r]``, the inclusive survivor count). One nb-operand
+    scatter-add of the jumps and a per-row cap-scale cumsum; crossings at
+    or past ``cap`` land in a spare slot that is cut off."""
+    nblocks, R = c_rb.shape
+    pos = jnp.minimum(c_rb, cap)
+    rgrid = jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32)[None, :],
+                             (nblocks, R))
+    jumps = jnp.zeros((R, cap + 1), jnp.int32).at[rgrid.T, pos.T].add(
+        jump_rb.T)
+    return start[:, None] + jnp.cumsum(jumps, axis=1)[:, :cap]
+
+
+def _gather_live(stage_flat, xflat, addr, blk, counts, n):
+    """The two cap-scale gather rounds of a materialise: output slot
+    ``[r, j]`` reads its staged in-block offset at ``stage_flat[addr]``
+    (round 1) and its value at ``xflat[blk * BLK + offset]`` (round 2).
+
+    Only the live prefix is gathered. Slots ``j >= counts[r]`` are value 0
+    / index ``n`` by contract and nobody reads anything else there, so the
+    outputs start as those constants and a loop whose trip count follows
+    the traced counts fills ``ceil(max(counts) / CHUNK)`` chunks of the
+    slot axis in place; the last chunk is masked by ``live``. A capacity of
+    at most one chunk is gathered whole, in straight-line code (a static
+    shape test). The last chunk of a capacity that is no multiple of
+    ``CHUNK`` starts early and rewrites slots of the chunk before it with
+    the same values: a slot's result depends on its position alone."""
+    R, cap = addr.shape
+
+    def rows(addr, blk, j):
+        w = stage_flat[jnp.clip(addr, 0, stage_flat.size - 1)] \
+            .astype(jnp.int32)                            # gather round 1
+        idx = blk * BLK + w
+        live = j < counts[:, None]
+        values = jnp.where(live, xflat[jnp.minimum(idx, xflat.size - 1)],
+                           0.0)                           # gather round 2
+        return values, jnp.where(live, idx, n).astype(jnp.int32)
+
+    if cap <= CHUNK:
+        return rows(addr, blk, jnp.arange(cap, dtype=jnp.int32)[None, :])
+
+    def chunk(c, out):
+        start = jnp.minimum(c * CHUNK, cap - CHUNK)
+        values, indices = rows(
+            jax.lax.dynamic_slice(addr, (0, start), (R, CHUNK)),
+            jax.lax.dynamic_slice(blk, (0, start), (R, CHUNK)),
+            start + jnp.arange(CHUNK, dtype=jnp.int32)[None, :])
+        return (jax.lax.dynamic_update_slice(out[0], values, (0, start)),
+                jax.lax.dynamic_update_slice(out[1], indices, (0, start)))
+
+    vma = compat.typeof_vma(addr) | compat.typeof_vma(xflat)
+    dead = (_pvary_to(jnp.zeros((R, cap), xflat.dtype), vma),
+            _pvary_to(jnp.full((R, cap), n, jnp.int32), vma))
+    nchunks = (jnp.max(counts) + CHUNK - 1) // CHUNK
+    return jax.lax.fori_loop(0, nchunks, chunk, dead)
+
+
 def _materialize(w_stage, xflat, cnt_rb, off_rb, capb, cap, counts, n):
     """Materialise ``(values [R, cap], indices [R, cap])`` from a packed
     staging ``w_stage [nb, capb]`` whose block b holds (ascending-index)
@@ -328,80 +406,69 @@ def _materialize(w_stage, xflat, cnt_rb, off_rb, capb, cap, counts, n):
     base by capb + off_rb[b+1, r] - off_rb[b, r] - cnt_rb[b, r] and the
     element base by BLK, starting from off_rb[0, r] and 0. One small
     scatter-add of those jumps + a per-row cap-scale cumsum therefore
-    replaces any searchsorted and per-slot base gather (the element base
-    needs no accumulator of its own: a live slot's in-row offset is < capb,
-    so its block is ``flat // capb``); only two cap-scale gather rounds
-    remain (the staged offset, then the value).
+    replaces any searchsorted and per-slot base gather (``_slot_bases``;
+    the element base needs no accumulator of its own: a live slot's in-row
+    offset is < capb, so its block is ``flat // capb``); only two cap-scale
+    gather rounds remain (the staged offset, then the value), over the
+    live prefix of the slot axis (``_gather_live``).
     """
-    nblocks, R = cnt_rb.shape
     if off_rb is None:
         off_rb = jnp.zeros_like(cnt_rb)
     c_rb = jnp.cumsum(cnt_rb, axis=0)                 # [nb, R] inclusive
     off_next = jnp.concatenate([off_rb[1:], off_rb[-1:]], axis=0)
     fval = capb + off_next - off_rb - cnt_rb          # [nb, R]
-    pos = jnp.minimum(c_rb, cap)
-    rgrid = jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32)[None, :],
-                             (nblocks, R))
-    fjump = jnp.zeros((R, cap + 1), jnp.int32).at[rgrid.T, pos.T].add(fval.T)
     j = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    flat = off_rb[0][:, None] + jnp.cumsum(fjump, axis=1)[:, :cap] + j
+    flat = _slot_bases(fval, c_rb, cap, off_rb[0]) + j
     # live slots always sit inside their block's staging row (in-row offset
     # < capb), so the source block is just flat // capb — a shift, no
     # second jump accumulator needed
-    flat = jnp.clip(flat, 0, nblocks * capb - 1)
-    w = w_stage.reshape(-1)[flat].astype(jnp.int32)   # gather round 1
-    idx = (flat // capb) * BLK + w
-    live = j < counts[:, None]
-    values = jnp.where(live, xflat[jnp.minimum(idx, xflat.size - 1)],
-                       0.0)                           # gather round 2
-    indices = jnp.where(live, idx, n).astype(jnp.int32)
-    return values, indices
+    return _gather_live(w_stage.reshape(-1), xflat, flat, flat // capb,
+                        counts, n)
+
+
+def _het_addresses(ovf, cnt_rb, off_rb, capf, cap):
+    """Per output slot ``[R, cap]`` of the repair path's mixed layout: its
+    address in the concatenated ``[w_fast | w_rep]`` staging and its source
+    block. Block b's row starts at ``phys_base[b]``: ``w_rep`` page-row
+    ``rank(b)`` (1024 wide) when ``ovf[b]``, else ``w_fast[b]`` (``capf``
+    wide).
+
+    The address is ``_materialize``'s telescoping base with the physical
+    row starts in the jumps: crossing block b moves it from b's row to the
+    start of b+1's, ``phys_base[b+1] - phys_base[b] + off_rb[b+1, r] -
+    off_rb[b, r] - cnt_rb[b, r]``, from ``phys_base[0] + off_rb[0, r]``; so
+    the cumsum is the physical address itself and nothing is looked up per
+    slot. A second accumulator (jump +1 at every crossing) carries the
+    source block, which the mixed widths no longer let one divide out.
+    Slots at or past a region's count get an address and a block that
+    nobody may read (``_gather_live`` clamps what it gathers there and
+    masks the result)."""
+    nblocks, R = cnt_rb.shape
+    capb_b = jnp.where(ovf, BLK, capf)                    # [nb]
+    rank = jnp.cumsum(ovf.astype(jnp.int32)) - ovf        # repair row of b
+    phys_base = jnp.where(ovf, nblocks * capf + rank * BLK,
+                          jnp.arange(nblocks, dtype=jnp.int32) * capf)
+    phys_next = jnp.concatenate([phys_base[1:], phys_base[-1:] + capb_b[-1:]])
+    c_rb = jnp.cumsum(cnt_rb, axis=0)                     # [nb, R] inclusive
+    off_next = jnp.concatenate([off_rb[1:], off_rb[-1:]], axis=0)
+    pval = (phys_next - phys_base)[:, None] + off_next - off_rb - cnt_rb
+    j = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    phys = _slot_bases(pval, c_rb, cap, phys_base[0] + off_rb[0]) + j
+    b = _slot_bases(jnp.ones_like(pval), c_rb, cap,
+                    jnp.zeros((R,), jnp.int32))
+    return phys, b
 
 
 def _materialize_het(w_fast, w_rep, ovf, xflat, cnt_rb, off_rb, capf, cap,
                      counts, n):
-    """``_materialize`` over the mixed staging layout of the repair path:
-    block b's row is ``w_rep`` page-row ``rank(b)`` (1024 wide) when
-    ``ovf[b]``, else ``w_fast[b]`` (``capf`` wide).
-
-    Same telescoping-jump construction, with per-block widths ``capb_b``
-    in the jump values and ONE extra accumulator carrying the per-slot
-    source block id b (jump +1 at every block crossing) — b can no longer
-    be recovered as ``flat // capb`` — plus one nb-operand gather of
-    ``delta[b] = phys_base[b] - vbase[b]`` translating virtual addresses
-    into the concatenated [w_fast | w_rep] physical array."""
-    nblocks, R = cnt_rb.shape
+    """``_materialize`` over the mixed staging layout of the repair path
+    (``_het_addresses``): the same two gather rounds over the live prefix,
+    from the concatenated [w_fast | w_rep] array."""
     if off_rb is None:
         off_rb = jnp.zeros_like(cnt_rb)
-    capb_b = jnp.where(ovf, BLK, capf)                    # [nb]
-    vbase = jnp.cumsum(capb_b) - capb_b                   # virtual row base
-    rank = jnp.cumsum(ovf.astype(jnp.int32)) - ovf        # repair row of b
-    fast_sz = nblocks * capf
-    phys_base = jnp.where(ovf, fast_sz + rank * BLK,
-                          jnp.arange(nblocks, dtype=jnp.int32) * capf)
-    delta = phys_base - vbase                             # [nb]
-
-    c_rb = jnp.cumsum(cnt_rb, axis=0)                     # [nb, R] inclusive
-    off_next = jnp.concatenate([off_rb[1:], off_rb[-1:]], axis=0)
-    fval = capb_b[:, None] + off_next - off_rb - cnt_rb   # [nb, R]
-    pos = jnp.minimum(c_rb, cap)
-    rgrid = jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32)[None, :],
-                             (nblocks, R))
-    fjump = jnp.zeros((R, cap + 1), jnp.int32).at[rgrid.T, pos.T].add(fval.T)
-    bjump = jnp.zeros((R, cap + 1), jnp.int32).at[rgrid.T, pos.T].add(
-        jnp.ones_like(fval.T))
-    j = jnp.arange(cap, dtype=jnp.int32)[None, :]
-    flat = off_rb[0][:, None] + jnp.cumsum(fjump, axis=1)[:, :cap] + j
-    b = jnp.minimum(jnp.cumsum(bjump, axis=1)[:, :cap], nblocks - 1)
+    phys, b = _het_addresses(ovf, cnt_rb, off_rb, capf, cap)
     stage_all = jnp.concatenate([w_fast.reshape(-1), w_rep.reshape(-1)])
-    phys = jnp.clip(flat + delta[b], 0, stage_all.size - 1)
-    w = stage_all[phys].astype(jnp.int32)                 # gather round 1
-    idx = b * BLK + w
-    live = j < counts[:, None]
-    values = jnp.where(live, xflat[jnp.minimum(idx, xflat.size - 1)],
-                       0.0)                               # gather round 2
-    indices = jnp.where(live, idx, n).astype(jnp.int32)
-    return values, indices
+    return _gather_live(stage_all, xflat, phys, b, counts, n)
 
 
 def _region_counts(stage_flat, phys_base, stored_v, capb_max, bnd, R,

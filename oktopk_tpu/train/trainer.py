@@ -862,6 +862,26 @@ class Trainer:
                 densities[b] = 1.0
         return names, densities
 
+    def _bucket_cfgs(self):
+        """``[(algo name, OkTopkConfig, SparseState), ...]``, a bucket
+        each: the config with the bucket's own n and the density of
+        :meth:`_bucket_plan`, which is what sizes its buffers."""
+        names, densities = self._bucket_plan()
+        sps = ([self.state.sparse_state] if self.cfg.num_buckets <= 1
+               else list(self.state.sparse_state))
+        return [(nm, self.algo_cfg.replace(
+            n=int(sp.residual.shape[-1]), density=float(dens)), sp)
+            for nm, dens, sp in zip(names, densities, sps)]
+
+    def capacities(self):
+        """The static sizes of the two buffers a step's selections fill, a
+        bucket each: ``local_k / cap_pair`` and ``global_k / cap_gather``
+        (``collectives/state.COUNTERS``, summed over the buckets) are the
+        live shares that the materialise's cost follows
+        (``ops/compaction._gather_live``)."""
+        return [{"cap_pair": c.cap_pair, "cap_gather": c.cap_gather}
+                for _, c, _ in self._bucket_cfgs()]
+
     def _emit_volume_report(self):
         """One ``volume_report`` event per bucket: mean realised wire
         bytes per step (from the SparseState accounting) against the
@@ -871,16 +891,9 @@ class Trainer:
         steady-state budget; the per-algorithm conformance guarantee is
         asserted by the steady-state tests, not here."""
         from oktopk_tpu.obs import volume as obs_volume
-        names, densities = self._bucket_plan()
-        single = self.cfg.num_buckets <= 1
-        sps = ([self.state.sparse_state] if single
-               else list(self.state.sparse_state))
-        for b, (nm, dens) in enumerate(zip(names, densities)):
-            sp = sps[b]
+        for b, (nm, cfg_b, sp) in enumerate(self._bucket_cfgs()):
             steps_done = int(np.asarray(sp.step)[0])
             wb = float(np.asarray(sp.wire_bytes)[0])
-            n_b = int(np.asarray(sp.residual).shape[-1])
-            cfg_b = self.algo_cfg.replace(n=n_b, density=float(dens))
             rep = obs_volume.volume_report(
                 nm, cfg_b, wb / max(1, steps_done), bucket=b,
                 step=getattr(self, "last_step", 0), steps=steps_done)

@@ -345,8 +345,10 @@ SETUP = PhaseTimers(every=0)
 
 def register(source) -> None:
     """Weakly register an object whose ``step_counters()`` returns
-    ``[(step_num, counters array), ...]`` (a ``Trainer`` does at
-    construction), so that :func:`snapshot` finds it."""
+    ``[(step_num, counters array), ...]`` and whose ``capacities()``
+    returns ``[{"cap_pair": int, "cap_gather": int}, ...]``, a bucket each
+    (a ``Trainer`` does at construction), so that :func:`snapshot` finds
+    it."""
     _sources[:] = [r for r in _sources if r() is not None]
     _sources.append(weakref.ref(source))
 
@@ -370,6 +372,11 @@ def snapshot() -> Dict[str, Any]:
       live registered source, fetched with ONE ``device_get``; the order
       of a vector is ``counter_names``, a branch entry's value its place
       in ``branch_names``;
+    - ``capacities``: the same sources' static buffer sizes, ``cap_pair``
+      and ``cap_gather`` a bucket, in the order of the buckets: a step's
+      ``local_k`` and ``global_k`` over their sums are the live shares of
+      the two buffers, which the materialise's cost follows
+      (ops/compaction.py ``_gather_live``);
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
     """
@@ -380,14 +387,15 @@ def snapshot() -> Dict[str, Any]:
     recs = list(SETUP.records)
     if _recorder is not None and _recorder is not SETUP:
         recs += list(_recorder.records)
-    pairs = [p for r in _sources if (src := r()) is not None
-             for p in src.step_counters()]
+    sources = [src for r in _sources if (src := r()) is not None]
+    pairs = [p for src in sources for p in src.step_counters()]
     return {
         "spans": [{"name": n, "start_ns": a, "end_ns": b, "step": s,
                    "parent": p} for n, a, b, s, p in recs],
         "host_counters": compile_counters().as_dict(),
         "counter_names": list(COUNTERS),
         "branch_names": list(BRANCHES),
+        "capacities": [c for src in sources for c in src.capacities()],
         "sub_scopes": {ph: list(subs) for ph, subs in SUB_SCOPES.items()},
         "step_counters": [{"step": s, "counters": c}
                           for s, c in fetch_counters(pairs)],

@@ -70,6 +70,133 @@ def straddling_bounds(blocks):
                       np.int32)
 
 
+# ---- live-prefix cases (``_gather_live``) ------------------------------
+# One geometry for every case, so that each fresh jit compiles once: 64
+# blocks, CHUNK patched to 1,024, capacities that are no multiple of it
+# (the last chunk starts early and overlaps the one before).
+LP_NBLOCKS = 64
+LP_CHUNK = 1024
+LP_SELECT_CAP = 5000
+LP_PACK_CAP = 2500
+# blocks over the fast staging width, by branch: none; two (the first and
+# the last block); ten, more than the repair list's eight
+LP_HOT = {
+    "fast": {},
+    "repair": {0: 300, LP_NBLOCKS - 1: 129},
+    "wide": {**{b: 129 for b in range(9)}, LP_NBLOCKS - 1: 300},
+}
+LP_BRANCH = {"fast": 0, "repair": 1, "wide": 2}
+# survivor counts at the ends of a chunk (of the first where the branch's
+# hot blocks leave room, else of the second), at cap and over it
+LP_SELECT_COUNTS = {
+    "fast": (0, 1, LP_CHUNK - 1, LP_CHUNK, LP_CHUNK + 1, LP_SELECT_CAP,
+             LP_SELECT_CAP + 600),
+    "repair": (LP_CHUNK - 1, LP_CHUNK, LP_CHUNK + 1, LP_SELECT_CAP,
+               LP_SELECT_CAP + 600),
+    "wide": (2 * LP_CHUNK - 1, 2 * LP_CHUNK, 2 * LP_CHUNK + 1,
+             LP_SELECT_CAP, LP_SELECT_CAP + 600),
+}
+# per-region counts that end in different chunks: none, one, either side
+# of a chunk's end, cap, over cap
+LP_REGION_COUNTS = {
+    "ends": (0, LP_CHUNK - 1, LP_CHUNK + 1, LP_PACK_CAP + 200),
+    "full": (1, LP_CHUNK, LP_PACK_CAP, 300),
+}
+
+
+def counted_vector(block_counts, seed=31):
+    """``block_counts[b]`` elements of magnitude >= 5 at random places of
+    block b, the rest under 1."""
+    rng = np.random.RandomState(seed)
+    nb = len(block_counts)
+    x = (rng.rand(nb, BLK).astype(np.float32) - 0.5)
+    for b, c in enumerate(block_counts):
+        at = rng.choice(BLK, c, replace=False)
+        x[b, at] = (5.0 + rng.rand(c)) * rng.choice([-1.0, 1.0], c)
+    return x.reshape(-1)
+
+
+def prefix_vector(branch, total):
+    """A vector of ``total`` survivors at threshold 1.0 whose overflowing
+    blocks are ``LP_HOT[branch]``; the others share the rest evenly (empty
+    blocks where ``total`` is small)."""
+    hot = LP_HOT[branch]
+    cool = [b for b in range(LP_NBLOCKS) if b not in hot]
+    rest = total - sum(hot.values())
+    assert 0 <= rest <= CAPB_FAST * len(cool)
+    counts = np.zeros(LP_NBLOCKS, int)
+    counts[cool] = rest // len(cool)
+    counts[cool[:rest % len(cool)]] += 1
+    for b, c in hot.items():
+        counts[b] = c
+    return counted_vector(counts)
+
+
+def rank_bounds(x, region_counts):
+    """Boundaries (unaligned) that give region r exactly
+    ``region_counts[r]`` of ``x``'s survivors, the last region the rest."""
+    at = np.flatnonzero(np.abs(x) >= 1.0)
+    ends = np.cumsum(region_counts[:-1])
+    assert sum(region_counts) == at.size
+    return np.asarray([0, *at[ends], x.size], np.int32)
+
+
+def small_chunk_jits(monkeypatch, interpret):
+    """(select, pack): fresh jits of the two public functions, traced
+    under ``CHUNK = LP_CHUNK`` (the module's own jits would keep whatever
+    chunk they were first traced with)."""
+    import functools
+
+    import jax
+
+    from oktopk_tpu.ops import compaction
+    monkeypatch.setattr(compaction, "CHUNK", LP_CHUNK)
+    select = jax.jit(functools.partial(
+        compaction.select_by_threshold_pallas.__wrapped__,
+        interpret=interpret), static_argnames=("cap",))
+    pack = jax.jit(functools.partial(
+        compaction._pack_by_region_pallas.__wrapped__, interpret=interpret),
+        static_argnames=("num_regions", "cap"))
+    return select, pack
+
+
+def check_select_prefix(select, branch, count):
+    x = prefix_vector(branch, count)
+    gv, gi, gc, br = [np.asarray(a) for a in
+                      select(jnp.asarray(x), 1.0, cap=LP_SELECT_CAP)]
+    wv, wi, wc = [np.asarray(a) for a in
+                  select_by_threshold(jnp.asarray(x), 1.0, LP_SELECT_CAP)]
+    assert br[0] == LP_BRANCH[branch]
+    assert gc == wc == min(count, LP_SELECT_CAP)
+    np.testing.assert_array_equal(gi, wi)
+    np.testing.assert_array_equal(gv.view(np.int32), wv.view(np.int32))
+
+
+def check_pack_prefix(pack, branch, case):
+    from oktopk_tpu.ops.select import pack_by_region
+
+    region_counts = LP_REGION_COUNTS[case]
+    x = prefix_vector(branch, sum(region_counts))
+    bnd = jnp.asarray(rank_bounds(x, region_counts))
+    R = len(region_counts)
+    gv, gi, gc, br = [np.asarray(a) for a in pack(
+        jnp.asarray(x), 1.0, bnd, num_regions=R, cap=LP_PACK_CAP)]
+    wv, wi, wc = [np.asarray(a) for a in pack_by_region(
+        jnp.asarray(x), jnp.abs(jnp.asarray(x)) >= 1.0, bnd, R,
+        LP_PACK_CAP)]
+    assert br[0] == LP_BRANCH[branch]
+    np.testing.assert_array_equal(gc, np.minimum(region_counts,
+                                                 LP_PACK_CAP))
+    np.testing.assert_array_equal(gc, wc)
+    np.testing.assert_array_equal(gi, wi)
+    np.testing.assert_array_equal(gv.view(np.int32), wv.view(np.int32))
+
+
+SELECT_PREFIX_CASES = [(b, c) for b in LP_BRANCH
+                       for c in LP_SELECT_COUNTS[b]]
+PACK_PREFIX_CASES = [(b, c) for b in LP_BRANCH for c in LP_REGION_COUNTS]
+
+
 class TestCompactionParity:
     @pytest.mark.parametrize("n", [BLK, 3 * BLK, 4 * BLK + 777])
     def test_matches_portable_select(self, n):
@@ -289,21 +416,214 @@ class TestPackRegionsParity:
         np.testing.assert_array_equal(gv, wv)
 
 
+def _phys_base(ovf):
+    """Where block b's staging row starts in [w_fast | w_rep]."""
+    rank = np.cumsum(ovf) - ovf
+    return np.where(ovf, ovf.size * CAPB_FAST + rank * BLK,
+                    np.arange(ovf.size) * CAPB_FAST)
+
+
+def _het_layout(raw, region_ranks, cap):
+    """What ``_het_addresses`` is given and what it must return, in plain
+    numpy. ``raw[b]`` survivors a block (over ``CAPB_FAST``: a repaired,
+    1024-wide row), regions cut at the survivor ranks ``region_ranks``.
+    Returns ``(ovf, cnt_rb, off_rb, counts, phys, blk)``; ``phys`` and
+    ``blk`` by the definition, a slot at a time, -1 on dead slots."""
+    raw = np.asarray(raw)
+    edges = np.asarray([0, *region_ranks, raw.sum()])
+    excl = np.cumsum(raw) - raw
+    # survivors of block b in region r: overlap of two rank intervals
+    cnt_rb = np.clip(np.minimum(excl[:, None] + raw[:, None], edges[1:])
+                     - np.maximum(excl[:, None], edges[:-1]), 0, None)
+    off_rb = np.cumsum(cnt_rb, axis=1) - cnt_rb
+    counts = np.minimum(cnt_rb.sum(axis=0), cap)
+    ovf = raw > CAPB_FAST
+    phys_base = _phys_base(ovf)
+    c_incl = np.cumsum(cnt_rb, axis=0)
+    R = cnt_rb.shape[1]
+    phys = -np.ones((R, cap), np.int64)
+    blk = -np.ones((R, cap), np.int64)
+    for r in range(R):
+        for j in range(counts[r]):
+            b = np.searchsorted(c_incl[:, r], j, side="right")
+            phys[r, j] = (phys_base[b] + off_rb[b, r]
+                          + j - (c_incl[b, r] - cnt_rb[b, r]))
+            blk[r, j] = b
+    return ovf, cnt_rb, off_rb, counts, phys, blk
+
+
+def _flat_plus_delta(ovf, cnt_rb, off_rb, cap):
+    """The address as the three-round materialise formed it: the virtual
+    address ``flat`` plus a per-slot lookup ``delta[b]``."""
+    nb, R = cnt_rb.shape
+    capb_b = np.where(ovf, BLK, CAPB_FAST)
+    vbase = np.cumsum(capb_b) - capb_b
+    delta = _phys_base(ovf) - vbase
+    off_next = np.concatenate([off_rb[1:], off_rb[-1:]])
+    fval = capb_b[:, None] + off_next - off_rb - cnt_rb
+    pos = np.minimum(np.cumsum(cnt_rb, axis=0), cap)
+    fjump = np.zeros((R, cap + 1), np.int64)
+    bjump = np.zeros((R, cap + 1), np.int64)
+    for r in range(R):
+        np.add.at(fjump[r], pos[:, r], fval[:, r])
+        np.add.at(bjump[r], pos[:, r], 1)
+    flat = off_rb[0][:, None] + np.cumsum(fjump, axis=1)[:, :cap] \
+        + np.arange(cap)
+    b = np.minimum(np.cumsum(bjump, axis=1)[:, :cap], nb - 1)
+    return flat + delta[b], b
+
+
+# raw survivors a block (16 blocks), cap: what each layout is there for
+HET_LAYOUTS = {
+    "empty_blocks": ([0, 0, 300, 0, 5, 0, 0, 129, 0, 0, 0, 40, 0, 0, 0, 0],
+                     600),
+    "overflow_first_and_last": ([1024, 3, 0, 7, 128, 9, 1, 0, 2, 127, 130,
+                                 4, 4, 0, 6, 700], 2500),
+    "boundary_in_overflow": ([10, 900, 20, 0, 0, 640, 3, 3, 3, 128, 129, 0,
+                              50, 1, 1024, 2], 3000),
+    "total_over_cap": ([100, 500, 100, 0, 1024, 100, 90, 80, 300, 0, 0, 70,
+                        60, 50, 800, 40], 1000),
+}
+
+
+class TestTelescopedAddress:
+    """``_het_addresses``: the cumsum of the jumps IS the physical staging
+    address, equal on every live slot to the definition and to the
+    ``flat + delta[b]`` it replaced (dead slots are masked downstream and
+    may differ)."""
+
+    @pytest.mark.parametrize("R", [1, 4])
+    @pytest.mark.parametrize("layout", sorted(HET_LAYOUTS))
+    def test_equals_flat_plus_delta_on_live_slots(self, layout, R):
+        from oktopk_tpu.ops.compaction import _het_addresses
+
+        raw, cap = HET_LAYOUTS[layout]
+        total = sum(raw)
+        # R = 4: region ends inside overflowing blocks, the first one a
+        # few slots past the fast width of the first block over it
+        first = int(np.argmax(np.asarray(raw) > CAPB_FAST))
+        ranks = [] if R == 1 else [sum(raw[:first]) + CAPB_FAST + 7,
+                                   total // 2, total - 60]
+        ovf, cnt_rb, off_rb, counts, want_phys, want_blk = _het_layout(
+            raw, ranks, cap)
+        assert ovf[first] and (R == 1 or cnt_rb[first].min() >= 0
+                               and (cnt_rb[first] > 0).sum() >= 2)
+        if layout == "total_over_cap":
+            assert cnt_rb.sum(axis=0).max() > cap
+        phys, blk = [np.asarray(a) for a in _het_addresses(
+            jnp.asarray(ovf), jnp.asarray(cnt_rb, jnp.int32),
+            jnp.asarray(off_rb, jnp.int32), CAPB_FAST, cap)]
+        old_phys, old_blk = _flat_plus_delta(ovf, cnt_rb, off_rb, cap)
+        live = np.arange(cap)[None, :] < counts[:, None]
+        assert live.any() and (want_phys >= 0)[live].all()
+        np.testing.assert_array_equal(phys[live], want_phys[live])
+        np.testing.assert_array_equal(blk[live], want_blk[live])
+        np.testing.assert_array_equal(phys[live], old_phys[live])
+        np.testing.assert_array_equal(blk[live], old_blk[live])
+
+
+class TestLivePrefix:
+    """The materialise gathers ``ceil(max(counts) / CHUNK)`` chunks of the
+    slot axis and leaves the rest at value 0 / index n: bit-equal to the
+    portable path in every branch, for counts at the ends of a chunk."""
+
+    @pytest.fixture(scope="class")
+    def jits(self):
+        with pytest.MonkeyPatch.context() as mp:
+            yield small_chunk_jits(mp, interpret=True)
+
+    @pytest.mark.parametrize("branch,count", SELECT_PREFIX_CASES)
+    def test_select(self, jits, branch, count):
+        check_select_prefix(jits[0], branch, count)
+
+    @pytest.mark.parametrize("branch,case", PACK_PREFIX_CASES)
+    def test_pack_regions_end_in_different_chunks(self, jits, branch, case):
+        check_pack_prefix(jits[1], branch, case)
+
+    @pytest.mark.parametrize("R", [1, 2])
+    def test_loop_under_varying_axes_tracking(self, mesh4, monkeypatch, R):
+        """The gather loop inside ``shard_map(check_vma=True)``, a count a
+        worker (the loop's carry starts as constants, which the tracking
+        types as unvarying): what ``build_allreduce_step``'s default runs
+        on the chip. The staging rows are made here, in numpy: the
+        interpreter cannot run a kernel under the tracking."""
+        import jax
+        from jax.sharding import PartitionSpec as P_
+
+        from oktopk_tpu.ops import compaction
+        from oktopk_tpu.ops.select import pack_by_region
+
+        monkeypatch.setattr(compaction, "CHUNK", 256)
+        nb, cap = 16, 600
+        n = nb * BLK
+        totals = (0, 255, 700, 2000)          # trips 0, 1, 3, 3 (over cap)
+        xs, stages, cnts, bnds = [], [], [], []
+        for w, total in enumerate(totals):
+            per = np.full(nb, total // nb)
+            per[:total % nb] += 1
+            x = counted_vector(per, seed=40 + w)
+            at = np.flatnonzero(np.abs(x) >= 1.0)
+            bnd = np.asarray([0, n] if R == 1 else
+                             [0, at[total // 3] if total else n // 2, n],
+                             np.int32)
+            stage = np.zeros((nb, CAPB_FAST), np.float32)
+            cnt = np.zeros((nb, R), np.int32)
+            for b in range(nb):
+                inb = at[at // BLK == b]
+                stage[b, :inb.size] = inb % BLK
+                for r in range(R):
+                    cnt[b, r] = ((inb >= bnd[r]) & (inb < bnd[r + 1])).sum()
+            xs.append(x), stages.append(stage), cnts.append(cnt)
+            bnds.append(bnd)
+
+        def per_worker(stage, x, cnt):
+            stage, x, cnt = stage[0], x[0], cnt[0]
+            off = jnp.cumsum(cnt, axis=1) - cnt
+            counts = jnp.minimum(jnp.sum(cnt, axis=0), cap)
+            v, i = compaction._materialize(stage, x, cnt, off, CAPB_FAST,
+                                           cap, counts, n)
+            return v[None], i[None]
+
+        got_v, got_i = jax.jit(jax.shard_map(
+            per_worker, mesh=mesh4, in_specs=(P_("data"),) * 3,
+            out_specs=(P_("data"),) * 2, check_vma=True))(
+                jnp.asarray(np.stack(stages)), jnp.asarray(np.stack(xs)),
+                jnp.asarray(np.stack(cnts)))
+        for w in range(len(totals)):
+            wv, wi, _ = pack_by_region(
+                jnp.asarray(xs[w]), jnp.abs(jnp.asarray(xs[w])) >= 1.0,
+                jnp.asarray(bnds[w]), R, cap)
+            np.testing.assert_array_equal(np.asarray(got_i[w]),
+                                          np.asarray(wi))
+            np.testing.assert_array_equal(np.asarray(got_v[w]),
+                                          np.asarray(wv))
+
+
 class TestRepairSkipInvariant:
     """What the repair kernel's skipping rests on (``_run_repair``): the
     consumers of ``w_rep`` read a listed block's row below its survivor
     count only, so the rows of padded list entries and the slots at or past
     a count may hold anything."""
 
+    @pytest.mark.parametrize("chunk", [None, 256])
     @pytest.mark.parametrize("novf", [2, 4])
-    def test_unaddressed_slots_may_hold_anything(self, novf):
+    def test_unaddressed_slots_may_hold_anything(self, novf, chunk,
+                                                 monkeypatch):
+        """``chunk``: the module's own (the survivors end in the first
+        chunk of several) and a small one (the gather loop makes 3 to 8
+        trips and leaves the rest of the buffer untouched)."""
+        from oktopk_tpu.ops import compaction
         from oktopk_tpu.ops.compaction import (
             BLK_COLS, _materialize_het, _prep, _region_counts, _run_repair,
             _run_stage, _vma_of)
         from oktopk_tpu.ops.select import pack_by_region
 
+        if chunk is not None:
+            monkeypatch.setattr(compaction, "CHUNK", chunk)
+
         x, blocks = overflow_vector(REPAIR_SURVIVORS[novf])
         R, cap = 2, x.size // 2
+        assert cap > compaction.CHUNK          # the loop, not the whole
         bnd = jnp.asarray(straddling_bounds(blocks))
         xp, xflat, t, rng, n, nb = _prep(jnp.asarray(x), 1.0, None, None)
         vma = _vma_of(xp)

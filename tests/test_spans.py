@@ -301,6 +301,7 @@ class TestSnapshot:
         with open(path) as f:
             snap = json.load(f)
         assert snap["counter_names"] == list(COUNTERS)
+        assert trainer.capacities()[0] in snap["capacities"]
         names = [s["name"] for s in snap["spans"]]
         assert "oktopk/setup/build_step" in names and "oktopk/step" in names
         last = snap["step_counters"][-1]
@@ -310,6 +311,32 @@ class TestSnapshot:
                                               "by_step", "recompiles"}
         step = next(s for s in snap["spans"] if s["name"] == "oktopk/step")
         assert step["step"] == trainer.step_num
+
+    @pytest.mark.parametrize("num_buckets", [1, 3])
+    def test_capacities_are_what_the_config_computes(self, mesh4,
+                                                     num_buckets):
+        """``snapshot()["capacities"]``: the static sizes of the two
+        buffers whose live shares (``local_k / cap_pair``, ``global_k /
+        cap_gather``) the materialise's cost follows, a bucket each, as
+        ``OkTopkConfig`` computes them from the bucket's n, the density
+        and the number of workers."""
+        cfg = TrainConfig(dnn="mnistnet", dataset="mnist", batch_size=8,
+                          lr=0.02, compressor="oktopk", density=0.05,
+                          num_buckets=num_buckets)
+        tr = Trainer(cfg, mesh=mesh4, warmup=False)
+        mine = profiling.snapshot()["capacities"][-num_buckets:]
+        assert mine == tr.capacities() and len(mine) == num_buckets
+        sps = ([tr.state.sparse_state] if num_buckets == 1
+               else list(tr.state.sparse_state))
+        sizes = [sp.residual.shape[-1] for sp in sps]
+        assert sum(sizes) == tr.algo_cfg.n
+        for caps, n_b in zip(mine, sizes):
+            want = OkTopkConfig(n=n_b, num_workers=4, density=0.05)
+            assert caps == {"cap_pair": want.cap_pair,
+                            "cap_gather": want.cap_gather}
+            k = int(0.05 * n_b)
+            assert caps["cap_pair"] == int(2.0 * k / 4) + 8
+            assert caps["cap_gather"] == int(2.5 * k / 4) + 8
 
     def test_trace_captured_carries_the_counters(self, trainer, tmp_path):
         from oktopk_tpu.obs.journal import EventBus

@@ -31,7 +31,9 @@ from oktopk_tpu.ops.compaction import (  # noqa: E402
 from oktopk_tpu.ops.select import pack_by_region, select_by_threshold  # noqa: E402
 
 from test_compaction import (  # noqa: E402
-    REPAIR_SURVIVORS, overflow_vector, straddling_bounds)
+    LP_REGION_COUNTS, LP_SELECT_COUNTS, REPAIR_SURVIVORS,
+    check_pack_prefix, check_select_prefix, overflow_vector,
+    small_chunk_jits, straddling_bounds)
 from test_fused_select import (  # noqa: E402
     assert_all_equal as fused_assert_all_equal, both_forms,
     run_both as fused_run_both)
@@ -235,6 +237,32 @@ def test_repair_pages_and_list_lengths_on_chip(tpu_dev, form, novf):
         for nm, a, b in zip(("values", "indices", "counts"), got, want):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=nm)
+
+
+class TestLivePrefixRepairOnChip:
+    """Mirror of tests/test_compaction.py::TestLivePrefix for the repair
+    branch on silicon: the materialise's gather loop (a ``while`` whose
+    trip count follows the survivor count, each chunk written in place)
+    compiled by XLA:TPU beside the Mosaic kernels, ``CHUNK`` patched to
+    1,024 so that the counts sit at the ends of a chunk. The module's own
+    ``CHUNK`` runs in ``test_repair_pages_and_list_lengths_on_chip``
+    (capacity 65,536 and 32,768)."""
+
+    @pytest.fixture(scope="class")
+    def jits(self):
+        with pytest.MonkeyPatch.context() as mp:
+            yield small_chunk_jits(mp, interpret=False)
+
+    @pytest.mark.parametrize("count", LP_SELECT_COUNTS["repair"])
+    def test_select(self, tpu_dev, jits, count):
+        with jax.default_device(tpu_dev):
+            check_select_prefix(jits[0], "repair", count)
+
+    @pytest.mark.parametrize("case", sorted(LP_REGION_COUNTS))
+    def test_pack_regions_end_in_different_chunks(self, tpu_dev, jits,
+                                                  case):
+        with jax.default_device(tpu_dev):
+            check_pack_prefix(jits[1], "repair", case)
 
 
 def test_pack_wide_branch_parity_on_chip(tpu_dev):
