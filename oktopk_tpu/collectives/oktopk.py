@@ -53,11 +53,6 @@ from oktopk_tpu.ops import (
     select_mask,
 )
 from oktopk_tpu.ops.topk import k2threshold_method
-from oktopk_tpu.ops.hist_threshold import (
-    hist_to_threshold,
-    k2threshold_hist,
-    log2_hist,
-)
 from oktopk_tpu.ops.fused_select import (
     fused_pack_finalize,
     fused_select_stage,
@@ -151,25 +146,24 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     rank = axis_rank(axis_name)
     up = bool(cfg.use_pallas)
     bkt = cfg.bucket_index   # anatomy scope names carry the bucket id
-    hist_mode = cfg.threshold_method == "hist"
     # Fused selection front-end (ops/fused_select.py): ONE Pallas sweep
-    # over (grad, residual) yields acc, the staging rows, the realised and
-    # Newton-probe counts, and — under hist_mode only, the one reader —
-    # the threshold histogram, replacing the separate add_residual / abs /
-    # mask / count / probe / pack passes below. The unfused path (cfg.fuse_select=False) stays as the
-    # bit-parity oracle (tests/test_fused_select.py).
-    fuse = (up and cfg.fuse_select is not False
-            and grad.dtype == jnp.float32)
+    # over (grad, residual) yields acc, the staging rows and the realised
+    # and Newton-probe counts, replacing the separate add_residual / abs /
+    # mask / count / probe / pack passes below. It runs whenever the Pallas
+    # back end is on and the gradient is float32 (the kernels' dtype); the
+    # portable path is the CPU implementation and the bit-parity oracle
+    # (tests/test_fused_select.py).
+    fuse = up and grad.dtype == jnp.float32
     if not fuse:
         with phase_scope("select", bkt, sub=SUB_SWEEP):
             acc = add_residual(grad, state.residual)
             abs_acc = jnp.abs(acc)
 
     def _abs_acc_branch():
-        # fused steps carry no precomputed |acc| buffer; the rare branches
-        # that need one (exact bisect recompute, first-sparse hist prime)
-        # recompute it inside their cond — bit-identical values, and the
-        # extra sweeps price only the steps that take the branch
+        # fused steps carry no precomputed |acc| buffer; the one branch
+        # that needs one (lt_exact, the exact recompute) recomputes it
+        # inside its cond — bit-identical values, and the extra sweeps
+        # price only the steps that take the branch
         return jnp.abs(add_residual(grad, state.residual)) if fuse \
             else abs_acc
 
@@ -195,54 +189,36 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     prev_lt = state.local_threshold
     tkl = _target_k(k, n, cfg.local_k_target)
 
-    if hist_mode:
-        # LAGGED exact recompute (config.threshold_method="hist"): every
-        # step selects with the carried drift-predicted threshold; the
-        # exact level is read off the histogram this same selection pass
-        # emits (zero extra passes fused, one standalone) and becomes
-        # lt_next in the controller block below — next step's
-        # ``prev_lt * drift`` compensates the one step of staleness. Only
-        # the first sparse step, which has no carried threshold yet, pays
-        # a standalone one-pass histogram prime inside the cond.
-        def lt_prime():
-            return k2threshold_hist(_abs_acc_branch(),
-                                    tkl).astype(grad.dtype)
+    def lt_exact():
+        # exact recompute lands the count at the local setpoint (<= k,
+        # inside the reference band) rather than exactly k: phase-(a)
+        # volume is 4*count*(P-1)/P, so the setpoint directly buys
+        # budget margin at the same nominal density
+        lt_new = k2threshold_method(_abs_acc_branch(), tkl,
+                                    cfg.threshold_method,
+                                    cfg.bisect_iters).astype(grad.dtype)
+        # drift measured between consecutive *exact* thresholds (the
+        # running predicted one is polluted by the controller's own
+        # corrections), as a per-step rate over the elapsed window
+        gap = max(1, cfg.local_recompute_every)
+        base_lt = state.last_exact_lt
+        ratio = jnp.where((lt_new > 0) & (base_lt > 0),
+                          lt_new / jnp.maximum(base_lt, 1e-30), 1.0)
+        per_step = jnp.clip(ratio ** (1.0 / gap),
+                            cfg.drift_clip_lo, cfg.drift_clip_hi)
+        # EMA over recompute windows damps oscillation; the first exact
+        # recompute has no meaningful baseline -> keep drift
+        mixed = ((1.0 - cfg.drift_ema) * state.drift
+                 + cfg.drift_ema * per_step)
+        drift_new = jnp.where(base_lt > 0, mixed, state.drift)
+        return lt_new, drift_new.astype(grad.dtype), lt_new
 
-        with phase_scope("select", bkt, sub=SUB_THRESHOLD):
-            lt = lax.cond(first_sparse, lt_prime,
-                          lambda: prev_lt * state.drift)
-        drift = state.drift   # re-measured from the histogram below
-    else:
-        def lt_exact():
-            # exact recompute lands the count at the local setpoint (<= k,
-            # inside the reference band) rather than exactly k: phase-(a)
-            # volume is 4*count*(P-1)/P, so the setpoint directly buys
-            # budget margin at the same nominal density
-            lt_new = k2threshold_method(_abs_acc_branch(), tkl,
-                                        cfg.threshold_method,
-                                        cfg.bisect_iters).astype(grad.dtype)
-            # drift measured between consecutive *exact* thresholds (the
-            # running predicted one is polluted by the controller's own
-            # corrections), as a per-step rate over the elapsed window
-            gap = max(1, cfg.local_recompute_every)
-            base_lt = state.last_exact_lt
-            ratio = jnp.where((lt_new > 0) & (base_lt > 0),
-                              lt_new / jnp.maximum(base_lt, 1e-30), 1.0)
-            per_step = jnp.clip(ratio ** (1.0 / gap),
-                                cfg.drift_clip_lo, cfg.drift_clip_hi)
-            # EMA over recompute windows damps oscillation; the first exact
-            # recompute has no meaningful baseline -> keep drift
-            mixed = ((1.0 - cfg.drift_ema) * state.drift
-                     + cfg.drift_ema * per_step)
-            drift_new = jnp.where(base_lt > 0, mixed, state.drift)
-            return lt_new, drift_new.astype(grad.dtype), lt_new
+    def lt_predicted():
+        return prev_lt * state.drift, state.drift, state.last_exact_lt
 
-        def lt_predicted():
-            return prev_lt * state.drift, state.drift, state.last_exact_lt
-
-        with phase_scope("select", bkt, sub=SUB_THRESHOLD):
-            lt, drift, last_exact_lt = lax.cond(recompute_local, lt_exact,
-                                                lt_predicted)
+    with phase_scope("select", bkt, sub=SUB_THRESHOLD):
+        lt, drift, last_exact_lt = lax.cond(recompute_local, lt_exact,
+                                            lt_predicted)
 
     # ---- phase (a): select, exchange to region owners, scatter-add reduce.
     # Region repartition every repartition_every steps (reference
@@ -254,8 +230,7 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     if fuse:
         with phase_scope("select", bkt, sub=SUB_SWEEP):
             st = fused_select_stage(grad, state.residual, lt,
-                                    lt * cfg.probe_ratio,
-                                    with_hist=hist_mode)
+                                    lt * cfg.probe_ratio)
             acc = st.acc
         with phase_scope("stage", bkt, sub=SUB_REPARTITION):
             boundaries = lax.cond(
@@ -267,7 +242,6 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
                 st, boundaries, P, cfg.cap_pair)
         local_count = st.local_count
         local_probe = st.probe_count
-        hist = st.hist
         # only the bf16 wire's residual path reads the sent mask; it fuses
         # into the single consumer pass over acc at the bottom (and is
         # DCE'd entirely under the f32 wire). The kernel's own staging
@@ -291,9 +265,6 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
         # threshold feedback probe (fuses into the same pass over abs_acc)
         with phase_scope("select", bkt, sub=SUB_SWEEP):
             local_probe = jnp.sum(abs_acc >= lt * cfg.probe_ratio)
-        # "hist" standalone pays its one histogram pass lazily, inside the
-        # recompute cond below (the fused kernel emits it for free)
-        hist = None
     with phase_scope("exchange", bkt):
         r_vals = all_to_all(_on_wire(s_vals, cfg, state.step), axis_name) \
             .astype(acc.dtype)                 # [P, cap_pair]
@@ -310,38 +281,9 @@ def oktopk(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
     vol_a = 2.0 * (sent_count - own_count) + 2.0 * (recv_count - own_count)
 
     # ---- local threshold feedback for the next step
-    if hist_mode:
-        def lt_measured():
-            # lagged exact recompute: adopt the k-th-value level read from
-            # this step's histogram, and re-measure the drift rate against
-            # the previous exact level (same machinery as lt_exact above).
-            # Unfused steps build the histogram here, inside the branch —
-            # integer counts, bit-identical to the kernel's
-            h = hist if hist is not None else log2_hist(acc)
-            lt_new = hist_to_threshold(h, tkl).astype(grad.dtype)
-            gap = max(1, cfg.local_recompute_every)
-            base_lt = state.last_exact_lt
-            ratio = jnp.where((lt_new > 0) & (base_lt > 0),
-                              lt_new / jnp.maximum(base_lt, 1e-30), 1.0)
-            per_step = jnp.clip(ratio ** (1.0 / gap),
-                                cfg.drift_clip_lo, cfg.drift_clip_hi)
-            mixed = ((1.0 - cfg.drift_ema) * state.drift
-                     + cfg.drift_ema * per_step)
-            drift_new = jnp.where(base_lt > 0, mixed, state.drift)
-            return lt_new, drift_new.astype(grad.dtype), lt_new
-
-        def lt_adapted():
-            return (_newton_adapt(lt, local_count, local_probe, k, cfg,
-                                  target=tkl),
-                    state.drift, state.last_exact_lt)
-
-        with phase_scope("select", bkt, sub=SUB_FEEDBACK):
-            lt_next, drift, last_exact_lt = lax.cond(recompute_local,
-                                                     lt_measured, lt_adapted)
-    else:
-        with phase_scope("select", bkt, sub=SUB_FEEDBACK):
-            lt_next = _newton_adapt(lt, local_count, local_probe, k, cfg,
-                                    target=tkl)
+    with phase_scope("select", bkt, sub=SUB_FEEDBACK):
+        lt_next = _newton_adapt(lt, local_count, local_probe, k, cfg,
+                                target=tkl)
 
     # ---- phase (b): global winner selection + allgather.
     cap_g = cfg.cap_gather

@@ -127,19 +127,6 @@ class OkTopkConfig:
     #   VPU compares instead of the O(n log n) sort the reference pays for
     #   torch.topk (SURVEY.md §7.3.5); ties resolved within float tolerance.
     # "sort": exact lax.top_k (reference-faithful; fine on CPU/small n).
-    # "hist": one-pass 256-bin log2-magnitude histogram cumsum read
-    #   (ops/hist_threshold.py) — 1-bit within-octave resolution, but ONE
-    #   data pass standalone and no pass of its own when the fused selection
-    #   kernel emits the histogram (ops/fused_select.py). No extra pass is
-    #   not no cost: the histogram is 16 of that kernel's 24 one-hot tiles
-    #   a block, so "hist" buys its recompute with ~66 ms in EVERY step at
-    #   n = 66 M on a v5e (105.6 ms a call against 39.2; PERF.md, PR 25);
-    #   the kernel builds it under this method only.
-    #   oktopk under "hist" uses LAGGED local recomputes: each step selects
-    #   with the carried drift-predicted threshold while the exact level is
-    #   read from the histogram that same selection pass produced, becoming
-    #   next step's threshold (one drift-compensated step of staleness
-    #   instead of ~11 extra HBM sweeps). "bisect" stays the oracle.
     threshold_method: str = "bisect"
     bisect_iters: int = 30
 
@@ -147,23 +134,13 @@ class OkTopkConfig:
     # reduced result is >= this dense (reference VGG/allreducer.py:1318-1351).
     sa_dense_fallback_ratio: float = 2.0 / 3.0
 
-    # Selection compaction backend: True = Pallas stream-compaction kernel
-    # (ops/compaction.py; TPU only), False = portable cumsum+scatter,
+    # Selection compaction backend: True = Pallas stream-compaction kernels
+    # (ops/compaction.py and, for oktopk's float32 gradients, the fused
+    # front-end ops/fused_select.py; TPU only), False = portable
+    # cumsum+scatter,
     # None = resolve from the mesh backend at step-build time
     # (collectives/api.py, optim/distributed.py).
     use_pallas: Optional[bool] = None
-
-    # Fused selection front-end (ops/fused_select.py): ONE Pallas sweep
-    # over (grad, residual) computes acc, the staging rows, the realised +
-    # Newton-probe counts and (threshold_method="hist" only) the threshold
-    # histogram, replacing the
-    # separate add_residual/abs/mask/count/probe/pack passes of
-    # collectives/oktopk.py. None = auto (on whenever the Pallas backend
-    # is active); False = force the unfused per-pass path (the parity
-    # oracle); True = same as None (the kernel still requires use_pallas;
-    # it cannot run on the portable path).
-    # oktopk only; f32 gradients only (as all Pallas selection paths).
-    fuse_select: Optional[bool] = None
 
     # Which reverse-layer-order gradient bucket this config instance
     # serves. Set by the multi-bucket step builder (optim/distributed.py)
@@ -238,14 +215,13 @@ class OkTopkConfig:
                     f"density_schedule peaks at {worst} > density "
                     f"{self.density}; capacities are sized by `density`, "
                     "set it to the schedule's max")
-            if self.threshold_method not in ("bisect", "hist"):
+            if self.threshold_method != "bisect":
                 raise ValueError(
-                    "density_schedule needs threshold_method='bisect' or "
-                    "'hist' (a traced target k; lax.top_k wants it "
-                    "static)")
-        if self.threshold_method not in ("sort", "bisect", "hist"):
+                    "density_schedule needs threshold_method='bisect' (a "
+                    "traced target k; lax.top_k wants it static)")
+        if self.threshold_method not in ("sort", "bisect"):
             raise ValueError(
-                f"threshold_method must be 'sort', 'bisect' or 'hist', "
+                f"threshold_method must be 'sort' or 'bisect', "
                 f"got {self.threshold_method!r}")
         for name in ("local_k_target", "global_k_target"):
             f = getattr(self, name)

@@ -23,11 +23,6 @@ from oktopk_tpu.ops.select import (  # noqa: F401
     pack_by_region,
 )
 from oktopk_tpu.ops.gaussian import gaussian_threshold  # noqa: F401
-from oktopk_tpu.ops.hist_threshold import (  # noqa: F401
-    hist_to_threshold,
-    k2threshold_hist,
-    log2_hist,
-)
 from oktopk_tpu.ops.fused_select import (  # noqa: F401
     fused_pack_finalize,
     fused_select_pallas,

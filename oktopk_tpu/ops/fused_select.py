@@ -14,23 +14,12 @@ in-register, and emits in the same grid step
   ``ops/compaction.py`` (same layout, bit-identical — the cap-scale
   post-processing ``_pack_finalize`` is shared),
 - the per-block Newton probe counts (``|acc| >= thresh * probe_ratio``,
-  previously a separate sweep in collectives/oktopk.py),
-- only when the caller asks (``with_hist``, static): a 256-bin
-  log2-magnitude histogram partial (ops/hist_threshold.py bins,
-  bit-identical to ``log2_hist``), which saves the "hist" exact threshold
-  recompute its own pass over the data on fused steps.
+  previously a separate sweep in collectives/oktopk.py).
 
-The histogram is not free. The kernel's time is its one-hot tiles, not its
-bytes (PERF.md section 5, the tile model): each block pays 8 [128, 128]
-tiles for its staging row and, with the histogram, 16 more (8 sublane rows
-x 256 bins = two tiles a row) — two thirds of the sweep: on a v5e at
-n = 66 M the call takes 105.6 ms with the histogram and 39.2 ms without,
-what the plain staging kernel takes (PERF.md, PR 25).
-It is an output of the ``pallas_call``, so XLA cannot drop it when nobody
-reads it; collectives/oktopk.py therefore asks for it under
-``threshold_method="hist"`` only, the one mode that reads it, and under
-"hist" it is built in every step although read on the recompute cadence
-only.
+The kernel's time is its one-hot tiles, not its bytes (PERF.md section 5,
+the tile model): each block pays 8 [128, 128] tiles for its staging row;
+on a v5e at n = 66 M the call takes 39.2 ms, what the plain staging kernel
+takes (PERF.md, PR 25).
 
 Steady-state sweeps over n after this module: the fused pass (2 reads +
 1 write), the phase-(a) scatter, and the single consumer pass (result
@@ -39,9 +28,9 @@ scale + winner mask + residual) — see docs/PERF.md.
 The staging mask uses the min-normal-clamped threshold exactly as
 ``_prep`` does; the probe count deliberately uses the UNCLAMPED probe
 threshold so it is bit-identical to the portable
-``jnp.sum(abs_acc >= lt * probe_ratio)`` (which has no clamp). The
-histogram covers nonzero in-range elements only, so the zero padding the
-kernel adds never shows up in any output.
+``jnp.sum(abs_acc >= lt * probe_ratio)`` (which has no clamp). Both
+counts are range-masked, so the zero padding the kernel adds never shows
+up in any output.
 
 All outputs reproduce the portable path bit-for-bit in interpret mode
 (tests/test_fused_select.py, same contract as ops/compaction.py);
@@ -71,23 +60,15 @@ from oktopk_tpu.ops.compaction import (
     _vma_of,
 )
 from oktopk_tpu.obs.anatomy import SUB_FINALIZE, SUB_SWEEP, phase_scope
-from oktopk_tpu.ops.hist_threshold import HIST_BINS, log2_bins, log2_hist
 
 
 def _fused_kernel(capb, t_ref, tp_ref, r_ref, g_ref, res_ref,
-                  acc_ref, w_ref, cr_ref, pr_ref, h_ref=None):
+                  acc_ref, w_ref, cr_ref, pr_ref):
     """Stage SB consecutive blocks of acc = grad + residual in one sweep.
 
     Outputs per grid step: the acc tile, the staging rows + raw counts of
-    ``_stage_kernel`` (identical layout), per-block probe counts, and —
-    only when the call has the fifth output (``with_hist``) — a
-    [SB, HIST_BINS] histogram accumulator (constant index_map: the block
-    stays resident in VMEM across grid steps and row sb accumulates
-    sub-block sb — the standard reduction-output pattern). Counts are f32
-    (MXU one-hot matmuls); each accumulator cell is bounded by n/SB, exact
-    in f32 for n up to 2^24 * SB = 134M elements. Without ``h_ref`` the
-    kernel builds no bins and no histogram tiles: 8 one-hot tiles a block
-    instead of 24.
+    ``_stage_kernel`` (identical layout) and per-block probe counts: 8
+    one-hot tiles a block.
     """
     import jax.experimental.pallas as pl
 
@@ -98,12 +79,7 @@ def _fused_kernel(capb, t_ref, tp_ref, r_ref, g_ref, res_ref,
             * BLK_COLS
             + jax.lax.broadcasted_iota(jnp.int32, (BLK_ROWS, BLK_COLS), 1))
 
-    if h_ref is not None:
-        @pl.when(i == 0)
-        def _():
-            h_ref[:] = jnp.zeros_like(h_ref)
-
-    rows_w, rows_r, rows_p, rows_h = [], [], [], []
+    rows_w, rows_r, rows_p = [], [], []
     for sb in range(SB):
         x = jax.lax.slice(acc, (sb * BLK_ROWS, 0),
                           ((sb + 1) * BLK_ROWS, BLK_COLS))
@@ -124,28 +100,14 @@ def _fused_kernel(capb, t_ref, tp_ref, r_ref, g_ref, res_ref,
         # never counts even when the probe threshold is 0
         probe = jnp.sum(((ax >= tp_ref[0]) & inr).astype(jnp.int32))
         rows_p.append(jnp.full((1, BLK_COLS), probe, jnp.int32))
-
-        if h_ref is not None:
-            # log2-magnitude histogram of live in-range elements: same
-            # one-hot NT matmul as the staging rows, with collisions doing
-            # the counting (HIST_BINS = 256: two tiles a sublane row)
-            bins = log2_bins(x)                           # -1 marks zeros
-            live = (bins >= 0) & inr
-            rows_h.append(_stage_tile(live.astype(jnp.int32),
-                                      jnp.maximum(bins, 0), HIST_BINS))
     w_ref[:] = jnp.concatenate(rows_w, axis=0)
     cr_ref[:] = jnp.concatenate(rows_r, axis=0)
     pr_ref[:] = jnp.concatenate(rows_p, axis=0)
-    if h_ref is not None:
-        h_ref[:] = h_ref[:] + jnp.concatenate(rows_h, axis=0)
 
 
-def _run_fused_stage(gp, rp, t, tp, rng, capb, nblocks, with_hist,
-                     interpret, vma):
+def _run_fused_stage(gp, rp, t, tp, rng, capb, nblocks, interpret, vma):
     """pallas_call wrapper: (acc_p [nb*8, 128], w_stage [nb, capb],
-    stored [nb], raw [nb], probe [nb], hist [HIST_BINS] or None). The
-    histogram is an output of the call, which XLA cannot drop: it exists
-    only when ``with_hist`` (static) asks for it."""
+    stored [nb], raw [nb], probe [nb])."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -162,18 +124,13 @@ def _run_fused_stage(gp, rp, t, tp, rng, capb, nblocks, with_hist,
         compat.shape_dtype_struct((nblocks, BLK_COLS), jnp.int32, vma=vma),
     ]
     out_specs = [tile, blocked(capb), blocked(BLK_COLS), blocked(BLK_COLS)]
-    if with_hist:
-        out_shapes.append(compat.shape_dtype_struct(
-            (SB, HIST_BINS), jnp.float32, vma=vma))
-        out_specs.append(pl.BlockSpec((SB, HIST_BINS),
-                                      lambda i, t, tp, r: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nblocks // SB,),
         in_specs=[tile, tile],
         out_specs=out_specs,
     )
-    acc_p, w, cr, pr, *h = pl.pallas_call(
+    acc_p, w, cr, pr = pl.pallas_call(
         functools.partial(_fused_kernel, capb),
         grid_spec=grid_spec,
         out_shape=out_shapes,
@@ -181,8 +138,7 @@ def _run_fused_stage(gp, rp, t, tp, rng, capb, nblocks, with_hist,
         name="oktopk_fused_select",
     )(t, tp, rng, gp, rp)
     raw = cr[:, 0]
-    hist = jnp.sum(h[0], axis=0).astype(jnp.int32) if with_hist else None
-    return acc_p, w, jnp.minimum(raw, capb), raw, pr[:, 0], hist
+    return acc_p, w, jnp.minimum(raw, capb), raw, pr[:, 0]
 
 
 class FusedStage(NamedTuple):
@@ -191,8 +147,6 @@ class FusedStage(NamedTuple):
     acc: jnp.ndarray           # [n] f32 — grad + residual
     local_count: jnp.ndarray   # i32 — realised count(|acc| >= thresh)
     probe_count: jnp.ndarray   # i32 — count(|acc| >= probe_thresh)
-    hist: jnp.ndarray | None   # [HIST_BINS] i32 — log2_hist(acc); None
-    #                            when the stage ran without ``with_hist``
     # staging internals (padded layout)
     accp: jnp.ndarray          # [nb*8, 128] padded acc tiles
     accflat: jnp.ndarray       # [nb*8*128] padded acc flat
@@ -203,16 +157,12 @@ class FusedStage(NamedTuple):
     rng: jnp.ndarray           # [2] element range [0, n)
 
 
-@functools.partial(jax.jit, static_argnames=("with_hist", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_select_stage(grad: jnp.ndarray, residual: jnp.ndarray, thresh,
-                       probe_thresh, with_hist: bool = True,
-                       interpret: bool | None = None) -> FusedStage:
+                       probe_thresh, interpret: bool | None = None
+                       ) -> FusedStage:
     """Run the fused kernel over (grad, residual): one sweep computes acc,
-    the fast staging rows, the realised/probe counts and, when
-    ``with_hist``, the histogram — two thirds of the kernel's one-hot
-    tiles, so a caller that will not read ``hist`` passes False and gets
-    ``hist=None`` (collectives/oktopk.py: only threshold_method="hist"
-    reads it). Every other field is bit-identical either way.
+    the fast staging rows and the realised/probe counts.
 
     The staging threshold is min-normal-clamped exactly as
     ``select_by_threshold_pallas`` (``_prep``); ``probe_thresh`` is used
@@ -230,11 +180,10 @@ def fused_select_stage(grad: jnp.ndarray, residual: jnp.ndarray, thresh,
     # stops at the nested pjit call op)
     with phase_scope("select", sub=SUB_SWEEP):
         return _fused_select_stage_impl(grad, residual, thresh,
-                                        probe_thresh, with_hist, interpret)
+                                        probe_thresh, interpret)
 
 
-def _fused_select_stage_impl(grad, residual, thresh, probe_thresh,
-                             with_hist, interpret):
+def _fused_select_stage_impl(grad, residual, thresh, probe_thresh, interpret):
     n = grad.size
     pad = (-n) % (SB * BLK)
     gp = jnp.pad(grad.reshape(-1), (0, pad)).reshape(-1, BLK_COLS)
@@ -250,12 +199,12 @@ def _fused_select_stage_impl(grad, residual, thresh, probe_thresh,
         tp = _pvary_to(tp, vma)
         rng = _pvary_to(rng, vma)
 
-    accp, w_f, stored_f, raw, probe_blk, hist = _run_fused_stage(
-        gp, rp, t, tp, rng, CAPB_FAST, nblocks, with_hist, interpret, vma)
+    accp, w_f, stored_f, raw, probe_blk = _run_fused_stage(
+        gp, rp, t, tp, rng, CAPB_FAST, nblocks, interpret, vma)
     accflat = accp.reshape(-1)
     return FusedStage(
         acc=accflat[:n], local_count=jnp.sum(raw),
-        probe_count=jnp.sum(probe_blk), hist=hist,
+        probe_count=jnp.sum(probe_blk),
         accp=accp, accflat=accflat, w_f=w_f, stored_f=stored_f, raw=raw,
         t=t, rng=rng)
 
@@ -291,15 +240,15 @@ def fused_select_pallas(grad: jnp.ndarray, residual: jnp.ndarray, thresh,
     """One-call form (unit tests / profiling): stage + finalize.
 
     Returns ``(acc, values [R, cap], indices [R, cap], counts [R],
-    local_count, probe_count, hist [HIST_BINS])`` — bit-identical to
+    local_count, probe_count)`` — bit-identical to
     :func:`fused_select_reference`.
     """
     st = fused_select_stage(grad, residual, thresh, probe_thresh,
-                            with_hist=True, interpret=interpret)
+                            interpret=interpret)
     values, indices, counts, _branch = fused_pack_finalize(
         st, boundaries, num_regions, cap, interpret=interpret)
     return (st.acc, values, indices, counts, st.local_count,
-            st.probe_count, st.hist)
+            st.probe_count)
 
 
 def fused_select_reference(grad: jnp.ndarray, residual: jnp.ndarray,
@@ -321,5 +270,4 @@ def fused_select_reference(grad: jnp.ndarray, residual: jnp.ndarray,
         acc, mask, jnp.asarray(boundaries, jnp.int32), num_regions, cap)
     local_count = jnp.sum(mask)
     probe_count = jnp.sum(abs_acc >= jnp.asarray(probe_thresh, acc.dtype))
-    return (acc, values, indices, counts, local_count, probe_count,
-            log2_hist(acc))
+    return acc, values, indices, counts, local_count, probe_count

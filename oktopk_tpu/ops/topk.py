@@ -37,16 +37,13 @@ def k2threshold(x_abs: jnp.ndarray, k: int):
 
 def k2threshold_method(x_abs: jnp.ndarray, k: int, method: str = "sort",
                        bisect_iters: int = 30):
-    """Dispatch between the exact sort-based threshold, the sort-free
-    bisection (ops/pallas_topk.py) and the one-pass histogram read
-    (ops/hist_threshold.py) — selected by
+    """Dispatch between the exact sort-based threshold ("sort": the
+    reference-faithful one, static k) and the sort-free bisection
+    ("bisect", ops/pallas_topk.py) — selected by
     ``OkTopkConfig.threshold_method``."""
     if method == "bisect":
         from oktopk_tpu.ops.pallas_topk import k2threshold_bisect
         return k2threshold_bisect(x_abs, k, iters=bisect_iters)
-    if method == "hist":
-        from oktopk_tpu.ops.hist_threshold import k2threshold_hist
-        return k2threshold_hist(x_abs, k)
     return k2threshold(x_abs, k)
 
 

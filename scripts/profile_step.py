@@ -9,10 +9,7 @@ subprograms on the same data instead:
   fwd_bwd      — loss + gradient only (the pure model compute path)
   select       — the full sparse allreduce on a same-sized flat gradient
                  (threshold + pack + exchange + gather + scatter)
-  select_hist  — the same allreduce under threshold_method="hist" (the
-                 one-pass lagged recompute; ops/hist_threshold.py)
   threshold    — just the exact k-th-value recompute (count-bisection)
-  hist         — just the one-pass histogram threshold (standalone form)
   fused_select — the single-sweep selection front-end of
                  ops/fused_select.py (portable reference twin on CPU —
                  the interpreter at real n takes minutes — the Pallas
@@ -180,7 +177,6 @@ def main():
         fused_select_pallas,
         fused_select_reference,
     )
-    from oktopk_tpu.ops.hist_threshold import k2threshold_hist
     from oktopk_tpu.ops.select import select_by_threshold
     from oktopk_tpu.ops.topk import k2threshold_method
     from oktopk_tpu.train.trainer import Trainer
@@ -228,33 +224,19 @@ def main():
     g = jax.device_put(jnp.asarray(rng.randn(1, n).astype(np.float32)))
 
     # The timed loop re-uses one state, freezing the step counter — pin it
-    # to an exact-recompute step (the branch where the threshold methods
-    # actually differ; predicted steps execute identical programs). A
-    # profile loop that re-used one state at step 1 would only ever time
-    # the predicted branch. This is also why the step builder's
-    # donate_state stays off here: a donated state is consumed by the
-    # first timed call.
+    # to an exact-recompute step (the branch that pays the threshold
+    # method; predicted steps never call it). A profile loop that re-used
+    # one state at step 1 would only ever time the predicted branch. This
+    # is also why the step builder's donate_state stays off here: a
+    # donated state is consumed by the first timed call.
     import dataclasses
 
-    def _steady(cfg_):
-        st0 = batched_init_state(cfg_)
-        _, st = step_fns[cfg_.threshold_method](g, st0)
-        pin = jnp.zeros_like(st.step) + cfg_.local_recompute_every
-        return dataclasses.replace(st, step=pin)
-
-    hcfg = acfg.replace(threshold_method="hist")
-    step_fns = {acfg.threshold_method: step,
-                "hist": build_allreduce_step("oktopk", hcfg, mesh,
-                                             warmup=False)}
-    state = _steady(acfg)
+    _, st = step(g, batched_init_state(acfg))
+    state = dataclasses.replace(
+        st, step=jnp.zeros_like(st.step) + acfg.local_recompute_every)
     out["select_ms"] = med(lambda: step(g, state)[0], "select_ms")
 
-    # --- the same allreduce under the one-pass histogram threshold
-    hstate = _steady(hcfg)
-    out["select_hist_ms"] = med(
-        lambda: step_fns["hist"](g, hstate)[0], "select_hist_ms")
-
-    # --- components: exact threshold (bisect + hist), and the pack
+    # --- components: the exact threshold, and the pack
     k = acfg.k
     gf = g[0]
     thr_fn = jax.jit(lambda x: k2threshold_method(jnp.abs(x), k,
@@ -264,16 +246,12 @@ def main():
     out["threshold_ms"] = med(lambda: thr_fn(gf), "threshold_ms")
     t = thr_fn(gf)
 
-    hist_fn = jax.jit(lambda x: k2threshold_hist(jnp.abs(x), k))
-    sync(hist_fn(gf))
-    out["hist_ms"] = med(lambda: hist_fn(gf), "hist_ms")
-
     pk = jax.jit(lambda x: select_by_threshold(
         x, t, acfg.cap_gather, use_pallas=bool(acfg.use_pallas)))
     sync(pk(gf))
     out["pack_ms"] = med(lambda: pk(gf), "pack_ms")
 
-    # --- the fused single-sweep front-end (acc + stage + counts + hist).
+    # --- the fused single-sweep front-end (acc + stage + counts).
     # The Pallas interpreter at real n is minutes-slow, so off-TPU the
     # probe times the portable semantics twin — the XLA-fused equivalent
     # of the separate passes it replaces; the kernel itself is timed on

@@ -49,15 +49,13 @@ ALGOS = ["dense", "topkA", "topkA2", "topkAopt", "gtopk", "gaussiank",
 _WIRE_CACHE = {}
 
 
-def _measure_wire_bytes(name, cfg, mesh, rng, steps=9, key=None):
+def _measure_wire_bytes(name, cfg, mesh, rng, steps=9):
     """Per-step mean realised wire bytes (averaged over workers) in
     steady state: oktopk's every-4th-step exact recomputes draw from the
     larger cap_exact pool and are excluded, exactly like bench.py's
-    volume probe. ``key`` disambiguates cache entries for non-default
-    configs (e.g. a different threshold_method)."""
-    key = key or name
-    if key in _WIRE_CACHE:
-        return _WIRE_CACHE[key]
+    volume probe."""
+    if name in _WIRE_CACHE:
+        return _WIRE_CACHE[name]
     step = build_allreduce_step(name, cfg, mesh, warmup=False)
     state = batched_init_state(cfg)
     base = rng.randn(cfg.num_workers, cfg.n).astype(np.float32)
@@ -68,8 +66,8 @@ def _measure_wire_bytes(name, cfg, mesh, rng, steps=9, key=None):
         _, state = step(grads, state)
         if name != "oktopk" or i % cfg.global_recompute_every != 0:
             wires.append(float(np.asarray(state.last_wire_bytes).mean()))
-    _WIRE_CACHE[key] = sum(wires) / len(wires)
-    return _WIRE_CACHE[key]
+    _WIRE_CACHE[name] = sum(wires) / len(wires)
+    return _WIRE_CACHE[name]
 
 
 class TestWireConformance:
@@ -124,24 +122,6 @@ class TestWireConformance:
         cfg = self._cfg()
         mean_wire = _measure_wire_bytes("dense", cfg, mesh8, rng)
         assert mean_wire == pytest.approx(8.0 * self.N)
-
-    def test_hist_threshold_bounded_overshoot(self, mesh8, rng):
-        """The one-pass histogram threshold estimator trades threshold
-        exactness for the single scan, so it may select past k — its
-        wire contract is the capacity ceiling the fixed buffers enforce,
-        plus a bounded overshoot of the sort path's O(6k) budget (the
-        realised factor is ~1.45x; 2x is the regression tripwire)."""
-        cfg = self._cfg().replace(threshold_method="hist")
-        mean_wire = _measure_wire_bytes("oktopk", cfg, mesh8, rng,
-                                        key="oktopk:hist")
-        assert mean_wire > 0
-        assert mean_wire <= obs_volume.capacity_bytes("oktopk", cfg), (
-            f"oktopk[hist]: measured {mean_wire:.0f} B/step exceeds the "
-            "fixed-buffer capacity ceiling")
-        ratio = obs_volume.conformance_ratio("oktopk", cfg, mean_wire)
-        assert ratio <= 2.0, (
-            f"oktopk[hist]: overshoot ratio {ratio:.3f} vs the sort "
-            "path's budget — histogram threshold quality regressed")
 
 
 def _load_obs_report():

@@ -310,8 +310,7 @@ class TestDenseVsSparseOracle:
         """The Pallas fused-select branch journals the same fidelity
         the unfused path does (interpret mode on the CPU mesh)."""
         monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
-        cfg = self._cfg(use_pallas=True, fuse_select=True,
-                        wire_dtype="float32")
+        cfg = self._cfg(use_pallas=True, wire_dtype="float32")
         rows = _oracle_run("oktopk", cfg, mesh8, check_vma=False)
         _assert_oracle(rows, "oktopk[fused]")
 

@@ -35,7 +35,7 @@ from test_compaction import (  # noqa: E402
     check_pack_prefix, check_select_prefix, overflow_vector,
     small_chunk_jits, straddling_bounds)
 from test_fused_select import (  # noqa: E402
-    assert_all_equal as fused_assert_all_equal, both_forms,
+    assert_all_equal as fused_assert_all_equal, layouts, region_bounds,
     run_both as fused_run_both)
 
 
@@ -146,54 +146,33 @@ def test_mesh_supports_pallas_on_hw(tpu_dev):
     assert mesh_supports_pallas(mesh)
 
 
-@both_forms
-def test_fused_select_parity_on_chip(tpu_dev, with_hist):
+@layouts
+def test_fused_select_parity_on_chip(tpu_dev, layout):
     """Mirror of tests/test_fused_select.py fast-branch parity on silicon:
     the fused residual+select+stage kernel (ops/fused_select.py) compiled
     through Mosaic must reproduce the portable separate-pass outputs —
-    acc, staged regions, realised count, unclamped probe count, and the
-    MXU one-hot histogram — bit-for-bit; and the form without the
-    histogram output everything else, staging rows and branch included
-    (``run_both``)."""
+    acc, staged regions, realised count and unclamped probe count — bit
+    for bit, over one region and over three whose boundaries lie inside a
+    block."""
     rng = np.random.RandomState(21)
     n = 1 << 18
     g = rng.randn(n).astype(np.float32)
     r = (0.1 * rng.randn(n)).astype(np.float32)
+    bnd = region_bounds(layout, n, (n // 3, n - 300))
     with jax.default_device(tpu_dev):
-        got, want = fused_run_both(g, r, 2.0, [0, n // 3, n], 2, 4096,
-                                   with_hist, interpret=False)
+        got, want, branch = fused_run_both(g, r, 2.0, bnd, 4096,
+                                           interpret=False)
     fused_assert_all_equal(got, want)
+    assert branch[0] == 0
 
 
-def test_fused_hist_bins_bitcast_on_chip(tpu_dev):
-    """The histogram bins come from f32 exponent-bit extraction
-    (hist_threshold.log2_bins); the fused kernel reproduces them via MXU
-    one-hot accumulation. Octave-boundary magnitudes (exact powers of two,
-    where a float log2 rounds wrong) must land in the right bin under
-    Mosaic's bitcast lowering, matching the host-side scatter-add."""
-    from oktopk_tpu.ops.fused_select import fused_select_pallas
-    from oktopk_tpu.ops.hist_threshold import log2_hist
-
-    rng = np.random.RandomState(22)
-    n = 1 << 15
-    g = (rng.randn(n) * 10.0 ** rng.randint(-30, 20, n)).astype(np.float32)
-    g[::7] = np.exp2(rng.randint(-40, 20, len(g[::7]))).astype(np.float32)
-    r = np.zeros(n, np.float32)
-    bounds = np.array([0, n], np.int32)
-    with jax.default_device(tpu_dev):
-        hist = np.asarray(fused_select_pallas(
-            jnp.asarray(g), jnp.asarray(r), 1.0, 1.25, jnp.asarray(bounds),
-            1, 4096, interpret=False)[6])
-    np.testing.assert_array_equal(hist, np.asarray(log2_hist(jnp.asarray(g))))
-
-
-@both_forms
-def test_fused_repair_branch_parity_on_chip(tpu_dev, with_hist):
+@layouts
+def test_fused_repair_branch_parity_on_chip(tpu_dev, layout):
     """Mirror of tests/test_fused_select.py::test_repair_branch on silicon:
     scattered dense blocks overflow CAPB_FAST so the shared _pack_finalize
     repair kernel re-stages them from the FUSED kernel's own acc output —
     the handoff between the fused staging layout and the repair path under
-    Mosaic, in both forms of the fused kernel."""
+    Mosaic, with the region boundaries inside two of the hot blocks."""
     from oktopk_tpu.ops.compaction import BLK, CAPB_FAST, _novf_cap
 
     rng = np.random.RandomState(23)
@@ -203,11 +182,14 @@ def test_fused_repair_branch_parity_on_chip(tpu_dev, with_hist):
         g[b * BLK:(b + 1) * BLK] = rng.randn(BLK) * 10 + 20
     r = (0.01 * rng.randn(n)).astype(np.float32)
     raw = (np.abs(g + r).reshape(-1, BLK) >= 1.0).sum(axis=1)
-    assert 0 < int((raw > CAPB_FAST).sum()) <= _novf_cap(64)
+    novf = int((raw > CAPB_FAST).sum())
+    assert 0 < novf <= _novf_cap(64)
+    bnd = region_bounds(layout, n, (3 * BLK + 700, 40 * BLK + 300))
     with jax.default_device(tpu_dev):
-        got, want = fused_run_both(g, r, 1.0, [0, n // 2, n], 2, 8 * BLK,
-                                   with_hist, interpret=False)
+        got, want, branch = fused_run_both(g, r, 1.0, bnd, 8 * BLK,
+                                           interpret=False)
     fused_assert_all_equal(got, want)
+    assert branch.tolist() == [1, novf]
 
 
 @pytest.mark.parametrize("novf", sorted(REPAIR_SURVIVORS))
