@@ -17,6 +17,19 @@ Attention runs a block of queries at a time against the keys at or before
 it (:func:`blocked_causal_attention`), each block recomputed in the backward
 pass, a sequence at a time, so no [heads, T, T] score tensor is ever alive.
 
+**Recomputation**, two levels. Every decoder layer is recomputed in the
+backward pass (``nn.remat``) from what the forward pass keeps of it: its
+input and its attention output (``ATTN_OUT``: [B, T, heads, v_head_dim] in
+the compute dtype, the size of the input, kept a query block at a time).
+Inside a layer each query block, the routed experts' branch and each
+sequence of a dense layer's SwiGLU recompute themselves
+(``jax.checkpoint``). So a block's scores, mask and softmax run twice
+before their backward pass (the forward pass and the block's own
+recomputation: the layer's starts ``o_proj`` from the kept blocks), the
+routed experts' products twice as well (nothing in the layer's backward
+pass needs their output, so the layer's recomputation of them is dead
+code), and no score block is ever kept.
+
 **Routed experts** (:class:`MoE`): ``s = softmax(h W_r)`` over ALL
 ``n_routed_experts`` in float32 at ``highest`` precision, greedy top-k,
 weights unrenormalised unless ``norm_topk_prob``. ``held_experts`` says
@@ -50,10 +63,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from oktopk_tpu.obs.anatomy import phase_scope
 
 HIGHEST = lax.Precision.HIGHEST
+# what a decoder layer keeps across its own recomputation beside its input:
+# the output of blocked_causal_attention, tagged a query block at a time
+ATTN_OUT = "attn_out"
 
 
 # ---- rotary embedding under YaRN ------------------------------------------
@@ -117,7 +134,11 @@ def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
     """Causal attention [B, T, H, d] -> [B, T, H, dv], a sequence at a time
     and ``block`` queries at a time. Each block's scores are recomputed in
     the backward pass (``jax.checkpoint``), so the largest score tensor
-    alive is [H, block, T], of one sequence."""
+    alive is [H, block, T], of one sequence. Each block's output carries
+    the name ``ATTN_OUT``, for a caller that recomputes all of this and
+    would keep the output (``save_only_these_names``): a block at a time,
+    because XLA:TPU packs [B, block, H, dv] pieces into the holes of its
+    heap, and one [B, T, H, dv] array that lives as long raises it."""
     t = q_nope.shape[1]
     block = min(block, t)
 
@@ -128,7 +149,8 @@ def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
             end = min(start + block, t)
             fn = jax.checkpoint(partial(_attend_block, start=start, end=end,
                                         scale=scale))
-            outs.append(fn(qn[start:end], qp[start:end], kn, kp, vv))
+            outs.append(checkpoint_name(
+                fn(qn[start:end], qp[start:end], kn, kp, vv), ATTN_OUT))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
     return lax.map(one_sequence, (q_nope, q_pe, k_nope, k_pe, v))
@@ -476,9 +498,14 @@ class DeepseekV2(nn.Module):
     def __call__(self, tokens, train: bool = True):
         del train   # no dropout
         c = self.cfg
-        # each layer is recomputed in the backward pass: its input is all
-        # that the forward pass keeps
-        layer_cls = nn.remat(DecoderLayer)
+        # each layer is recomputed in the backward pass from its input and
+        # its attention output, all that the forward pass keeps of it: the
+        # recomputation starts o_proj and what follows from the kept blocks
+        # and runs no block's scores (each block's own checkpoint still
+        # does, once, for its backward pass)
+        layer_cls = nn.remat(
+            DecoderLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT))
         x = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
                      name="embed")(tokens)
         counts = []

@@ -79,6 +79,20 @@ def program_loss(model, batch):
     return jax.jit(jax.value_and_grad(loss, has_aux=True))
 
 
+def gradient_jaxpr(model, params, batch):
+    """The gradient's program as JAX hands it to XLA, as text."""
+    return str(jax.make_jaxpr(
+        lambda p: program_loss(model, batch).__wrapped__(p)[1])(params))
+
+
+def forget_the_attention_output(monkeypatch):
+    """The parent's form, for a comparison: every ``nn.remat`` that
+    ``models/deepseek_v2.py`` makes from here on has no policy, so a layer
+    keeps its input alone."""
+    remat = ds.nn.remat
+    monkeypatch.setattr(ds.nn, "remat", lambda cls, policy=None: remat(cls))
+
+
 def leaf_gaps(prog, ref):
     flat = jax.tree_util.tree_flatten_with_path(prog)[0]
     return {jax.tree_util.keystr(path): float(
@@ -126,11 +140,48 @@ class TestAgainstReference:
         ``benchmark/configs/deepseek_v2_lite_ep8.json`` says under
         ``recompute``."""
         model, params, batch, _ = tiny
-        text = str(jax.make_jaxpr(
-            lambda p: program_loss(model, batch).__wrapped__(p)[1])(params))
+        text = gradient_jaxpr(model, params, batch)
         expert_layers = (model.cfg.num_hidden_layers
                          - model.cfg.first_k_dense_replace)
         assert text.count("ragged_dot_general[") == 12 * expert_layers
+
+    @pytest.mark.parametrize("kept, passes", [(True, 2), (False, 3)])
+    def test_passes_over_a_blocks_scores(self, tiny, monkeypatch, kept,
+                                         passes):
+        """A block's scores, mask and softmax are in the gradient's program
+        twice a layer before their backward pass: the forward pass's and
+        the block's own ``jax.checkpoint``. The layer's recomputation
+        starts from the kept attention output (``ds.ATTN_OUT``) and runs
+        none; with the layer's remat given no policy (built here, no
+        option in the package) it runs a third. The experts' twelve
+        grouped products a layer are the same either way."""
+        model, params, batch, _ = tiny
+        if not kept:
+            forget_the_attention_output(monkeypatch)
+        text = gradient_jaxpr(model, params, batch)
+        cfg, t = model.cfg, batch["tokens"].shape[1]
+        for end in range(cfg.attn_block, t + 1, cfg.attn_block):
+            scores = (f"f32[{cfg.num_attention_heads},{cfg.attn_block},"
+                      f"{end}] = exp ")
+            assert text.count(scores) == passes * cfg.num_hidden_layers, end
+        expert_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
+        assert text.count("ragged_dot_general[") == 12 * expert_layers
+
+    def test_the_kept_attention_output_changes_no_bit(self, tiny,
+                                                      monkeypatch):
+        """The kept array holds the values the recomputation would have
+        made: loss and every gradient leaf are bit-equal to the same model
+        with the layer's remat given no policy."""
+        model, params, batch, _ = tiny
+        (loss, rows), grads = program_loss(model, batch)(params)
+        forget_the_attention_output(monkeypatch)
+        (loss0, rows0), grads0 = program_loss(model, batch)(params)
+        assert float(loss) == float(loss0)
+        assert np.array_equal(np.asarray(rows), np.asarray(rows0))
+        flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+        assert len(flat) == 51
+        for (path, a), b in zip(flat, jax.tree.leaves(grads0)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), path
 
     def test_counters_equal_the_reference_routing(self, tiny):
         """``expert_rows``: the reference's own routing, layer by layer on
