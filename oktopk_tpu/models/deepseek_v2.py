@@ -32,7 +32,9 @@ code), and no score block is ever kept.
 
 **Routed experts** (:class:`MoE`): ``s = softmax(h W_r)`` over ALL
 ``n_routed_experts`` in float32 at ``highest`` precision, greedy top-k,
-weights unrenormalised unless ``norm_topk_prob``. ``held_experts`` says
+weights unrenormalised unless ``norm_topk_prob`` (then over the k, held or
+not); the shared experts sit behind a sigmoid gate where ``shared_gate``
+(``models/qwen3_next.py``). ``held_experts`` says
 which experts this chip holds (expert parallelism: the others live on other
 chips); the layer computes ``sum_{e in topk, e held} s_e E_e(h)`` plus the
 shared experts, and what the absent experts would add is left out: no code
@@ -355,6 +357,8 @@ class MoE(nn.Module):
     routed_scaling_factor: float
     norm_topk_prob: bool
     dtype: Any = jnp.float32
+    # the shared experts' output times sigmoid(x w_g), a scalar a token
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, h):
@@ -389,9 +393,25 @@ class MoE(nn.Module):
                 expert_capacity(tokens, held, k, self.n_routed_experts), k)
         if self.n_shared_experts:
             with phase_scope("fwd_bwd", sub="shared"):
-                y = y + SwiGLU(f * self.n_shared_experts, self.dtype,
-                               name="shared_ffn")(x)
+                shared = SwiGLU(f * self.n_shared_experts, self.dtype,
+                                name="shared_ffn")(x)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        name="shared_gate")(x))
+                y = y + shared
         return y.reshape(shape), counts
+
+
+def held_ids(held, experts: int) -> Tuple[int, ...]:
+    """A configuration's ``held_experts`` as a tuple of distinct ids under
+    ``experts``, at least one; None: all of them."""
+    held = tuple(int(e) for e in (range(experts) if held is None else held))
+    if not held or len(set(held)) != len(held) or not all(
+            0 <= e < experts for e in held):
+        raise ValueError(f"held_experts {held}: distinct ids under "
+                         f"{experts}, at least one")
+    return held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -429,14 +449,8 @@ class DeepseekV2Config:
     dtype: Any = jnp.float32
 
     def __post_init__(self):
-        held = (range(self.n_routed_experts) if self.held_experts is None
-                else self.held_experts)
-        held = tuple(int(e) for e in held)
-        if not held or len(set(held)) != len(held) or not all(
-                0 <= e < self.n_routed_experts for e in held):
-            raise ValueError(f"held_experts {held}: distinct ids under "
-                             f"{self.n_routed_experts}, at least one")
-        object.__setattr__(self, "held_experts", held)
+        object.__setattr__(self, "held_experts", held_ids(
+            self.held_experts, self.n_routed_experts))
 
     @classmethod
     def tiny(cls, **kw):

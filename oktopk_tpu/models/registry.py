@@ -12,6 +12,7 @@ from oktopk_tpu.models.alexnet import AlexNet
 from oktopk_tpu.models.caffe_cifar import CaffeCifar
 from oktopk_tpu.models.densenet import DenseNet
 from oktopk_tpu.models.preresnet import PreResNet
+from oktopk_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from oktopk_tpu.models.resnext import ResNeXt
 from oktopk_tpu.models.bert import BertConfig, BertForPreTraining
 from oktopk_tpu.models.deepseek_v2 import DeepseekV2, DeepseekV2Config
@@ -40,6 +41,8 @@ TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "lstm_tiny": (35, 1024),
     "deepseek_v2_lite": (4096, 102400),
     "deepseek_v2_tiny": (64, 512),
+    "qwen3_next_80b_a3b": (8192, 151936),
+    "qwen3_next_tiny": (64, 512),
 }
 
 
@@ -78,6 +81,13 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
     "deepseek_v2_tiny": lambda **kw: (
         DeepseekV2(DeepseekV2Config.tiny(**kw)),
         _tokens(*TOKEN_LMS["deepseek_v2_tiny"])),
+    # Qwen3-Next-80B-A3B-Instruct at its published config.json; a chip's
+    # share comes as model_kwargs, as for deepseek_v2_lite.
+    "qwen3_next_80b_a3b": lambda **kw: (
+        Qwen3Next(Qwen3NextConfig(**kw)), _tokens(64, 151936)),
+    "qwen3_next_tiny": lambda **kw: (
+        Qwen3Next(Qwen3NextConfig.tiny(**kw)),
+        _tokens(*TOKEN_LMS["qwen3_next_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

@@ -68,12 +68,16 @@ SUB_FINALIZE = "finalize"        # stage: census, prefix, branch, gathers
 SUB_GLOBAL = "global"            # select: phase-(b) winner selection
 SUB_FEEDBACK = "feedback"        # select: controller feedback
 # ... and inside ``fwd_bwd``, entered by the model itself
-# (models/deepseek_v2.py), so forward, recomputed and backward operations
-# alike carry them: a model that enters none leaves the phase unscoped.
+# (models/deepseek_v2.py, models/qwen3_next.py), so forward, recomputed and
+# backward operations alike carry them: a model that enters none leaves the
+# phase unscoped. ``delta_rule`` (the chunked recurrence alone) lies inside
+# ``linear_attention`` (its projections, convolution, gates and norm): a
+# reader takes the innermost.
 SUB_SCOPES = {
     "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
     "stage": (SUB_REPARTITION, SUB_FINALIZE),
-    "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head"),
+    "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head",
+                "linear_attention", "delta_rule"),
 }
 
 # phases whose time is wire time; everything else in the contract is

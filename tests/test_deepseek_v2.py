@@ -421,7 +421,11 @@ class TestRegistryAndScopes:
         text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
         import re
         paths = set(re.findall(r'op_name="([^"]*)"', text))
-        for sub in anatomy.SUB_SCOPES["fwd_bwd"]:
+        # the sub-scopes this model enters (``linear_attention`` and
+        # ``delta_rule`` are models/qwen3_next.py's)
+        entered = ("attention", "router", "experts", "shared", "mlp", "head")
+        assert set(entered) <= set(anatomy.SUB_SCOPES["fwd_bwd"])
+        for sub in entered:
             mine = [p for p in paths if f"anat/fwd_bwd/{sub}" in p]
             assert mine, sub
             assert any("transpose" in p for p in mine), sub  # backward too
