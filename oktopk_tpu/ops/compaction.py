@@ -9,9 +9,11 @@ work by what the hardware is good at:
 
 1. A Pallas *staging* kernel does the n-scale work: per 1024-element block
    (one [8, 128] f32 tile), threshold-mask -> in-block exclusive prefix sum
-   (Hillis-Steele shifted adds on the VPU) -> one [1,128] x [capb,128]^T
-   MXU matmul per sublane row that drops each survivor's in-block offset
-   (< 1024, exact in f32 at Precision.HIGHEST) into its packed slot. Each
+   (Hillis-Steele shifted adds on the VPU) -> one [16,128] x [capb,128]^T
+   MXU matmul per sublane row, a single bf16 pass, that drops each
+   survivor's in-block offset into its packed slot as its two digits (lane
+   0-127 and row 0-7: bf16 holds integers up to 256, not an offset up to
+   1023), put together once a block (``_stage_tile``). Each
    block writes its own staging row — standard blocked VMEM outputs, no
    cross-block sequencing, so the grid pipelines freely.
 2. Plain-XLA post-processing does the cap-scale work with *gathers* (the
@@ -151,37 +153,62 @@ def _block_prefix(m):
     return s - m + (r - rt), jnp.sum(m)           # (excl. positions, total)
 
 
-def _stage_tile(woff, sel, capb):
-    """The MXU "scatter": stage[j] = in-block offset of the element whose
-    packed slot is ``j``, as one [1, capb] f32 row.
+# rows of the one-hot product's LHS: bfloat16's minimum tile is (16, 128)
+# (8 rows time alike on a v5e: PERF.md, PR 34). Rows 0 and 1 carry the two
+# digits of the in-block offset; the rest are zeros that ride along unread
+STAGE_ROWS = 16
+
+
+def _onehot_pass(rows, selr, capb):
+    """ONE bf16 pass of the MXU "scatter": ``out[m, j] = rows[m, l]`` of the
+    lane ``l`` whose packed slot ``selr[0, l]`` is ``j``, 0 where no lane's
+    is; ``[M, capb]`` f32. Exact for ``rows`` that bfloat16 holds (integers
+    up to 256): each product is such a number times 1.0 and each sum has at
+    most one non-zero term, accumulated in f32.
 
     Mosaic rejects cross-lane reshapes — the obvious ``[8,128] -> [BLK,1]``
     one-hot layout is an "unsupported shape cast" on real hardware (the
     interpreter accepts it, which is why only a chip run catches it). So
-    everything stays in tile layout: per sublane-row, broadcast the row's
-    slot vector along a fresh sublane axis, compare with a sublane iota to
-    get the transposed one-hot [capb, 128], and contract both operands on
-    their lane axis (an NT matmul — dimension numbers ((1,),(1,))). Slots
-    are distinct across rows so the accumulation is collision-free."""
+    everything stays in tile layout: broadcast the row's slot vector along
+    a fresh sublane axis, compare with a sublane iota to get the transposed
+    one-hot [capb, 128], and contract both operands on their lane axis (an
+    NT matmul — dimension numbers ((1,),(1,))). Mosaic never builds the
+    one-hot: it pushes a constant 1.0 into the MXU under the compare's mask
+    (PERF.md section 5)."""
     # i32 iota/compare: tpu.iota verifies only integer result types (a
     # float iota fails Mosaic verification on the real chip; the
     # interpreter accepts it)
     jio = jax.lax.broadcasted_iota(jnp.int32, (capb, BLK_COLS), 0)
-    acc = jnp.zeros((1, capb), jnp.float32)
+    onehot_t = (jnp.broadcast_to(selr, (capb, BLK_COLS)) == jio) \
+        .astype(jnp.float32)                                   # [capb, 128]
+    # both operands cast to bf16 by hand, not f32 at Precision.DEFAULT: the
+    # chip rounds either to bf16 on its way into the MXU, but the Pallas
+    # interpreter multiplies f32 exactly, and would hide a row that bf16
+    # cannot hold from every CPU test
+    return jax.lax.dot_general(
+        rows.astype(jnp.bfloat16), onehot_t.astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _stage_tile(sel, capb):
+    """stage[j] = in-block offset of the element whose packed slot is
+    ``sel[r, l] == j``, as one [1, capb] f32 row; ``sel == capb`` (a dropped
+    element) matches no slot and stages nothing.
+
+    The offset ``r * 128 + l`` (0-1023) does not fit bf16's 8 significant
+    bits, its two digits do: the lane (0-127) and the row (0-7) go through
+    ``_onehot_pass`` as two LHS rows, one pass a sublane row, and the offset
+    is put together once a block from the [1, capb] sums. The bound on the
+    digits is the block's geometry, whatever ``capb``. Slots are distinct
+    across rows so the accumulation is collision-free."""
+    mio = jax.lax.broadcasted_iota(jnp.int32, (STAGE_ROWS, BLK_COLS), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (STAGE_ROWS, BLK_COLS), 1)
+    acc = jnp.zeros((STAGE_ROWS, capb), jnp.float32)
     for r in range(BLK_ROWS):
+        digits = jnp.where(mio == 0, lane, jnp.where(mio == 1, r, 0))
         selr = jax.lax.slice(sel, (r, 0), (r + 1, BLK_COLS))   # [1, 128]
-        onehot_t = (jnp.broadcast_to(selr, (capb, BLK_COLS)) == jio) \
-            .astype(jnp.float32)                               # [capb, 128]
-        wr = jax.lax.slice(woff, (r, 0),
-                           (r + 1, BLK_COLS)).astype(jnp.float32)
-        # HIGHEST precision: the default matmul path feeds the MXU bf16
-        # inputs (8 mantissa bits), silently rounding offsets > 256;
-        # HIGHEST decomposes f32 exactly, keeping one-hot x offset exact.
-        acc = acc + jax.lax.dot_general(
-            wr, onehot_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
-    return acc
+        acc = acc + _onehot_pass(digits.astype(jnp.float32), selr, capb)
+    return acc[0:1] + acc[1:2] * BLK_COLS
 
 
 def _stage_kernel(capb, t_ref, r_ref, x_ref, w_ref, cr_ref):
@@ -212,7 +239,7 @@ def _stage_kernel(capb, t_ref, r_ref, x_ref, w_ref, cr_ref):
         kept = mask & (pos < capb)
         sel = jnp.where(kept, pos, capb)                  # capb = dropped
 
-        rows_w.append(_stage_tile(jnp.where(kept, woff, 0), sel, capb))
+        rows_w.append(_stage_tile(sel, capb))
         rows_r.append(jnp.full((1, BLK_COLS), raw, jnp.int32))
     w_ref[:] = jnp.concatenate(rows_w, axis=0)
     cr_ref[:] = jnp.concatenate(rows_r, axis=0)
@@ -288,8 +315,7 @@ def _repair_kernel(t_ref, r_ref, bl_ref, nv_ref, x_ref, w_ref):
                 kept_p = (mask & (pos >= p * BLK_COLS)
                           & (pos < (p + 1) * BLK_COLS))
                 sel_p = jnp.where(kept_p, pos - p * BLK_COLS, BLK_COLS)
-                w_ref[p:p + 1, :] = _stage_tile(
-                    jnp.where(kept_p, woff, 0), sel_p, BLK_COLS)
+                w_ref[p:p + 1, :] = _stage_tile(sel_p, BLK_COLS)
 
 
 def _run_repair(xp, t, rng, bl, novf, novf_cap, interpret, vma):

@@ -92,7 +92,7 @@ def _fused_kernel(capb, t_ref, tp_ref, r_ref, g_ref, res_ref,
 
         kept = mask & (pos < capb)
         sel = jnp.where(kept, pos, capb)                  # capb = dropped
-        rows_w.append(_stage_tile(jnp.where(kept, woff, 0), sel, capb))
+        rows_w.append(_stage_tile(sel, capb))
         rows_r.append(jnp.full((1, BLK_COLS), raw, jnp.int32))
 
         # Newton probe: unclamped threshold (bit-parity with the portable

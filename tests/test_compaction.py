@@ -192,9 +192,114 @@ def check_pack_prefix(pack, branch, case):
     np.testing.assert_array_equal(gv.view(np.int32), wv.view(np.int32))
 
 
+# ---- the one-hot product (``_stage_tile``) -----------------------------
+# Eight blocks a call. Block b gives a packed slot to the in-block offsets
+# o with o % (1024 / capb) == b % (1024 / capb): every offset 0-1023 is
+# staged, from all eight sublane rows of a block, at capb 128 (an eighth of
+# them a block) and at 1,024 (all of them in every block). The layouts say
+# which slot an offset gets: its rank (offset 0 or b in the first slot, as
+# the kernels pack survivors), the reverse (offset 1023 in the first slot,
+# the smallest in the last), a seeded shuffle, and a shuffle in which every
+# third element is dropped (slot ``capb``, which no one-hot row matches).
+STAGE_TILE_BLOCKS = 8
+STAGE_TILE_LAYOUTS = ("ascending", "descending", "shuffled", "dropped")
+
+
+def stage_tile_slots(capb, layout, seed=41):
+    """``sel [8 * 8, 128]`` i32 (a block's slots in its [8, 128] tile
+    layout; ``capb`` = no slot) and the portable staging rows
+    ``[8, capb]`` f32 they must give: ``stage[b, sel] = offset``, 0 where
+    no element's slot is."""
+    rng = np.random.RandomState(seed)
+    stride = BLK // capb
+    sel = np.full((STAGE_TILE_BLOCKS, BLK), capb, np.int32)
+    want = np.zeros((STAGE_TILE_BLOCKS, capb), np.float32)
+    for b in range(STAGE_TILE_BLOCKS):
+        offsets = np.arange(b % stride, BLK, stride)
+        slots = np.arange(capb)
+        if layout == "descending":
+            slots = slots[::-1]
+        elif layout in ("shuffled", "dropped"):
+            slots = rng.permutation(capb)
+        keep = np.ones(capb, bool)
+        if layout == "dropped":
+            keep[rng.randint(3)::3] = False
+        sel[b, offsets[keep]] = slots[keep]
+        want[b, slots[keep]] = offsets[keep]
+    return sel.reshape(-1, 128), want
+
+
+def stage_tile_call(capb, interpret, undigited=False):
+    """``_stage_tile`` alone over eight blocks' slots, as a kernel of its
+    own: ``sel [64, 128]`` i32 -> ``[8, capb]`` f32. ``undigited`` stages
+    the whole offset ``r * 128 + l`` as ONE row of the same single-pass
+    product."""
+    import jax
+    import jax.experimental.pallas as pl
+
+    from oktopk_tpu.ops.compaction import (BLK_COLS, BLK_ROWS, STAGE_ROWS,
+                                           _onehot_pass, _stage_tile)
+
+    def whole_offset(sel_b, capb):
+        mio = jax.lax.broadcasted_iota(jnp.int32, (STAGE_ROWS, BLK_COLS), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (STAGE_ROWS, BLK_COLS), 1)
+        acc = jnp.zeros((STAGE_ROWS, capb), jnp.float32)
+        for r in range(BLK_ROWS):
+            rows = jnp.where(mio == 0, r * BLK_COLS + lane, 0)
+            acc = acc + _onehot_pass(rows.astype(jnp.float32),
+                                     sel_b[r:r + 1], capb)
+        return acc[0:1]
+
+    stage = whole_offset if undigited else _stage_tile
+
+    def kernel(sel_ref, w_ref):
+        s = sel_ref[:]
+        w_ref[:] = jnp.concatenate([
+            stage(s[b * BLK_ROWS:(b + 1) * BLK_ROWS], capb)
+            for b in range(STAGE_TILE_BLOCKS)], axis=0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((STAGE_TILE_BLOCKS, capb),
+                                       jnp.float32),
+        interpret=interpret, name=f"stage_tile_w{capb}")
+
+
+def check_stage_tile(capb, layout, interpret):
+    sel, want = stage_tile_slots(capb, layout)
+    got = np.asarray(stage_tile_call(capb, interpret)(jnp.asarray(sel)))
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+
+
+def check_whole_offset_rounds(interpret):
+    """Why the digits: the offset as one row of the same bf16 pass is exact
+    up to 256 (8 significant bits) and wrong at every odd offset above."""
+    sel, want = stage_tile_slots(BLK, "ascending")
+    assert (want == np.arange(BLK)).all()             # slot j holds j
+    got = np.asarray(stage_tile_call(BLK, interpret, undigited=True)(
+        jnp.asarray(sel)))
+    np.testing.assert_array_equal(got[:, :257], want[:, :257])
+    assert (got[:, 257::2] != want[:, 257::2]).all()
+    assert np.abs(got - want).max() == 2.0            # 8 of 10 bits kept
+
+
 SELECT_PREFIX_CASES = [(b, c) for b in LP_BRANCH
                        for c in LP_SELECT_COUNTS[b]]
 PACK_PREFIX_CASES = [(b, c) for b in LP_BRANCH for c in LP_REGION_COUNTS]
+
+
+class TestStageTile:
+    """The MXU "scatter" of all three kernels alone (``_stage_tile``): one
+    bf16 pass stages a block's lane and row digits, and the offset put
+    together from them is the portable one bit for bit."""
+
+    @pytest.mark.parametrize("layout", STAGE_TILE_LAYOUTS)
+    @pytest.mark.parametrize("capb", [CAPB_FAST, BLK])
+    def test_every_offset_exact(self, capb, layout):
+        check_stage_tile(capb, layout, interpret=True)
+
+    def test_whole_offset_in_one_pass_rounds_above_256(self):
+        check_whole_offset_rounds(interpret=True)
 
 
 class TestCompactionParity:

@@ -32,7 +32,8 @@ from oktopk_tpu.ops.select import pack_by_region, select_by_threshold  # noqa: E
 
 from test_compaction import (  # noqa: E402
     LP_REGION_COUNTS, LP_SELECT_COUNTS, REPAIR_SURVIVORS,
-    check_pack_prefix, check_select_prefix, overflow_vector,
+    STAGE_TILE_LAYOUTS, check_pack_prefix, check_select_prefix,
+    check_stage_tile, check_whole_offset_rounds, overflow_vector,
     small_chunk_jits, straddling_bounds)
 from test_fused_select import (  # noqa: E402
     assert_all_equal as fused_assert_all_equal, layouts, region_bounds,
@@ -45,6 +46,23 @@ def tpu_dev():
     if not devs:
         pytest.skip("no TPU device visible")
     return devs[0]
+
+
+@pytest.mark.parametrize("layout", STAGE_TILE_LAYOUTS)
+@pytest.mark.parametrize("capb", [128, 1024])
+def test_stage_tile_every_offset_exact_on_chip(tpu_dev, capb, layout):
+    """Mirror of tests/test_compaction.py::TestStageTile on silicon: the
+    MXU's own bf16 pass stages the lane and row digits of every in-block
+    offset exactly, at both staging widths."""
+    with jax.default_device(tpu_dev):
+        check_stage_tile(capb, layout, interpret=False)
+
+
+def test_whole_offset_in_one_pass_rounds_on_chip(tpu_dev):
+    """The reason for the digits, on silicon: the whole offset as one row
+    of the same pass comes back rounded to bf16 above 256."""
+    with jax.default_device(tpu_dev):
+        check_whole_offset_rounds(interpret=False)
 
 
 def test_select_parity_on_chip(tpu_dev):
