@@ -12,13 +12,15 @@ This is the only module of the benchmark that imports the program.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
 
 from benchlib import check, traffic, weights
+from benchlib import window as window_lib
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 FIRST_STEPS = 3   # the steps the plain reference follows
@@ -39,6 +41,15 @@ class CompileCounter:
             self.count += 1
 
 
+class Cadence(NamedTuple):
+    """A window of whole periods, in steps: it starts on a multiple of
+    ``align``, is cut every ``period`` and holds ``most`` periods at the
+    most (0: as many as the clock lets end)."""
+    align: int
+    period: int
+    most: int
+
+
 @dataclasses.dataclass
 class Window:
     t0: float
@@ -48,6 +59,7 @@ class Window:
     delivered: np.ndarray
     compiles: int
     spans: List[tuple]      # (name, start, end) of the host, traced runs only
+    first_step: int = 0     # steps the job had done before the window's first
 
 
 def _dataclass_kwargs(cls, *layers: Dict[str, Any]) -> Dict[str, Any]:
@@ -95,6 +107,23 @@ class Harness:
         self.snaps: Optional[check.Snapshots] = None
         self.feed: Optional[traffic.Feed] = None
         self.key0 = None
+        self.cadence = self._cadence()
+
+    def _cadence(self) -> Optional[Cadence]:
+        """Where the traffic file asks for a window of whole periods
+        (``window.whole_periods_of``: fields of the trainer's own
+        ``OkTopkConfig``, read from it and written nowhere else): the
+        window starts where all of them fall together (their least common
+        multiple), is cut by the shortest and holds ``window.periods`` of
+        it at the most. None for a traffic file that names none: the
+        clock's window."""
+        spec = self.traffic.get("window", {})
+        names = spec.get("whole_periods_of")
+        if not names:
+            return None
+        every = [int(getattr(self.trainer.algo_cfg, n)) for n in names]
+        return Cadence(math.lcm(*every), min(every),
+                       int(spec.get("periods", 0)))
 
     # ---- state from the seed ------------------------------------------
 
@@ -151,9 +180,13 @@ class Harness:
 
     def settle(self) -> None:
         """Past the first predicted-threshold steps, so that the window is
-        in the regime a job spends its life in."""
+        in the regime a job spends its life in; and, where the window is
+        whole periods, on to the job step where the periods start, so that
+        every run of the cell times the same steps of the job."""
         m = None
         for _ in range(int(self.traffic.get("settle_steps", 4))):
+            m = self.step(next(self.feed))
+        while self.cadence and self.steps_done % self.cadence.align:
             m = self.step(next(self.feed))
         if m is not None:
             jax.block_until_ready(m["loss"])
@@ -163,8 +196,15 @@ class Harness:
     def window(self, seconds: float, max_steps: Optional[int] = None,
                annotate: bool = False) -> Window:
         """Queue one step deep, as ``Trainer.train``: dispatch step i, then
-        wait for step i-1 and stamp its completion."""
+        wait for step i-1 and stamp its completion. The clock ends it at
+        the first completion past ``seconds``. Where the traffic file asks
+        for whole periods it ends with a period: the one the file counts
+        to, or an earlier one where the periods before it say that the
+        next would end past ``seconds`` (never before the first).
+        ``max_steps`` overrides both."""
         step, feed = self.step, self.feed
+        cadence = self.cadence if max_steps is None else None
+        first_step = self.steps_done
         note = (jax.profiler.TraceAnnotation if annotate else None)
         metrics, stamps, spans = [], [], []
         clock = time.perf_counter
@@ -195,7 +235,14 @@ class Harness:
             prev = m
             if max_steps is not None and len(metrics) >= max_steps:
                 break
-            if max_steps is None and stamps and stamps[-1] - t0 >= seconds:
+            if cadence:
+                if stamps and len(metrics) % cadence.period == 0 and (
+                        len(metrics) == cadence.most * cadence.period
+                        or window_lib.last_period(
+                            stamps[-1] - t0, len(stamps), len(metrics),
+                            cadence.period, seconds)):
+                    break
+            elif max_steps is None and stamps and stamps[-1] - t0 >= seconds:
                 break
         jax.block_until_ready(prev["loss"])
         stamps.append(clock())
@@ -205,7 +252,8 @@ class Harness:
                                for m in metrics])
         cols = np.asarray(host, np.float64).reshape(len(metrics), 3)
         return Window(t0, stamps, cols[:, 0], cols[:, 1], cols[:, 2],
-                      self.compiles.count - compiled_before, spans)
+                      self.compiles.count - compiled_before, spans,
+                      first_step)
 
     # ---- one step of the exchange, copied to the host for the check ----
 

@@ -11,8 +11,10 @@ hundreds of them by far less.
 
 from __future__ import annotations
 
+import itertools
 import math
-from typing import List, Sequence, Tuple
+import statistics
+from typing import List, Optional, Sequence, Tuple
 
 
 def step_times(stamps: Sequence[float]) -> List[float]:
@@ -38,6 +40,37 @@ def rate(t0: float, stamps: Sequence[float], per_step: float) -> float:
     if not stamps or stamps[-1] <= t0:
         raise ValueError("empty window")
     return len(stamps) * per_step / (stamps[-1] - t0)
+
+
+def last_period(elapsed: float, stamped: int, dispatched: int, period: int,
+                seconds: float) -> bool:
+    """Asked when ``dispatched`` steps, a whole number of periods, have
+    been sent and ``stamped`` of them have completed in ``elapsed``
+    seconds: would one more period, at the mean pace so far, end past
+    ``seconds``? Then this period is the window's last. The queue is one
+    step deep, so the question is put one completion before the period's
+    end, while its last step can still be the last one sent."""
+    if stamped < 1 or dispatched % period:
+        raise ValueError("asked off a period's end")
+    periods = dispatched // period
+    so_far = elapsed * dispatched / stamped
+    return so_far * (periods + 1) / periods > seconds
+
+
+def line_at(xs: Sequence[float], ys: Sequence[float],
+            x0: float) -> Optional[float]:
+    """``a + b x0`` of the Theil-Sen line through the points: b the median
+    slope over all pairs, a the median of ``y - b x``. A stamp that the
+    host takes late makes one gap long and the next short by as much; a
+    median line does not follow the pair where least squares would. None
+    under four points (a traced window of a step over a second) or where
+    all ``xs`` are one value."""
+    slopes = [(y2 - y1) / (x2 - x1) for (x1, y1), (x2, y2)
+              in itertools.combinations(zip(xs, ys), 2) if x2 != x1]
+    if len(xs) < 4 or not slopes:
+        return None
+    b = statistics.median(slopes)
+    return statistics.median(y - b * x for x, y in zip(xs, ys)) + b * x0
 
 
 def summary(t0: float, stamps: Sequence[float]) -> Tuple[int, float, int]:

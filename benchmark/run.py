@@ -3,11 +3,12 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A new process that sets up (``setup_s``: from process start to the first
-timed step), measures for ``--seconds``, decides ``correct`` against the
-plain reference, prints each number compared beside its limit and, as its
-last line, one JSON object. ``--trace 0`` reports the cell's end-to-end
-metrics with the profiler off; ``--trace 1`` reports its per-layer metrics
-from a short ``jax.profiler`` window round the same loop.
+timed step), measures for ``--seconds`` (or, where the cell's traffic file
+asks for them, for whole controller periods inside it), decides ``correct``
+against the plain reference, prints each number compared beside its limit
+and, as its last line, one JSON object. ``--trace 0`` reports the cell's
+end-to-end metrics with the profiler off; ``--trace 1`` reports its
+per-layer metrics from a short ``jax.profiler`` window round the same loop.
 
 It refuses to run (exit 2, no result line) without a TPU or with fewer
 chips than the cell asks for. ``--rehearse`` is for the sandbox: four
@@ -192,15 +193,17 @@ def main(argv=None) -> int:
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     steps, span, readings = window_lib.summary(win.t0, win.stamps)
-    print(f"{tag}window: {steps} steps in {span:.3f} s, {readings} step-time "
-          f"readings, one a step", flush=True)
+    print(f"{tag}window: {steps} steps in {span:.3f} s from job step "
+          f"{win.first_step}, {readings} step-time readings, one a step",
+          flush=True)
     # every stamp of the window, for whoever wants another statistic of it
     stamps_dir = os.path.join(bench.root, ".bench_out", "stamps")
     os.makedirs(stamps_dir, exist_ok=True)
     with open(os.path.join(stamps_dir, f"{cell['name']}.{args.seed}."
                            f"trace{args.trace}.json"), "w") as f:
         json.dump({"t0": win.t0, "stamps": win.stamps,
-                   "delivered": win.delivered.tolist()}, f)
+                   "delivered": win.delivered.tolist(),
+                   "first_step": win.first_step}, f)
 
     if args.rehearse:
         # a sandbox number is never written under a device metric's name

@@ -35,9 +35,13 @@ def interpreted_kernels(monkeypatch):
 @pytest.fixture(autouse=True)
 def short_settling(monkeypatch):
     real = discover.Bench.traffic
-    monkeypatch.setattr(
-        discover.Bench, "traffic",
-        lambda self, name: dict(real(self, name), settle_steps=SETTLE))
+
+    def short(self, name):
+        # nor does it go on to where a window of whole periods starts
+        spec = dict(real(self, name), settle_steps=SETTLE)
+        spec.pop("window", None)
+        return spec
+    monkeypatch.setattr(discover.Bench, "traffic", short)
 
 
 def settled(cell, seed, over=None):

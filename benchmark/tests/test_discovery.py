@@ -33,7 +33,7 @@ def test_scratch_cell_and_metric_by_files_alone(checkout):
     home = checkout / "benchmark"
     (home / "traffic" / "scratch_b4.json").write_text(json.dumps(
         {"train": {"compressor": "oktopk", "num_buckets": 4},
-         "algo": {"warmup_steps": 3, "threshold_method": "hist"}}))
+         "algo": {"warmup_steps": 3, "threshold_method": "sort"}}))
     (home / "cells" / "scratch_cell.json").write_text(json.dumps(
         {"limits": {"loss_gap": 1e-3}}))
     (home / "metrics" / "scratch_metric.py").write_text(
@@ -83,7 +83,7 @@ def test_keys_of_a_traffic_file_reach_the_program_unchanged(checkout):
     assert cfg.num_buckets == 4 and cfg.autotune_candidates == (
         "dense", "oktopk")
     assert OkTopkConfig(**_dataclass_kwargs(
-        OkTopkConfig, {"threshold_method": "hist"})).threshold_method == "hist"
+        OkTopkConfig, {"threshold_method": "sort"})).threshold_method == "sort"
     with pytest.raises(KeyError):
         _dataclass_kwargs(TrainConfig, {"num_bukets": 4})
 

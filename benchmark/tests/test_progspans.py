@@ -188,12 +188,29 @@ def test_a_program_without_spans_reads_as_nothing():
     assert reader("select_stage_ms")(ctx) == pytest.approx(100.0)
 
 
+def test_unscoped_counts_the_two_phases_only():
+    """The model's sub-scopes under ``fwd_bwd`` (the program names them
+    since PR 28) are not selection's unscoped time."""
+    ctx, _ = context()
+    ctx.sub_scope_ms = {"fwd_bwd_unscoped": 77.5, "fwd_bwd_attention": 3.0,
+                        "select_sweep": 30.0, "select_threshold": 0.25,
+                        "stage_unscoped": 4.75, "kernel:oktopk_repair": 15.0}
+    assert reader("select_stage_unscoped_ms")(ctx) == pytest.approx(5.0)
+
+
 def test_new_entries_are_appended_and_listed():
+    """The eleven are in the list, each for named cells, and after the
+    entries the list had before them; later PRs appended theirs behind."""
     per_layer = discover.Bench().spec["per_layer"]
     names = [m["name"] for m in per_layer]
-    assert set(names[-len(NEW):]) == NEW
-    for m in per_layer[-len(NEW):]:
-        assert m["workloads"], m["name"]
+    assert NEW <= set(names)
+    first = min(names.index(n) for n in NEW)
+    assert first > 0 and not NEW & set(names[:first])
+    assert {names.index(n) for n in NEW} == set(
+        range(first, first + len(NEW)))
+    for m in per_layer:
+        if m["name"] in NEW:
+            assert m["workloads"], m["name"]
 
 
 @pytest.mark.parametrize("cell", ["lstm_ptb_dense_x1", "lstm_ptb_oktopk_x1",

@@ -1,7 +1,10 @@
 """Spreads of a cell's two sets of runs, as the builder's contract reads
 them: for each metric the distance between the first and third quartile
 (``statistics.quantiles(values, n=4)``) over the median, a set; the wider of
-the two; and how far the second set's median lies from the first's.
+the two; how far the second set's median lies from the first's; and the
+driver's rule for tightness (``tight``): in a set, the run farthest from the
+set's median left out, the largest less the smallest of the rest over the
+median, which has to stay under half the metric's bound.
 
     python benchmark/tools/spread.py SET1.jsonl SET2.jsonl
 
@@ -27,6 +30,12 @@ def spread(values):
     return (q3 - q1) / statistics.median(values)
 
 
+def tight(values):
+    med = statistics.median(values)
+    rest = sorted(values, key=lambda v: abs(v - med))[:-1]
+    return (max(rest) - min(rest)) / med
+
+
 def main(a, b):
     sa, ra = load(a)
     sb, rb = load(b)
@@ -40,7 +49,8 @@ def main(a, b):
         ma, mb = statistics.median(va), statistics.median(vb)
         print(f"{name:16s} median {ma:.6g} / {mb:.6g}  second-vs-first "
               f"{(mb - ma) / ma:+.4%}  spread {spread(va):.4%} / "
-              f"{spread(vb):.4%}  -> five times the wider "
+              f"{spread(vb):.4%}  tight {tight(va):.4%} / {tight(vb):.4%}"
+              f"  -> five times the wider "
               f"{5 * max(spread(va), spread(vb)):.4%}  values {[round(v, 4) for v in sa[name]]} {[round(v, 4) for v in sb[name]]}")
 
 
