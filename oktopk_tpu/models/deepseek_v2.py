@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -161,7 +161,7 @@ def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
 # ---- routed experts ---------------------------------------------------------
 
 def _grouped_branch(rows: int, x, weights, routed, counts,
-                    w_gate, w_up, w_down):
+                    w_gate, w_up, w_down, act=jax.nn.silu):
     """The token-expert pairs of the held experts, sorted by expert into
     one buffer of ``rows`` rows (enough for all of them: the caller
     checks), through grouped products (``lax.ragged_dot``: expert h's
@@ -185,12 +185,13 @@ def _grouped_branch(rows: int, x, weights, routed, counts,
     xg = keep(x[token])
     g = lax.ragged_dot(xg, w_gate, groups)
     u = lax.ragged_dot(xg, w_up, groups)
-    y = lax.ragged_dot(jax.nn.silu(g) * u, w_down, groups)
+    y = lax.ragged_dot(act(g) * u, w_down, groups)
     w = keep(weights.T.reshape(-1)[pair][:, None])
     return jnp.zeros_like(x).at[token].add(y * w.astype(y.dtype))
 
 
-def _all_rows_branch(x, weights, routed, counts, w_gate, w_up, w_down):
+def _all_rows_branch(x, weights, routed, counts, w_gate, w_up, w_down,
+                     act=jax.nn.silu):
     """More pairs than the buffer holds: each held expert over all rows,
     masked by the routing, one expert at a time."""
     del counts
@@ -198,7 +199,7 @@ def _all_rows_branch(x, weights, routed, counts, w_gate, w_up, w_down):
     @jax.checkpoint
     def one(out, operand):
         wg, wu, wd, w = operand
-        y = swiglu(x, wg, wu, wd)
+        y = swiglu(x, wg, wu, wd, act)
         return out + y * w[:, None].astype(y.dtype), None
 
     w = jnp.where(routed, weights, 0.0).T
@@ -224,21 +225,30 @@ def expert_capacity(tokens: int, held: int, k: int, experts: int) -> int:
 
 
 def routed_experts(x, weights, routed, w_gate, w_up, w_down,
-                   capacity: int, k: int):
-    """``sum_h weights[:, h] * E_h(x)`` over the held experts h, for the
-    tokens ``routed`` [T, H] gives each (``k`` experts a token, held or
-    not). Returns it and the rows each held expert computed, i32[H]. The
-    pairs go through the grouped branch where its ``capacity`` rows hold
-    them all, else every expert runs over all rows, masked. Each branch is
-    recomputed in the backward pass, so that neither's intermediates are
-    kept (a ``cond`` keeps those of both)."""
+                   capacity: int, k: int, act=jax.nn.silu):
+    """``sum_h weights[:, h] * E_h(x)`` over the held experts h (``E_h(x) =
+    (act(x W_gate) * x W_up) W_down``), for the tokens ``routed`` [T, H]
+    gives each (``k`` experts a token, held or not). Returns it and the
+    rows each held expert computed, i32[H]. The pairs go through the
+    grouped branch where its ``capacity`` rows hold them all, else every
+    expert runs over all rows, masked. Each branch is recomputed in the
+    backward pass, so that neither's intermediates are kept (a ``cond``
+    keeps those of both)."""
     counts = jnp.sum(routed, axis=0, dtype=jnp.int32)
     operands = (x, weights, routed, counts, w_gate, w_up, w_down)
-    grouped = jax.checkpoint(partial(_grouped_branch, capacity))
+    grouped = jax.checkpoint(partial(_grouped_branch, capacity, act=act))
     if capacity >= x.shape[0] * min(k, routed.shape[1]):    # holds any step
         return grouped(*operands), counts
     return lax.cond(jnp.sum(counts) <= capacity, grouped,
-                    jax.checkpoint(_all_rows_branch), *operands), counts
+                    jax.checkpoint(_all_rows(act)), *operands), counts
+
+
+@lru_cache(maxsize=None)
+def _all_rows(act):
+    """``_all_rows_branch`` under ``act``: one function an activation, so
+    that every layer's ``cond`` traces the same branch (jax shares a trace
+    by the function's identity, and the step program one body)."""
+    return partial(_all_rows_branch, act=act)
 
 
 # ---- modules ---------------------------------------------------------------
@@ -267,8 +277,12 @@ class Kernel(nn.Module):
                           (fan_in, self.features))
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+# a gated expert's activation, by the published ``hidden_act``
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def swiglu(x, w_gate, w_up, w_down, act=jax.nn.silu):
+    return (act(x @ w_gate) * (x @ w_up)) @ w_down
 
 
 class SwiGLU(nn.Module):
@@ -359,9 +373,14 @@ class MoE(nn.Module):
     dtype: Any = jnp.float32
     # the shared experts' output times sigmoid(x w_g), a scalar a token
     shared_gate: bool = False
+    # the routed experts' gate activation, a key of ACTIVATIONS
+    hidden_act: str = "silu"
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, router_input=None):
+        """``router_input`` (None: ``h``): what the router scores, where
+        that is not what the experts read (``models/smallthinker.py``
+        routes from the layer's normalised input, before attention)."""
         shape = h.shape
         x = h.reshape(-1, shape[-1])
         tokens, d = x.shape
@@ -369,8 +388,9 @@ class MoE(nn.Module):
         with phase_scope("fwd_bwd", sub="router"):
             w_r = self.param("kernel", nn.initializers.lecun_normal(),
                              (d, self.n_routed_experts))
+            r = x if router_input is None else router_input.reshape(-1, d)
             scores = jax.nn.softmax(
-                jnp.dot(x.astype(jnp.float32), w_r, precision=HIGHEST),
+                jnp.dot(r.astype(jnp.float32), w_r, precision=HIGHEST),
                 axis=-1)
             top_w, top_i = lax.top_k(scores, k)
             if self.norm_topk_prob:
@@ -390,7 +410,8 @@ class MoE(nn.Module):
                 x.astype(self.dtype), weights, routed,
                 w_gate.astype(self.dtype), w_up.astype(self.dtype),
                 w_down.astype(self.dtype),
-                expert_capacity(tokens, held, k, self.n_routed_experts), k)
+                expert_capacity(tokens, held, k, self.n_routed_experts), k,
+                ACTIVATIONS[self.hidden_act])
         if self.n_shared_experts:
             with phase_scope("fwd_bwd", sub="shared"):
                 shared = SwiGLU(f * self.n_shared_experts, self.dtype,

@@ -122,29 +122,42 @@ def rotate_half_partial(x, cos, sin):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
-def _attend_block_gqa(q, k, v, start, end, scale):
-    """One sequence's queries ``start .. end`` against its keys ``0 ..
+def _attend_block_gqa(q, k, v, start, end, scale, first=0, window=None):
+    """One sequence's queries ``start .. end`` against its keys ``first ..
     end``. q [block, G, R, d] (G key-value heads, R query heads each); k, v
-    [T, G, d], cut here (a caller who recomputes this keeps no cut copy)."""
-    k, v = k[:end], v[:end]
+    [T, G, d], cut here (a caller who recomputes this keeps no cut copy).
+    ``window``: query i reads the keys j with ``i - j < window`` only."""
+    k, v = k[first:end], v[first:end]
     s = jnp.einsum("qgrd,kgd->grqk", q, k).astype(jnp.float32) * scale
     rows = start + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
     cols = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-    s = jnp.where(cols <= rows, s, -jnp.inf)
+    if first:   # no add of a zero: without a window nothing is lowered for it
+        cols = cols + first
+    seen = cols <= rows
+    if window is not None:
+        seen = seen & (rows - cols < window)
+    s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("grqk,kgd->qgrd", p, v)
 
 
-def blocked_causal_gqa(q, k, v, scale: float, block: int):
+def blocked_causal_gqa(q, k, v, scale: float, block: int,
+                       window: Optional[int] = None):
     """Causal attention with grouped heads: q [B, T, H, d], k and v [B, T,
     G, d] (query head h reads key-value head ``h // (H / G)``) -> [B, T, H,
     d]. A sequence at a time and ``block`` queries at a time, each block's
     scores recomputed in the backward pass and its output named
     ``ATTN_OUT``, as ``deepseek_v2.blocked_causal_attention`` does for
-    MLA's split heads."""
+    MLA's split heads. ``window`` (None: all keys at or before the query):
+    query i reads keys ``i - window < j <= i``, and a block of queries
+    reads, scores and masks only the keys ``[max(0, start - window + 1),
+    end)`` that any of them can see, so a windowed layer's work follows the
+    band and not the causal triangle."""
     b, t, h, d = q.shape
     g = k.shape[2]
     block = min(block, t)
+    if window is not None and window >= t:
+        window = None   # every key at or before a query is in its window
 
     def one_sequence(seq):
         qq, kk, vv = seq
@@ -152,8 +165,10 @@ def blocked_causal_gqa(q, k, v, scale: float, block: int):
         outs = []
         for start in range(0, t, block):
             end = min(start + block, t)
-            fn = jax.checkpoint(partial(_attend_block_gqa, start=start,
-                                        end=end, scale=scale))
+            first = 0 if window is None else max(0, start - window + 1)
+            fn = jax.checkpoint(partial(
+                _attend_block_gqa, start=start, end=end, scale=scale,
+                first=first, window=window))
             outs.append(checkpoint_name(fn(qq[start:end], kk, vv), ATTN_OUT))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
         return out.reshape(t, h, d)

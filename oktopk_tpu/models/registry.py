@@ -14,6 +14,7 @@ from oktopk_tpu.models.densenet import DenseNet
 from oktopk_tpu.models.preresnet import PreResNet
 from oktopk_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from oktopk_tpu.models.resnext import ResNeXt
+from oktopk_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from oktopk_tpu.models.bert import BertConfig, BertForPreTraining
 from oktopk_tpu.models.deepseek_v2 import DeepseekV2, DeepseekV2Config
 from oktopk_tpu.models.deepspeech import DeepSpeech
@@ -43,6 +44,8 @@ TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "deepseek_v2_tiny": (64, 512),
     "qwen3_next_80b_a3b": (8192, 151936),
     "qwen3_next_tiny": (64, 512),
+    "smallthinker_21b_a3b": (16384, 151936),
+    "smallthinker_tiny": (64, 512),
 }
 
 
@@ -88,6 +91,13 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
     "qwen3_next_tiny": lambda **kw: (
         Qwen3Next(Qwen3NextConfig.tiny(**kw)),
         _tokens(*TOKEN_LMS["qwen3_next_tiny"])),
+    # SmallThinker-21BA3B-Instruct at its published config.json; a chip's
+    # share comes as model_kwargs, as for deepseek_v2_lite.
+    "smallthinker_21b_a3b": lambda **kw: (
+        SmallThinker(SmallThinkerConfig(**kw)), _tokens(64, 151936)),
+    "smallthinker_tiny": lambda **kw: (
+        SmallThinker(SmallThinkerConfig.tiny(**kw)),
+        _tokens(*TOKEN_LMS["smallthinker_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

@@ -68,16 +68,20 @@ SUB_FINALIZE = "finalize"        # stage: census, prefix, branch, gathers
 SUB_GLOBAL = "global"            # select: phase-(b) winner selection
 SUB_FEEDBACK = "feedback"        # select: controller feedback
 # ... and inside ``fwd_bwd``, entered by the model itself
-# (models/deepseek_v2.py, models/qwen3_next.py), so forward, recomputed and
-# backward operations alike carry them: a model that enters none leaves the
-# phase unscoped. ``delta_rule`` (the chunked recurrence alone) lies inside
-# ``linear_attention`` (its projections, convolution, gates and norm): a
-# reader takes the innermost.
+# (models/deepseek_v2.py, models/qwen3_next.py, models/smallthinker.py), so
+# forward, recomputed and backward operations alike carry them: a model that
+# enters none leaves the phase unscoped. ``delta_rule`` (the chunked
+# recurrence alone) lies inside ``linear_attention`` (its projections,
+# convolution, gates and norm), and ``window_scores`` (a windowed layer's
+# blocked scores, softmax and weighted sum alone) inside ``window_attention``
+# (its projections, rotary and output projection): a reader takes the
+# innermost.
 SUB_SCOPES = {
     "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
     "stage": (SUB_REPARTITION, SUB_FINALIZE),
     "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head",
-                "linear_attention", "delta_rule"),
+                "linear_attention", "delta_rule", "window_attention",
+                "window_scores"),
 }
 
 # phases whose time is wire time; everything else in the contract is

@@ -511,3 +511,43 @@ class TestBenchmarkReaders:
         scores = 2 * 16 * (4096 * 4097 // 2) * 320
         assert a == 5 * 4 * (4096 * proj + scores) * 3
         assert 11e12 < a < 12e12      # 61 ms at the chip's matrix peak
+
+
+class TestNewArgumentsAtTheirDefaults:
+    """``MoE`` gained ``router_input`` and ``hidden_act`` for
+    ``models/smallthinker.py``: at their defaults the model is the parent
+    commit's, bit for bit."""
+
+    def test_loss_and_gradients_hash_to_the_parents(self, tiny):
+        """``deepseek_v2_tiny``'s loss, counters and every gradient leaf on
+        seeded weights hash to what the parent commit's code gave
+        (recorded from a checkout of commit d8fca06 by these same
+        lines)."""
+        import hashlib
+        model, params, batch, _ = tiny
+        (loss, rows), grads = program_loss(model, batch)(params)
+        digest = hashlib.sha256()
+        for x in [loss, rows] + jax.tree.leaves(grads):
+            digest.update(np.asarray(x).tobytes())
+        assert digest.hexdigest() == AT_PARENT
+
+    def test_the_defaults_spelled_out_change_no_bit(self):
+        cfg = ds.DeepseekV2Config.tiny()
+        args = (cfg.n_routed_experts, (1, 2, 5), cfg.num_experts_per_tok,
+                cfg.moe_intermediate_size, 2, 1.0, False)
+        h = jax.random.normal(jax.random.PRNGKey(3), (2, 48, cfg.hidden_size))
+        plain = ds.MoE(*args)
+        params = plain.init(jax.random.PRNGKey(1), h)
+        y, rows = plain.apply(params, h)
+        spelled = ds.MoE(*args, hidden_act="silu")
+        y2, rows2 = spelled.apply(params, h, router_input=h)
+        assert np.array_equal(y, y2) and np.array_equal(rows, rows2)
+        # ... and each of them is read: another gate, another input of the
+        # router
+        relu, _ = ds.MoE(*args, hidden_act="relu").apply(params, h)
+        _, moved = plain.apply(params, h, router_input=2.0 * h - 1.0)
+        assert not np.array_equal(y, relu) and not np.array_equal(rows, moved)
+
+
+AT_PARENT = (
+    "acc2ecf97eb274dabae8bbcd425e53ac751132a6385341bdd2efcb85192da3ce")

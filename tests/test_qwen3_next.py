@@ -540,3 +540,37 @@ def deepseek_digest():
 
 DEEPSEEK_AT_PARENT = (
     "acc2ecf97eb274dabae8bbcd425e53ac751132a6385341bdd2efcb85192da3ce")
+
+
+class TestWindowArgumentAtItsDefault:
+    def test_loss_and_gradients_hash_to_the_parents(self, tiny):
+        """``blocked_causal_gqa`` gained ``window`` and ``MoE`` three
+        fields for ``models/smallthinker.py``: at their defaults
+        ``qwen3_next_tiny``'s loss, counters and every gradient leaf on
+        seeded weights hash to what the parent commit's code gave
+        (recorded from a checkout of commit d8fca06 by these same
+        lines)."""
+        import hashlib
+        model, params, batch, _ = tiny
+        (loss, rows), grads = program_loss(model, batch)(params)
+        digest = hashlib.sha256()
+        for x in [loss, rows] + jax.tree.leaves(grads):
+            digest.update(np.asarray(x).tobytes())
+        assert digest.hexdigest() == QWEN3_NEXT_AT_PARENT
+        # a window that holds the whole sequence is no window: the same
+        # program, so the same bits
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(kq, (2, 64, 4, 32))
+        k = jax.random.normal(kk, (2, 64, 2, 32))
+        v = jax.random.normal(kv, (2, 64, 2, 32))
+        plain = jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+            q, k, v, 0.2, 16))()
+        for window in (None, 64, 1000):
+            assert str(jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+                q, k, v, 0.2, 16, window))()) == str(plain)
+        assert str(jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+            q, k, v, 0.2, 16, 63))()) != str(plain)
+
+
+QWEN3_NEXT_AT_PARENT = (
+    "bb4965c46b792d66869f9b6eaae3d07984092b6e4392f43e53a1bf510443bb00")
