@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from functools import partial
 
-import jax.numpy as jnp
+import jax
 from jax import lax
 
 from oktopk_tpu.collectives.state import SparseState, bump
@@ -19,15 +19,28 @@ from oktopk_tpu.config import OkTopkConfig
 from oktopk_tpu.obs.anatomy import phase_scope
 
 
-def dense_allreduce(grad: jnp.ndarray, state: SparseState, cfg: OkTopkConfig,
+def dense_allreduce(grad, state: SparseState, cfg: OkTopkConfig,
                     axis_name: str = "data"):
-    """psum-mean over the data axis (ring allreduce moves ~2n per worker)."""
+    """psum-mean over the data axis (ring allreduce moves ~2n per worker).
+
+    ``grad`` is the bucket's flat vector or the bucket a leaf at a time: a
+    tuple of its gradient leaves, each in its own shape, ``cfg.n`` elements
+    in all (optim/distributed.py hands a dense bucket over so where nothing
+    reads the flat vector). The mean is element-wise, so the mean of a
+    concatenation is the concatenation of the means, and either form is ONE
+    ``pmean`` under one ``exchange`` scope. Over a tuple JAX binds a
+    ``psum`` a leaf and lowers an ``all_reduce`` a leaf; what goes on the
+    wire together is XLA's all-reduce combiner's choice in both forms
+    (XLA:CPU merges all of a step's buckets into one all-reduce, flat or
+    not; over a mesh axis of size one nothing is emitted at all). The
+    accounting is the same line for both."""
     with phase_scope("exchange", cfg.bucket_index):
         out = lax.pmean(grad, axis_name)
     out, state = pvary_like(
         (out, bump(state, volume=2.0 * cfg.n,
                    wire_bytes=dense_wire_bytes(2.0 * cfg.n),
-                   local_count=cfg.n, global_count=cfg.n)), grad)
+                   local_count=cfg.n, global_count=cfg.n)),
+        jax.tree.leaves(grad)[0])
     return out, state
 
 

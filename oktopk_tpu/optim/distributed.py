@@ -5,12 +5,17 @@ This replaces the reference's entire L3/L4 concurrency machinery
 (VGG/distributed_optimizer.py:63-94), the background allreducer thread and
 its two-queue handshake (VGG/allreducer.py:549, :1640-1643), and the
 ``synchronize()`` join (:96-105). Under XLA all of that is one traced
-program: backward, reverse-layer-order bucket flatten (the analogue of the
+program: backward, reverse-layer-order buckets (the analogue of the
 reference's bucket merge, VGG/allreducer.py:272-330; with ``num_buckets=1``
 the whole model is one bucket like the BERT variant's "myallreduce" flat
-tensor, BERT/bert/allreducer.py:200), one sparse collective per bucket,
-unflatten, optimizer update. Compute/communication overlap is XLA's async collective
-scheduling instead of Python threads.
+tensor, BERT/bert/allreducer.py:200), one collective per bucket, optimizer
+update. A sparse collective selects over one address space: its bucket is
+flattened (the leaves concatenated into one vector) and the reduced vector
+is cut up into the leaves again. The dense all-reduce is element-wise and
+needs no such vector: its bucket is reduced a leaf at a time, the leaves
+the operands of one ``pmean``, unless an option of the step reads the
+vector (``step.leafwise`` says which buckets were). Compute/communication
+overlap is XLA's async collective scheduling instead of Python threads.
 
 Local gradient accumulation (``nsteps_update``, reference
 VGG/main_trainer.py:82-100) is a ``lax.scan`` over microbatches before the
@@ -27,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from oktopk_tpu.collectives.dense import dense_allreduce
 from oktopk_tpu.collectives.registry import get_algorithm
 from oktopk_tpu.collectives.state import (
     BRANCH_COUNTERS,
@@ -257,7 +263,11 @@ def build_sparse_grad_step(
 
     Returns ``step(state: DistTrainState, batch, rng) -> (state, metrics)``.
     ``batch`` leaves are [num_workers * nsteps_update * mb, ...] and get
-    sharded over the data axis.
+    sharded over the data axis. ``step.leafwise`` holds a bool a bucket:
+    True where the bucket's collective is the dense all-reduce itself and
+    neither ``fault_plan``, ``momentum_correction``, ``quality``, ``guard``
+    nor ``profile_norm`` reads its flat vector, so that none is built and
+    the bucket is reduced a leaf at a time.
     """
     from oktopk_tpu.ops.compaction import resolve_use_pallas
     cfg = resolve_use_pallas(cfg, mesh)
@@ -279,6 +289,15 @@ def build_sparse_grad_step(
     has_quality = quality is not None
     if has_quality:
         from oktopk_tpu.obs import quality as _quality_mod
+    # the dense all-reduce is element-wise: the mean of a concatenation is
+    # the concatenation of the means. Where it is the bucket's collective
+    # (the registry hands it out unwrapped; a warm-up's lax.cond needs one
+    # shape for both branches) and nothing below reads the flat vector,
+    # the bucket is handed over as its leaves and no vector is built
+    reads_flat = bool(profile_norm or momentum_correction or has_health
+                      or has_quality)
+    leafwise = tuple(fn is dense_allreduce and not reads_flat
+                     for fn in algos)
 
     def shard_fn(state: DistTrainState, batch, rng):
         if has_health and state.health is None:
@@ -339,6 +358,7 @@ def build_sparse_grad_step(
         # layout stays a bare SparseState in that case for checkpoint
         # compatibility. ---
         buckets = bucket_partition(grads, num_buckets)  # static sizes
+        sizes = bucket_sizes(grads, buckets)
         leaves, treedef = jax.tree.flatten(grads)
         assert sum(x.size for x in leaves) == cfg.n, (
             f"cfg.n={cfg.n} != flat grad size "
@@ -359,14 +379,17 @@ def build_sparse_grad_step(
         for bi, idxs in enumerate(buckets):
             # copy-free single-leaf bucket: reshape is a view under XLA,
             # while a 1-element concatenate still materialises a second
-            # n-length buffer (and the matching slice-back below a third)
-            if len(idxs) == 1:
+            # n-length buffer (and the matching slice-back below a third);
+            # a leaf-wise bucket is the tuple of its leaves as they are
+            if leafwise[bi]:
+                flat = tuple(leaves[i] for i in idxs)
+            elif len(idxs) == 1:
                 flat = leaves[idxs[0]].reshape(-1)
             else:
                 flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
             over = {}
             if not single:
-                over["n"] = int(flat.size)
+                over["n"] = sizes[bi]
                 over["bucket_index"] = bi
             if bucket_densities is not None:
                 over["density"] = float(bucket_densities[bi])
@@ -407,7 +430,10 @@ def build_sparse_grad_step(
                 # density-backoff policy watches (how close delivered
                 # gradients crowd cfg.abs_limit without tripping it)
                 absmaxes.append(jnp.max(jnp.abs(reduced)))
-            if len(idxs) == 1:
+            if leafwise[bi]:
+                for i, r in zip(idxs, reduced):
+                    results[i] = r
+            elif len(idxs) == 1:
                 results[idxs[0]] = reduced.reshape(leaves[idxs[0]].shape)
             else:
                 off = 0
@@ -552,4 +578,6 @@ def build_sparse_grad_step(
         in_specs=(state_specs, P(axis_name), P()),
         out_specs=(state_specs, P()),
         check_vma=False)
-    return jax.jit(mapped, donate_argnums=(0,))
+    step = jax.jit(mapped, donate_argnums=(0,))
+    step.leafwise = leafwise
+    return step

@@ -878,9 +878,14 @@ class Trainer:
         bucket each: ``local_k / cap_pair`` and ``global_k / cap_gather``
         (``collectives/state.COUNTERS``, summed over the buckets) are the
         live shares that the materialise's cost follows
-        (``ops/compaction._gather_live``)."""
-        return [{"cap_pair": c.cap_pair, "cap_gather": c.cap_gather}
-                for _, c, _ in self._bucket_cfgs()]
+        (``ops/compaction._gather_live``). Beside them ``leafwise``,
+        another static fact of the built step: whether the bucket is
+        reduced a leaf at a time, with no flat vector built or cut up
+        (``optim/distributed.build_sparse_grad_step``)."""
+        return [{"cap_pair": c.cap_pair, "cap_gather": c.cap_gather,
+                 "leafwise": lw}
+                for (_, c, _), lw in zip(self._bucket_cfgs(),
+                                         self.step_fn.leafwise)]
 
     def _emit_volume_report(self):
         """One ``volume_report`` event per bucket: mean realised wire

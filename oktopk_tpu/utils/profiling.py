@@ -346,9 +346,9 @@ SETUP = PhaseTimers(every=0)
 def register(source) -> None:
     """Weakly register an object whose ``step_counters()`` returns
     ``[(step_num, counters array), ...]`` and whose ``capacities()``
-    returns ``[{"cap_pair": int, "cap_gather": int}, ...]``, a bucket each
-    (a ``Trainer`` does at construction), so that :func:`snapshot` finds
-    it."""
+    returns ``[{"cap_pair": int, "cap_gather": int, "leafwise": bool},
+    ...]``, a bucket each (a ``Trainer`` does at construction), so that
+    :func:`snapshot` finds it."""
     _sources[:] = [r for r in _sources if r() is not None]
     _sources.append(weakref.ref(source))
 
@@ -376,7 +376,10 @@ def snapshot() -> Dict[str, Any]:
       and ``cap_gather`` a bucket, in the order of the buckets: a step's
       ``local_k`` and ``global_k`` over their sums are the live shares of
       the two buffers, which the materialise's cost follows
-      (ops/compaction.py ``_gather_live``);
+      (ops/compaction.py ``_gather_live``); and ``leafwise`` beside them,
+      whether the built step reduces the bucket a leaf at a time (a dense
+      bucket whose flat vector nothing reads: none is built or cut up
+      again, optim/distributed.py) or flattens it (every other bucket);
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
     """

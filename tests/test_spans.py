@@ -333,7 +333,8 @@ class TestSnapshot:
         for caps, n_b in zip(mine, sizes):
             want = OkTopkConfig(n=n_b, num_workers=4, density=0.05)
             assert caps == {"cap_pair": want.cap_pair,
-                            "cap_gather": want.cap_gather}
+                            "cap_gather": want.cap_gather,
+                            "leafwise": False}
             k = int(0.05 * n_b)
             assert caps["cap_pair"] == int(2.0 * k / 4) + 8
             assert caps["cap_gather"] == int(2.5 * k / 4) + 8
