@@ -1,38 +1,48 @@
-"""Step-anatomy plane: phase annotation contract + device-trace attribution.
+"""Step-anatomy plane: the phase naming contract, whose each instruction of
+the compiled step is, and the attribution of a trace's time to its phases.
 
 The jitted train step is one opaque XLA program; the reference's
 per-phase timers (VGG/allreducer.py:256-262) have no analogue inside
-it. This module gives the step a time-domain anatomy in three pieces:
+it. This module gives the step a time-domain anatomy in four pieces:
 
 1. **Naming contract** — ``scope_name(phase, bucket)`` produces names
    like ``anat/b003/exchange``. ``phase_scope(...)`` wraps pipeline
    regions in ``jax.named_scope`` so the names reach compiled-HLO op
-   metadata (``op_name="jit(step)/.../anat/b000/select/..."``) and
-   therefore the device lanes of a ``jax.profiler`` capture on
-   backends that attribute per-op device time (TPU). The scopes are
-   pure metadata: computation is bit-identical annotations-on vs
-   annotations-off and no host callback is ever introduced
-   (tests/test_anatomy.py pins both). ``trace_annotation(...)`` is the
-   host-side twin (``jax.profiler.TraceAnnotation``) used by capture
-   drivers on backends whose traces carry no per-op device lanes
-   (CPU: only host threads appear, so the driver dispatches per-phase
-   subprograms under annotations instead).
+   metadata (``op_name="jit(step)/.../anat/b000/select/..."``). The
+   scopes are pure metadata: computation is bit-identical
+   annotations-on vs annotations-off and no host callback is ever
+   introduced (tests/test_anatomy.py pins both).
 
-2. **Trace analyzer** — parses captured profiler output (the perfetto
-   trace-event JSON ``jax.profiler.start_trace(...,
-   create_perfetto_trace=True)`` writes, or any Chrome trace-event
-   file, plus checked-in synthetic fixtures in CI) into per-(bucket,
-   phase) durations, classifies events into
-   compute vs collective lanes, computes the compute/comm overlap
-   ratio and a time-sweep critical-path attribution of the measured
-   span.
+2. **The owners' map** — ``owners(hlo_text)``: for every instruction of
+   the compiled step's text an ``Owner`` (phase, sub-scope, bucket, the
+   rule that answered, the source frame). A device event of a TPU trace
+   is named by its instruction and carries NO scope; the scope is in the
+   instruction's ``op_name``. What the compiler made or renamed
+   (asynchronous copies and slices, layout changes, its own kernels for
+   ``lax.ragged_dot``) has no ``op_name`` of the program's and is given
+   the owner of its surroundings: its ``*-start``, its nearest operand,
+   its nearest user, the loop or branch it lies in. A pure text pass.
 
-3. **Journal events** — ``step_anatomy`` (one per bucket; model-level
+3. **Trace analyzer** — two front ends over one core (interval unions,
+   compute and collective lanes, the overlap scorecard, a sweep for the
+   critical path). ``analyze_events`` takes Chrome trace-event JSON whose
+   events are NAMED by their scope (host annotations, checked-in
+   fixtures). ``analyze_device`` takes the instruction-named events of a
+   chip's line of operations and the owners' map, gives every instant of
+   the busy time to the innermost event that covers it, and returns,
+   beside the scorecard, the table that closes on the busy time: for
+   every (phase, sub-scope) the time of its own instructions, of those
+   that inherit from it and of its loops and branches themselves, and the
+   time nobody owns, with the largest instructions of each.
+   ``analyze_xplane`` reads them from a ``jax.profiler`` capture.
+
+4. **Journal events** — ``step_anatomy`` (one per bucket; model-level
    unbucketed phases land on bucket -1) and one ``overlap_report``
    carrying the scorecard: measured span vs the ideal fully-overlapped
-   lower bound ``max(compute_ms, comm_ms)``. Malformed or empty traces
-   journal one ``anatomy_warning`` — analysis never raises
-   (observability must never take down the thing it observes).
+   lower bound ``max(compute_ms, comm_ms)``; ``source`` says which
+   front end read it (``"device"``: the anomaly tracer's capture,
+   obs/tracing.py). Malformed or empty traces journal one
+   ``anatomy_warning``.
 
 Scorecard semantics (docs/OBSERVABILITY.md "Step anatomy"):
 ``overlap_ratio = overlap_ms / comm_ms`` — the fraction of collective
@@ -43,12 +53,14 @@ this number toward 1.0 while ``step_ms`` approaches ``ideal_ms``.
 
 from __future__ import annotations
 
+import bisect
+import dataclasses
 import glob
 import gzip
 import json
 import os
 import re
-from contextlib import contextmanager, nullcontext
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 SCOPE_PREFIX = "anat"
@@ -153,39 +165,16 @@ def phase_scope(phase: Optional[str] = None, bucket: Optional[int] = None,
     return jax.named_scope(name)
 
 
-@contextmanager
-def trace_annotation(phase: Optional[str] = None,
-                     bucket: Optional[int] = None):
-    """Host-side ``jax.profiler.TraceAnnotation`` with the contract
-    name — the capture-driver twin of :func:`phase_scope` for backends
-    whose device traces carry no per-op lanes. Degrades to a no-op if
-    the profiler annotation cannot start."""
-    name = scope_name(phase, bucket)
-    try:
-        import jax
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:
-        cm = nullcontext()
-    with cm:
-        yield
-
-
-def parse_scope_level(
-        name: Any) -> Optional[Tuple[Optional[str], Optional[int],
-                                     Optional[int]]]:
-    """Extract ``(phase, bucket, level)`` from any name carrying the
-    contract — a bare annotation (``anat/b000/select``,
-    ``anat/b000/lvl1/exchange``) or a compiled-HLO op path
-    (``jit(step)/.../anat/b000/anat/select/add``). Nested scopes merge:
-    bucket, level and phase may come from different ``anat`` components.
-    Returns None when the name carries no contract component; ``level``
-    is None for legacy (single-level) names."""
-    if not isinstance(name, str) or SCOPE_PREFIX not in name:
-        return None
-    parts = name.split("/")
+def _contract_frames(parts: List[str]) -> Optional[
+        Tuple[Optional[str], Optional[int], Optional[int], Optional[str]]]:
+    """``(phase, bucket, level, sub)`` of the ``anat`` frames among the
+    parts of a name; None when it holds no ``anat`` part. Nested frames
+    merge, the innermost of each kind wins; ``sub`` is the innermost name
+    of ``SUB_SCOPES[phase]`` right after a frame of the final phase."""
     phase: Optional[str] = None
     bucket: Optional[int] = None
     level: Optional[int] = None
+    sub: Optional[str] = None
     seen = False
     for i, part in enumerate(parts):
         if part != SCOPE_PREFIX:
@@ -203,8 +192,29 @@ def parse_scope_level(
                 level = int(m.group(1))
                 j += 1
         if j < len(parts) and parts[j] in PHASES:
+            if parts[j] != phase:
+                sub = None
             phase = parts[j]
-    return (phase, bucket, level) if seen else None
+            if j + 1 < len(parts) and parts[j + 1] in SUB_SCOPES.get(
+                    phase, ()):
+                sub = parts[j + 1]
+    return (phase, bucket, level, sub) if seen else None
+
+
+def parse_scope_level(
+        name: Any) -> Optional[Tuple[Optional[str], Optional[int],
+                                     Optional[int]]]:
+    """Extract ``(phase, bucket, level)`` from any name carrying the
+    contract — a bare annotation (``anat/b000/select``,
+    ``anat/b000/lvl1/exchange``) or a compiled-HLO op path
+    (``jit(step)/.../anat/b000/anat/select/add``). Nested scopes merge:
+    bucket, level and phase may come from different ``anat`` components.
+    Returns None when the name carries no contract component; ``level``
+    is None for legacy (single-level) names."""
+    if not isinstance(name, str) or SCOPE_PREFIX not in name:
+        return None
+    parsed = _contract_frames(name.split("/"))
+    return None if parsed is None else parsed[:3]
 
 
 def parse_scope(name: Any) -> Optional[Tuple[Optional[str], Optional[int]]]:
@@ -308,81 +318,93 @@ def _intersection_ms(a: List[Tuple[float, float]],
     return total
 
 
-def analyze_events(events: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
-    """Attribute contract-scoped trace events into the step anatomy.
+@dataclasses.dataclass
+class _Span:
+    """One stretch of time of one phase: a contract-scoped event of a
+    Chrome trace, or the part of a device event that is its own. Times
+    in milliseconds. ``count`` is 0 on the later pieces of an event that
+    its children cut up."""
+    start: float
+    end: float
+    phase: Optional[str]
+    bucket: Optional[int]
+    lane: str
+    level: Optional[int] = None
+    count: int = 1
 
-    Returns None when no contract event is present (the caller
-    journals an ``anatomy_warning``). Times in the trace are
-    microseconds (trace-event convention); everything returned is
-    milliseconds."""
-    spans: List[Tuple[float, float, Optional[str], Optional[int], str,
-                      Optional[int]]] = []
-    for e in events:
-        if e.get("ph") != "X":
-            continue
-        parsed = parse_scope_level(e.get("name"))
-        if parsed is None:
-            continue
-        ts, dur = e.get("ts"), e.get("dur")
-        if not isinstance(ts, (int, float)) or not isinstance(
-                dur, (int, float)) or dur < 0:
-            continue
-        phase, bucket, level = parsed
-        start, end = float(ts) / 1e3, (float(ts) + float(dur)) / 1e3
-        spans.append((start, end, phase, bucket,
-                      lane_of(phase, str(e.get("name"))), level))
-    if not spans:
-        return None
 
-    t0 = min(s for s, *_ in spans)
-    # per-(bucket, phase) totals; phase-less contract events (a bare
-    # "anat/b000" container) attribute to phase "other". Level-tagged
-    # spans (hierarchical collectives) get their own lane key
-    # ("lvl1/exchange") so the two levels of one phase never merge;
-    # legacy keys are unchanged.
+def _critical_path(spans: List[_Span]) -> Dict[str, float]:
+    """Sweep the elementary intervals between the spans' ends; each
+    instant is split equally among the phases active then, and an instant
+    that no span covers lands on ``idle``."""
+    edges: List[Tuple[float, int, str]] = []
+    for sp in spans:
+        if sp.end > sp.start:
+            ph = sp.phase or "other"
+            edges.append((sp.start, 1, ph))
+            edges.append((sp.end, -1, ph))
+    edges.sort(key=lambda e: e[0])
+    critical: Dict[str, float] = {}
+    active: Dict[str, int] = {}
+    total = 0
+    i, n = 0, len(edges)
+    while i < n:
+        t = edges[i][0]
+        while i < n and edges[i][0] == t:
+            _, d, ph = edges[i]
+            active[ph] = active.get(ph, 0) + d
+            total += d
+            i += 1
+        if i == n:
+            break
+        width = edges[i][0] - t
+        if total == 0:
+            critical["idle"] = critical.get("idle", 0.0) + width
+            continue
+        for ph, k in active.items():
+            if k:
+                critical[ph] = critical.get(ph, 0.0) + width * k / total
+    return critical
+
+
+def _scorecard(spans: List[_Span]) -> Dict[str, Any]:
+    """The core both front ends feed: per-(bucket, phase) totals, the
+    compute and collective lanes' unions, their overlap, the measured
+    span against the fully-overlapped bound, the critical path."""
+    t0 = min(sp.start for sp in spans)
+    # per-(bucket, phase) totals; phase-less spans (a bare "anat/b000"
+    # container, a device instruction nobody owns) attribute to "other".
+    # Level-tagged spans (hierarchical collectives) get their own lane
+    # key ("lvl1/exchange") so the two levels of one phase never merge.
     per: Dict[Tuple[int, str], Dict[str, Any]] = {}
     compute_iv: List[Tuple[float, float]] = []
     comm_iv: List[Tuple[float, float]] = []
-    for start, end, phase, bucket, lane, level in spans:
-        pkey = phase or "other"
-        if level is not None:
-            pkey = f"lvl{int(level)}/{pkey}"
-        key = (-1 if bucket is None else int(bucket), pkey)
-        d = per.setdefault(key, {"ms": 0.0, "count": 0, "lane": lane})
-        if level is not None:
-            d["level"] = int(level)
-        d["ms"] += end - start
-        d["count"] += 1
-        if lane == "collective":
+    for sp in spans:
+        pkey = sp.phase or "other"
+        if sp.level is not None:
+            pkey = f"lvl{int(sp.level)}/{pkey}"
+        key = (-1 if sp.bucket is None else int(sp.bucket), pkey)
+        d = per.setdefault(key, {"ms": 0.0, "count": 0, "lane": sp.lane})
+        if sp.level is not None:
+            d["level"] = int(sp.level)
+        d["ms"] += sp.end - sp.start
+        d["count"] += sp.count
+        if sp.lane == "collective":
             d["lane"] = "collective"
-            comm_iv.append((start, end))
+            comm_iv.append((sp.start, sp.end))
         else:
-            compute_iv.append((start, end))
+            compute_iv.append((sp.start, sp.end))
 
     compute_ms = _union_ms(compute_iv)
     comm_ms = _union_ms(comm_iv)
     overlap_ms = _intersection_ms(compute_iv, comm_iv)
-    step_ms = max(e for _, e, *_ in spans) - t0
+    step_ms = max(sp.end for sp in spans) - t0
     ideal_ms = max(compute_ms, comm_ms)
 
-    # critical-path attribution: sweep the span's elementary intervals;
-    # each instant's duration is split equally among the phases active
-    # then (idle gaps — host dispatch between probes, tails — land on
-    # "idle"). The dominant entry is what a latency optimisation must
-    # attack first.
-    bounds = sorted({b for s, e, *_ in spans for b in (s, e)})
-    critical: Dict[str, float] = {}
-    for lo, hi in zip(bounds[:-1], bounds[1:]):
-        if hi <= lo:
-            continue
-        active = [ph or "other" for s, e, ph, _b, _l, _lv in spans
-                  if s <= lo and e >= hi]
-        if not active:
-            critical["idle"] = critical.get("idle", 0.0) + (hi - lo)
-            continue
-        share = (hi - lo) / len(active)
-        for ph in active:
-            critical[ph] = critical.get(ph, 0.0) + share
+    # the dominant entry of the critical path is what a latency
+    # optimisation must attack first (idle: host dispatch between
+    # probes, tails)
+    critical = _critical_path(spans)
     ranked = sorted(((ph, ms) for ph, ms in critical.items()
                      if ph != "idle"), key=lambda kv: -kv[1])
     critical_phase = ranked[0][0] if ranked else None
@@ -407,8 +429,34 @@ def analyze_events(events: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
         "critical_path": {ph: round(ms, 4)
                           for ph, ms in sorted(critical.items())},
         "critical_phase": critical_phase,
-        "events": len(spans),
+        "events": sum(sp.count for sp in spans),
     }
+
+
+def analyze_events(events: List[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+    """Attribute contract-scoped trace events into the step anatomy: the
+    Chrome trace-event front end (an event is named by its scope).
+
+    Returns None when no contract event is present (the caller
+    journals an ``anatomy_warning``). Times in the trace are
+    microseconds (trace-event convention); everything returned is
+    milliseconds."""
+    spans: List[_Span] = []
+    for e in events:
+        if e.get("ph") != "X":
+            continue
+        parsed = parse_scope_level(e.get("name"))
+        if parsed is None:
+            continue
+        ts, dur = e.get("ts"), e.get("dur")
+        if not isinstance(ts, (int, float)) or not isinstance(
+                dur, (int, float)) or dur < 0:
+            continue
+        phase, bucket, level = parsed
+        spans.append(_Span(float(ts) / 1e3, (float(ts) + float(dur)) / 1e3,
+                           phase, bucket,
+                           lane_of(phase, str(e.get("name"))), level))
+    return _scorecard(spans) if spans else None
 
 
 def phase_totals(analysis: Dict[str, Any]) -> Dict[str, float]:
@@ -481,139 +529,463 @@ def analyze_capture(path: str, bus=None, step: int = 0,
 
 
 # ---------------------------------------------------------------------------
-# capture driver
+# whose an instruction of the compiled step is
+
+# the owner rules, in the order they are tried
+HOWS = ("own", "pair", "operand", "user", "body", "none")
+_SEARCH_LEVELS = 12     # operands up / users down, breadth first
+_CALLER_LEVELS = 6      # a body's caller, and that one's caller
+_REPO_DIR = "/oktopk_tpu/"
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OPCODE = re.compile(r"(?:^|[\s)}\]])([a-z][\w\-]*)\(")
+_REF = re.compile(r"%([\w.\-]+)")
+_CALLED = re.compile(
+    r"\b(?:body|condition|calls|to_apply|true_computation"
+    r"|false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_FRAME_ID = re.compile(r"\bstack_frame_id=(\d+)")
+_PAYLOAD = re.compile(r'"[^"]{400,}"')
+_PATH_SEP = re.compile(r"[/()]")
+_TABLE_ROW = re.compile(r"^(\d+)\s+(.*)$")
+_FIELD = re.compile(r"(\w+)=(\d+)")
 
 
-def capture_pipeline_anatomy(cfg, mesh, logdir: str, num_buckets: int = 4,
-                             iters: int = 3, axis_name: str = "data",
-                             bus=None, step: int = 0,
-                             fwd_bwd_elems: int = 1 << 16):
-    """Capture + attribute one step anatomy on the given mesh.
+@dataclasses.dataclass(frozen=True)
+class Owner:
+    """Whose an instruction of the compiled step is. ``how`` names the
+    rule that answered (``HOWS``); ``frame`` is ``file:line function`` of
+    the innermost stack frame inside ``oktopk_tpu/`` (the instruction's
+    own where it carries one, else that of the instruction it inherits
+    from), None where the text has no stack-frame tables."""
+    phase: Optional[str]
+    sub: Optional[str]
+    bucket: Optional[int]
+    how: str
+    frame: Optional[str]
 
-    On backends whose device traces carry no per-op lanes (CPU), the
-    in-jit named scopes never reach the trace, so this driver measures
-    the anatomy by dispatching separately-jitted per-phase subprograms
-    (the profile_step.py decomposition) under host
-    ``TraceAnnotation``s — same shapes and caps as the configured
-    pipeline, one annotation span per (bucket, phase) per iteration.
-    Dispatch is serial by construction, so the resulting
-    ``overlap_ratio`` is the honest floor of today's un-pipelined step;
-    an in-jit device capture on TPU flows through the same analyzer and
-    credits real overlap.
 
-    Returns the analysis dict (journalled on ``bus`` when given), or
-    None when the profiler cannot capture — the caller records
-    ``anatomy_unavailable``/``anatomy_warning`` instead of dying."""
-    import numpy as np
+@dataclasses.dataclass
+class _Inst:
+    opcode: str
+    operands: Tuple[str, ...]
+    op_name: str
+    frame_id: Optional[int]
+    computation: Optional[str]
 
-    import jax
-    import jax.numpy as jnp
 
-    from oktopk_tpu.comm import all_gather, all_to_all, compat
-    from oktopk_tpu.ops import pack_by_region, scatter_sparse, \
-        select_by_threshold
-    from oktopk_tpu.ops.topk import k2threshold_method
-    from jax.sharding import PartitionSpec as P_
-
-    P = int(cfg.num_workers)
-    nb = max(1, int(num_buckets))
-    sizes = [cfg.n // nb] * nb
-    sizes[-1] += cfg.n - sum(sizes)
-    rng = np.random.RandomState(0)
-
-    def sync(x):
-        jax.tree.map(lambda a: np.asarray(a), x)
-
-    probes = []   # (phase, bucket, fn) in dispatch order
-
-    # model-level fwd/bwd stand-in: a matmul-chain gradient sized to be
-    # visible next to the bucket probes (the real model's fwd/bwd is
-    # profiled by profile_step.py's fwd_bwd_dense probe)
-    d = max(32, int(np.sqrt(fwd_bwd_elems)) // 32 * 32)
-    w = jax.device_put(jnp.asarray(rng.randn(d, d).astype(np.float32)))
-    x0 = jax.device_put(jnp.asarray(rng.randn(8, d).astype(np.float32)))
-    fwd_bwd = jax.jit(jax.grad(
-        lambda wv: jnp.sum(jnp.tanh(x0 @ wv @ wv.T) ** 2)))
-    sync(fwd_bwd(w))
-    probes.append(("fwd_bwd", None, lambda: fwd_bwd(w)))
-
-    for bi, n_b in enumerate(sizes):
-        cfg_b = cfg.replace(n=n_b, bucket_index=bi)
-        k_b, cap_p, cap_g = cfg_b.k, cfg_b.cap_pair, cfg_b.cap_gather
-        g_b = jax.device_put(jnp.asarray(
-            rng.randn(n_b).astype(np.float32)))
-        bnd = jnp.asarray(
-            [round(i * n_b / P) for i in range(P + 1)], jnp.int32)
-
-        sel = jax.jit(lambda x, k=k_b, cap=cap_g, c=cfg_b:
-                      select_by_threshold(
-                          x, k2threshold_method(
-                              jnp.abs(x), k, c.threshold_method,
-                              c.bisect_iters).astype(x.dtype),
-                          cap, use_pallas=False))
-        sync(sel(g_b))
-        t_b = jax.jit(lambda x, k=k_b, c=cfg_b: k2threshold_method(
-            jnp.abs(x), k, c.threshold_method, c.bisect_iters))(g_b)
-
-        stage = jax.jit(lambda x, t, b=bnd, cap=cap_p:
-                        pack_by_region(x, jnp.abs(x) >= t, b, P, cap,
-                                       thresh=t, use_pallas=False))
-        sync(stage(g_b, t_b))
-        s_vals, s_idx, _ = stage(g_b, t_b)
-
-        def _exchange(sv, si, gv):
-            # shard_map blocks keep the sharded axis at size 1 — drop it
-            # so all_to_all sees split-axis size == mesh size, and re-add
-            # it so out_specs can concatenate the per-shard results
-            rv = all_to_all(sv[0], axis_name)
-            ri = all_to_all(si[0], axis_name)
-            gg = all_gather(gv[0], axis_name)
-            return rv[None], ri[None], gg[None]
-
-        exchange = jax.jit(compat.shard_map(
-            _exchange, mesh=mesh,
-            in_specs=(P_(axis_name), P_(axis_name), P_(axis_name)),
-            out_specs=(P_(axis_name),) * 3, check_vma=False))
-        sv8 = jnp.broadcast_to(s_vals, (P,) + s_vals.shape)
-        si8 = jnp.broadcast_to(s_idx, (P,) + s_idx.shape)
-        gv8 = jnp.asarray(rng.randn(P, cap_g).astype(np.float32))
-        sync(exchange(sv8, si8, gv8))
-        rv8, ri8, _ = exchange(sv8, si8, gv8)
-
-        combine = jax.jit(
-            lambda rv, ri, x, n_b=n_b:
-            jnp.where(scatter_sparse(n_b, rv, ri) != 0.0, 0.0, x))
-        sync(combine(rv8[0], ri8[0], g_b))
-
-        probes.append(("select", bi, lambda g=g_b, f=sel: f(g)))
-        probes.append(("stage", bi,
-                       lambda g=g_b, t=t_b, f=stage: f(g, t)))
-        probes.append(("exchange", bi,
-                       lambda a=sv8, b=si8, c=gv8, f=exchange: f(a, b, c)))
-        probes.append(("combine", bi,
-                       lambda a=rv8[0], b=ri8[0], g=g_b, f=combine:
-                       f(a, b, g)))
-
-    # model-level optimizer: SGD-momentum update on the flat vector
-    gm = jax.device_put(jnp.asarray(rng.randn(cfg.n).astype(np.float32)))
-    pm = jnp.zeros_like(gm)
-    opt = jax.jit(lambda p, m, g: (p - 0.1 * (0.9 * m + g), 0.9 * m + g))
-    sync(opt(pm, pm, gm))
-    probes.append(("optimizer", None, lambda: opt(pm, pm, gm)))
-
-    os.makedirs(logdir, exist_ok=True)
-    try:
-        jax.profiler.start_trace(logdir, create_perfetto_trace=True)
-    except Exception:
+def parse_op_path(op_name: str):
+    """``(phase, bucket, level, sub)`` of an instruction's ``op_name``, or
+    None: :func:`parse_scope_level`, but brackets split the path too
+    (``transpose(jvp(anat/fwd_bwd/experts))``) and the sub-scope comes
+    with it."""
+    if SCOPE_PREFIX not in op_name:
         return None
+    return _contract_frames([p for p in _PATH_SEP.split(op_name) if p])
+
+
+def _operands_of(rest: str, start: int) -> Tuple[str, ...]:
+    """The ``%names`` between the bracket at ``rest[start]`` and the one
+    that closes it."""
+    depth = 0
+    for i in range(start, len(rest)):
+        c = rest[i]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if depth == 0:
+                return tuple(_REF.findall(rest, start, i))
+    return tuple(_REF.findall(rest, start))
+
+
+def _hlo_table(lines: List[str], title: str) -> Dict[int, str]:
     try:
-        for _ in range(max(1, int(iters))):
-            for phase, bucket, fn in probes:
-                with trace_annotation(phase, bucket):
-                    sync(fn())
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            return None
-    return analyze_capture(logdir, bus=bus, step=step, source="host_probe")
+        i = lines.index(title)
+    except ValueError:
+        return {}
+    out: Dict[int, str] = {}
+    for line in lines[i + 1:]:
+        m = _TABLE_ROW.match(line)
+        if not m:
+            break
+        out[int(m.group(1))] = m.group(2)
+    return out
+
+
+def _frame_names(lines: List[str]) -> Dict[int, Optional[str]]:
+    """stack_frame_id -> ``file:line function`` of the innermost frame
+    inside the repo, from the text's four tables. A row's
+    ``parent_frame_id`` is printed one above the parent's id; 1 is no
+    parent."""
+    files = {k: v.strip('"') for k, v in _hlo_table(
+        lines, "FileNames").items()}
+    funcs = {k: v.strip('"') for k, v in _hlo_table(
+        lines, "FunctionNames").items()}
+    locs = {k: {f: int(n) for f, n in _FIELD.findall(v)}
+            for k, v in _hlo_table(lines, "FileLocations").items()}
+    frames = {k: {f: int(n) for f, n in _FIELD.findall(v)}
+              for k, v in _hlo_table(lines, "StackFrames").items()}
+    out: Dict[int, Optional[str]] = {}
+    for fid in frames:
+        cur, seen, found = fid, set(), None
+        while cur in frames and cur not in seen:
+            seen.add(cur)
+            loc = locs.get(frames[cur].get("file_location_id"), {})
+            path = files.get(loc.get("file_name_id"), "")
+            if _REPO_DIR in path:
+                found = (f"{path.split(_REPO_DIR, 1)[1]}:"
+                         f"{loc.get('line', 0)} "
+                         f"{funcs.get(loc.get('function_name_id'), '?')}")
+                break
+            cur = frames[cur].get("parent_frame_id", 1) - 1
+        out[fid] = found
+    return out
+
+
+def _parse_hlo(hlo_text: str):
+    """One sweep of the lines: the instructions by name, and who calls
+    each computation (the first that does, in the order written)."""
+    lines = hlo_text.split("\n")
+    insts: Dict[str, _Inst] = {}
+    caller_of: Dict[str, str] = {}
+    computation: Optional[str] = None
+    for line in lines:
+        if not line:
+            continue
+        if line[0] != " ":
+            m = _COMPUTATION.match(line)
+            if m:
+                computation = m.group(1)
+            continue
+        if len(line) >= 1500:
+            # a Mosaic call's line carries its whole kernel as bytes
+            line = _PAYLOAD.sub('"..."', line)
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        op = _OPCODE.search(rest)
+        if not op:
+            continue
+        operands = _operands_of(rest, op.end() - 1)
+        op_name = _OP_NAME.search(rest)
+        frame_id = _FRAME_ID.search(rest)
+        insts[name] = _Inst(op.group(1), operands,
+                            op_name.group(1) if op_name else "",
+                            int(frame_id.group(1)) if frame_id else None,
+                            computation)
+        called = _CALLED.findall(rest)
+        for group in _BRANCHES.findall(rest):
+            called += _REF.findall(group)
+        for c in called:
+            caller_of.setdefault(c, name)
+    return lines, insts, caller_of
+
+
+def owners(hlo_text: str) -> Dict[str, Owner]:
+    """instruction name -> :class:`Owner`, for every instruction of a
+    compiled step's text (``step_fn.lower(...).compile().as_text()``).
+
+    A device event of a TPU trace is named by its instruction and carries
+    no scope; the ``anat/...`` path is in the instruction's ``op_name``.
+    What the compiler made or renamed (asynchronous copies and slices,
+    layout changes, kernels of its own) has no ``op_name`` of the
+    program's, and is given the owner of its surroundings. The rules, in
+    this order; ``Owner.how`` says which answered:
+
+    1. ``own``: the instruction's ``op_name`` holds an ``anat/`` phase;
+    2. ``pair``: a ``*-done`` takes its ``*-start``'s owner;
+    3. ``operand``: the nearest instruction up its operands (breadth
+       first, operands in the order written, ``_SEARCH_LEVELS`` deep)
+       whose own ``op_name`` holds a phase;
+    4. ``user``: else the nearest down its users, the same search;
+    5. ``body``: else what rules 1-4 answer for the instruction that
+       calls the computation it lies in (a loop, a branch, a fusion, a
+       call), and for that one's caller, ``_CALLER_LEVELS`` up;
+    6. ``none``.
+
+    A pure function of the text: one sweep of its lines and two adjacency
+    maps; nothing is compiled or run."""
+    lines, insts, caller_of = _parse_hlo(hlo_text)
+    frames = _frame_names(lines)
+    parsed = {name: parse_op_path(i.op_name) if i.op_name else None
+              for name, i in insts.items()}
+    own = {name for name, p in parsed.items() if p and p[0]}
+    users: Dict[str, List[str]] = {}
+    for name, i in insts.items():
+        for o in i.operands:
+            users.setdefault(o, []).append(name)
+
+    def nearest(name: str, neighbours) -> Optional[str]:
+        seen, frontier = {name}, [name]
+        for _ in range(_SEARCH_LEVELS):
+            nxt: List[str] = []
+            for n in frontier:
+                for o in neighbours(n):
+                    if o in seen:
+                        continue
+                    if o in own:
+                        return o
+                    seen.add(o)
+                    nxt.append(o)
+            if not nxt:
+                return None
+            frontier = nxt
+        return None
+
+    def up(n: str):
+        i = insts.get(n)
+        return i.operands if i else ()
+
+    def down(n: str):
+        return users.get(n, ())
+
+    local: Dict[str, Tuple[Optional[str], str]] = {}
+
+    def nearby(name: str) -> Tuple[Optional[str], str]:
+        """Rules 1-4: the instruction whose ``op_name`` answers for
+        ``name`` inside its own computation, and the rule."""
+        if name in local:
+            return local[name]
+        got: Tuple[Optional[str], str] = (None, "none")
+        inst = insts[name]
+        if name in own:
+            got = (name, "own")
+        elif (inst.opcode.endswith("-done") and inst.operands
+                and inst.operands[0] in insts
+                and insts[inst.operands[0]].opcode.endswith("-start")):
+            got = (nearby(inst.operands[0])[0], "pair")
+        for how, neighbours in (("operand", up), ("user", down)):
+            if got[0] is None:
+                got = (nearest(name, neighbours), how)
+        if got[0] is None:
+            got = (None, "none")
+        local[name] = got
+        return got
+
+    def resolve(name: str) -> Tuple[Optional[str], str]:
+        src, how = nearby(name)
+        n = name
+        for _ in range(_CALLER_LEVELS):
+            if src is not None:
+                return src, how
+            n = caller_of.get(insts[n].computation)
+            if n not in insts:
+                break
+            src, how = nearby(n)[0], "body"
+        return (src, how) if src is not None else (None, "none")
+
+    out: Dict[str, Owner] = {}
+    for name, inst in insts.items():
+        src, how = resolve(name)
+        phase, bucket, _level, sub = parsed[src] if src else (None,) * 4
+        frame = frames.get(inst.frame_id)
+        if frame is None and src is not None:
+            frame = frames.get(insts[src].frame_id)
+        out[name] = Owner(phase, sub, bucket, how, frame)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# the device front end: instruction-named events and the owners' map
+
+_NOBODY = Owner(None, None, None, "none", None)
+_KINDS = ("own_ms", "inherited_ms", "control_ms")
+_NEST_EPS = 1e-12
+_DEVICE_PLANE = "/device:TPU:"
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+
+
+def _owner_key(who: Owner) -> Optional[str]:
+    """``fwd_bwd/attention``, ``optimizer``: a row of the owners' table."""
+    return who.phase if who.sub is None else f"{who.phase}/{who.sub}"
+
+
+def _self_time(events: List[Tuple[float, float, str]]):
+    """Give each instant to the innermost event that covers it. ``events``
+    sorted by (start, -end). Returns the pieces ``(lo, hi, index)`` in
+    time order, and which events are containers (they span another event
+    of the line: a loop, a branch, a call)."""
+    pieces: List[Tuple[float, float, int]] = []
+    container = [False] * len(events)
+    stack: List[int] = []
+    cursor = events[0][0]
+
+    def close(i: int):
+        nonlocal cursor
+        end = events[i][1]
+        if end > cursor:
+            pieces.append((cursor, end, i))
+            cursor = end
+
+    for i, (start, end, _) in enumerate(events):
+        while stack and events[stack[-1]][1] <= start:
+            close(stack.pop())
+        if stack:
+            if start > cursor:
+                pieces.append((cursor, start, stack[-1]))
+            if end <= events[stack[-1]][1] + _NEST_EPS:
+                container[stack[-1]] = True
+        cursor = max(cursor, start)
+        stack.append(i)
+    while stack:
+        close(stack.pop())
+    return pieces, container
+
+
+def analyze_device(ops, owner_map: Dict[str, Owner], steps: int = 1,
+                   top: int = 10) -> Optional[Dict[str, Any]]:
+    """Attribute the device events of one chip's line of operations to
+    their owners: the device front end (an event is named by its
+    instruction; :func:`owners` says whose that is).
+
+    ``ops`` are ``(instruction name, start, end)`` in seconds, leaves and
+    the loops, branches and calls that span them alike. Every instant of
+    the union busy time goes to the innermost event that covers it, so
+    the table closes: over all ``(phase, sub)`` the ``own_ms`` (the
+    instruction's own ``op_name`` names the phase), ``inherited_ms`` (it
+    got its owner by rules 2-5) and ``control_ms`` (the time a loop or a
+    branch covers and none of its leaves does, given to the container's
+    owner), plus ``unowned_ms``, are ``busy_ms``. Everything returned is
+    milliseconds a step (the window's total over ``steps``), on top of
+    what :func:`analyze_events` returns, from the same core. None when
+    ``ops`` holds no event."""
+    events = sorted(((float(s), float(e), str(n)) for n, s, e in ops
+                     if e > s), key=lambda ev: (ev[0], -ev[1]))
+    if not events:
+        return None
+    pieces, container = _self_time(events)
+    scale = 1e3 / max(1, int(steps))
+    table: Dict[str, Dict[str, float]] = {}
+    split: Dict[Tuple[int, str], Dict[str, float]] = {}
+    by_name: Dict[str, Dict[str, float]] = {"inherited": {}, "unowned": {}}
+    spans: List[_Span] = []
+    unowned = busy = 0.0
+    counted = set()
+    for lo, hi, i in pieces:
+        name = events[i][2]
+        who = owner_map.get(name, _NOBODY)
+        ms = (hi - lo) * scale
+        busy += ms
+        if who.how == "none":
+            kind = "unowned"
+            unowned += ms
+        else:
+            kind = ("control" if container[i] else
+                    "own" if who.how == "own" else "inherited")
+            for rows, key in ((table, _owner_key(who)), (split, (
+                    -1 if who.bucket is None else who.bucket, who.phase))):
+                row = rows.setdefault(key, dict.fromkeys(_KINDS, 0.0))
+                row[kind + "_ms"] += ms
+        if kind in by_name:
+            by_name[kind][name] = by_name[kind].get(name, 0.0) + ms
+        lane = lane_of(who.phase, name)
+        first = i not in counted
+        counted.add(i)
+        last = spans[-1] if spans else None
+        if (last is not None and last.end >= lo * scale - _NEST_EPS
+                and (last.phase, last.bucket, last.lane)
+                == (who.phase, who.bucket, lane)):
+            last.end = hi * scale
+            last.count += first
+        else:
+            spans.append(_Span(lo * scale, hi * scale, who.phase,
+                               who.bucket, lane, count=int(first)))
+
+    out = _scorecard(spans)
+    for (bucket, phase), cell in split.items():
+        out["buckets"][bucket][phase].update(cell)
+
+    def largest(kind: str):
+        ranked = sorted(by_name[kind].items(), key=lambda r: (-r[1], r[0]))
+        return [{"name": n, "ms": ms, "how": who.how,
+                 "owner": _owner_key(who), "frame": who.frame}
+                for n, ms in ranked[:top]
+                for who in (owner_map.get(n, _NOBODY),)]
+
+    out.update(
+        owners=dict(sorted(table.items())),
+        unowned_ms=unowned, busy_ms=busy,
+        largest_inherited=largest("inherited"),
+        largest_unowned=largest("unowned"), steps=int(steps))
+    return out
+
+
+def find_xplane_file(path: str) -> Optional[str]:
+    """A ``.xplane.pb`` file itself, or the newest one under a profiler
+    logdir (``plugins/profile/<time>/<host>.xplane.pb``)."""
+    if os.path.isfile(path):
+        return path
+    found = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def _load_profile(path: str):
+    import jax
+    return jax.profiler.ProfileData.from_file(path)
+
+
+def _device_ops(profile) -> List[List[Tuple[str, float, float]]]:
+    """For each ``/device:TPU:<i>`` plane, in the planes' order, the
+    events of its line of operations as ``(instruction name, start,
+    end)`` in seconds. An event is named by its instruction's whole text
+    (``%fusion.4 = bf16[...] fusion(...)``). Where the plane has a line
+    of programs, only the operations inside executions of the program
+    that took most of the capture are kept: the train step."""
+    chips = []
+    for plane in profile.planes:
+        if not plane.name.startswith(_DEVICE_PLANE):
+            continue
+        ops: List[Tuple[str, float, float]] = []
+        modules: Dict[str, List[Tuple[float, float]]] = {}
+        for line in plane.lines:
+            if line.name not in (_OPS_LINE, _MODULES_LINE):
+                continue
+            for ev in line.events:
+                start = ev.start_ns * 1e-9
+                end = start + ev.duration_ns * 1e-9
+                if line.name == _MODULES_LINE:
+                    modules.setdefault(ev.name, []).append((start, end))
+                    continue
+                m = _INSTRUCTION.match(ev.name)
+                ops.append((m.group(1) if m else ev.name, start, end))
+        if modules:
+            runs = _merged(max(modules.values(), key=_union_ms))
+            starts = [s for s, _ in runs]
+            kept = []
+            for op in ops:
+                k = bisect.bisect_right(starts, op[1]) - 1
+                if k >= 0 and op[1] < runs[k][1]:
+                    kept.append(op)
+            ops = kept
+        if ops:
+            chips.append(ops)
+    return chips
+
+
+def analyze_xplane(path: str, hlo_text, steps: int = 1
+                   ) -> Optional[Dict[str, Any]]:
+    """A ``jax.profiler`` capture (an ``.xplane.pb`` or the logdir that
+    holds one) and the compiled step's text -> :func:`analyze_device` of
+    the first chip, with ``chips`` (how many device planes the capture
+    holds) beside it. ``hlo_text`` may be a function that gives the text
+    (``Trainer.step_hlo``): it is called only once the capture is known
+    to hold a device plane. None where it holds none (a CPU run: only
+    host threads appear). Raises what reading the file or making the
+    text raises: the caller decides whether that may stop it."""
+    resolved = find_xplane_file(path)
+    if resolved is None:
+        return None
+    chips = _device_ops(_load_profile(resolved))
+    if not chips:
+        return None
+    if callable(hlo_text):
+        hlo_text = hlo_text()
+    out = analyze_device(chips[0], owners(hlo_text), steps=steps)
+    if out is not None:
+        out["chips"] = len(chips)
+    return out

@@ -20,7 +20,12 @@ observability must never take down training (some backends/platforms
 cannot start a trace at all).
 
 The window is the profiler's own trace: it holds the program's host spans
-(``utils/profiling.span``) beside the device planes, on one clock.
+(``utils/profiling.span``) beside the device planes, on one clock. Where it
+holds device planes (a TPU run) and the tracer was given the compiled
+step's text (``Trainer.step_hlo``), the capture is reduced on the spot:
+``anatomy.analyze_xplane`` labels every device event with its owner and
+the window's anatomy is journalled as ``step_anatomy`` / ``overlap_report``
+events with ``source: "device"``, milliseconds a captured step.
 """
 
 from __future__ import annotations
@@ -37,13 +42,16 @@ class AnomalyTracer:
 
     def __init__(self, logdir: str, bus=None, num_steps: int = 3,
                  max_captures: int = 3,
-                 step_counters: Optional[Callable[[], list]] = None):
+                 step_counters: Optional[Callable[[], list]] = None,
+                 step_hlo: Optional[Callable[[], str]] = None):
         self.logdir = logdir
         self.bus = bus
         self.num_steps = max(1, int(num_steps))
         self.max_captures = max(0, int(max_captures))
         # () -> [(step, counters array), ...]: Trainer.step_counters
         self.step_counters = step_counters
+        # () -> the compiled step's text: Trainer.step_hlo
+        self.step_hlo = step_hlo
         self.captures: List[Dict[str, Any]] = []
         self._armed: Optional[str] = None      # trigger description
         self._start_step: Optional[int] = None
@@ -119,6 +127,29 @@ class AnomalyTracer:
         self._profiler_ok = False
         if self.bus is not None:
             self.bus.emit("trace_captured", **cap)
+        self._journal_anatomy(cap)
+
+    def _journal_anatomy(self, cap: Dict[str, Any]):
+        """The captured window's device time by owner, a step. Nothing
+        where there is no capture, no bus or no step text; a capture
+        without device planes (a CPU run) journals an
+        ``anatomy_warning``."""
+        if (self.bus is None or self.step_hlo is None
+                or cap["logdir"] is None or cap["num_steps"] < 1):
+            return
+        try:
+            from oktopk_tpu.obs import anatomy
+            analysis = anatomy.analyze_xplane(
+                cap["logdir"], self.step_hlo, steps=cap["num_steps"])
+            anatomy.emit_anatomy(
+                self.bus, analysis, step=cap["step"], source="device",
+                warn_reason="no device plane in the capture",
+                warn_path=cap["logdir"])
+        except Exception:
+            # a compile, a file of the profiler's, a text of another
+            # XLA: the window is journalled, its anatomy is not
+            logging.getLogger(__name__).exception(
+                "the captured window's anatomy not journalled")
 
     def finish(self, step: int):
         """Force-close any open window (end of train())."""

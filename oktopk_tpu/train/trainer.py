@@ -134,6 +134,7 @@ class Trainer:
         self.bus = None
         self.run_journal = None
         self.tracer = None
+        self._fed_shapes = None
         self.regress = None
         self.rollup = None
         self._quality_cfg = None
@@ -172,7 +173,8 @@ class Trainer:
                 self.tracer = AnomalyTracer(
                     tdir, bus=self.bus, num_steps=cfg.obs_trace_steps,
                     max_captures=cfg.obs_max_traces,
-                    step_counters=self.step_counters)
+                    step_counters=self.step_counters,
+                    step_hlo=self.step_hlo)
             if cfg.obs_regress_key:
                 from oktopk_tpu.obs.regress import RegressionDetector
                 self.regress = RegressionDetector.from_bench_records(
@@ -753,6 +755,10 @@ class Trainer:
                 self.tracer.on_step(step)
             with span("oktopk/data", step=step):
                 batch = next(data_iter)
+            if self.tracer is not None and self.tracer.active:
+                # what the captured steps are fed, for step_hlo()
+                self._fed_shapes = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch)
             metrics = self.train_step(batch)
             if (self.supervisor is not None
                     and step % max(1, self.cfg.resilience_check_every) == 0):
@@ -886,6 +892,28 @@ class Trainer:
                  "leafwise": lw}
                 for (_, c, _), lw in zip(self._bucket_cfgs(),
                                          self.step_fn.leafwise)]
+
+    def step_hlo(self, batch=None) -> str:
+        """The compiled text of the step as last built, for
+        ``obs/anatomy.owners``: lower and compile again (from the
+        persistent cache where the step has run), nothing executes.
+        ``batch`` gives the shapes the step is fed; left out, those of
+        the anomaly tracer's last captured step, else the registry's
+        example at the configured global batch. Called by
+        nobody on the step path."""
+        if batch is None:
+            batch = self._fed_shapes or self._example_batch(
+                self.cfg.batch_size * self.cfg.num_workers)
+        return self.step_fn.lower(
+            self.state, batch, self._rng).compile().as_text()
+
+    def step_owners(self, batch=None):
+        """Whose each instruction of the compiled step is:
+        ``{instruction name: anatomy.Owner}``, the map that
+        ``anatomy.analyze_device`` / ``analyze_xplane`` label a device
+        trace with."""
+        from oktopk_tpu.obs import anatomy
+        return anatomy.owners(self.step_hlo(batch))
 
     def _emit_volume_report(self):
         """One ``volume_report`` event per bucket: mean realised wire

@@ -51,78 +51,6 @@ def _med_ms(fn, sync, iters, timers=None, name=None):
     return statistics.median(ts)
 
 
-def _parse_phase_limits(specs):
-    """--phase-limit exchange=50 [--phase-limit select=120 ...]"""
-    limits = {}
-    for spec in specs or []:
-        name, _, val = spec.partition("=")
-        if not name or not val:
-            raise SystemExit(f"--phase-limit wants PHASE=MS, got {spec!r}")
-        limits[name.strip()] = float(val)
-    return limits
-
-
-def _anatomy_main(args):
-    """--anatomy mode: capture one step anatomy on an emulated mesh,
-    journal step_anatomy/overlap_report events, check phase limits."""
-    # must precede `import jax`: the emulated multi-worker CPU mesh
-    # exists only if XLA is told before backend init
-    plat = args.platform or os.environ.get("JAX_PLATFORMS", "") or "cpu"
-    if ("cpu" in plat and args.anatomy_workers > 1
-            and "xla_force_host_platform_device_count"
-            not in os.environ.get("XLA_FLAGS", "")):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count"
-              f"={args.anatomy_workers}").strip()
-    import tempfile
-
-    import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
-
-    from oktopk_tpu.comm.mesh import get_mesh
-    from oktopk_tpu.config import OkTopkConfig
-    from oktopk_tpu.obs.anatomy import capture_pipeline_anatomy, \
-        phase_totals
-    from oktopk_tpu.obs.journal import EventBus, RunJournal
-    from oktopk_tpu.obs.regress import RegressionDetector
-
-    devs = jax.devices()
-    P = min(args.anatomy_workers, len(devs))
-    mesh = get_mesh((P,), ("data",), devices=devs[:P])
-    cfg = OkTopkConfig(n=args.anatomy_n, num_workers=P,
-                       density=args.density, warmup_steps=0)
-    bus = EventBus()
-    RunJournal(args.anatomy_journal, bus)
-    logdir = args.anatomy_logdir or tempfile.mkdtemp(
-        prefix="oktopk_anatomy_")
-    analysis = capture_pipeline_anatomy(
-        cfg, mesh, logdir, num_buckets=args.anatomy_buckets,
-        iters=max(2, min(args.iters, 5)), bus=bus, step=0)
-
-    out = {"journal": args.anatomy_journal, "logdir": logdir,
-           "workers": P, "buckets": args.anatomy_buckets}
-    limits = _parse_phase_limits(args.phase_limit)
-    if analysis is None:
-        out["anatomy_unavailable"] = "profiler capture failed"
-    else:
-        out.update({k2: analysis[k2] for k2 in
-                    ("compute_ms", "comm_ms", "overlap_ms",
-                     "overlap_ratio", "step_ms", "ideal_ms",
-                     "serialization_ms", "critical_phase")})
-        out["phase_totals_ms"] = phase_totals(analysis)
-        if limits:
-            det = RegressionDetector(None, bus=bus, phase_limits=limits)
-            breaches = det.observe_phases(0, out["phase_totals_ms"])
-            out["phase_breaches"] = [b["key"] for b in breaches]
-    print("ANATOMY " + json.dumps(out))
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=2)
-            f.write("\n")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=10)
@@ -138,28 +66,7 @@ def main():
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the profile dict to PATH as JSON "
                          "(machine-readable; feedable to obs/regress.py)")
-    ap.add_argument("--anatomy", action="store_true",
-                    help="capture + analyze + journal a step anatomy "
-                         "(obs/anatomy.py) instead of the subprogram "
-                         "breakdown")
-    ap.add_argument("--anatomy-journal", default="anatomy_journal.jsonl",
-                    metavar="PATH", help="run-journal JSONL for --anatomy")
-    ap.add_argument("--anatomy-buckets", type=int, default=4)
-    ap.add_argument("--anatomy-workers", type=int, default=8,
-                    help="emulated mesh width for --anatomy (forces "
-                         "host-platform device count on CPU)")
-    ap.add_argument("--anatomy-n", type=int, default=1 << 18,
-                    help="flat gradient length for the --anatomy probes")
-    ap.add_argument("--anatomy-logdir", default=None,
-                    help="profiler capture dir (default: fresh tempdir)")
-    ap.add_argument("--phase-limit", action="append", default=[],
-                    metavar="PHASE=MS",
-                    help="journal a regression when a phase-family total "
-                         "exceeds MS (repeatable; --anatomy mode)")
     args = ap.parse_args()
-
-    if args.anatomy:
-        return _anatomy_main(args)
 
     import jax
     if args.platform:

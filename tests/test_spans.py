@@ -101,7 +101,7 @@ class TestSpans:
         assert np.isfinite(float(m["loss"]))
 
     def test_phase_limits_keep_their_old_keys(self, mesh4):
-        """``obs_phase_limits={"step": ...}`` (``--phase-limit step=MS``)
+        """``obs_phase_limits={"step": ...}``
         goes on firing: ``step`` reads the log window's wall time a step,
         ``data`` the ``oktopk/data`` span; span names work as keys too."""
         from oktopk_tpu.obs.regress import RegressionDetector
