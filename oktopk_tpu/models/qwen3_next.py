@@ -13,7 +13,8 @@ at a time, ``k = h W_k``, ``v = h W_v``; ``q`` and ``k`` through ``N`` over a
 head; rotary on the first ``partial_rotary_factor`` of a head's dims (the
 half-split convention, :func:`rotate_half_partial`); causal softmax of
 ``q k^T / sqrt(head_dim)`` in float32, each key-value head serving
-``heads / kv_heads`` query heads (:func:`blocked_causal_gqa`, a sibling of
+``heads / kv_heads`` query heads (:func:`blocked_causal_gqa`: flash kernels
+compiled for a TPU, elsewhere a sibling of
 ``deepseek_v2.blocked_causal_attention``, which is written for MLA's split
 heads and whose program has to stay what it is); ``(out *
 sigmoid(gate)) W_o``.
@@ -40,8 +41,11 @@ rmsnorm(o_t) * w_n * silu(z_t)`` a head and ``W_out``.
 **Recomputation.** A decoder layer is recomputed in the backward pass
 (``nn.remat``) from its input and its mixer's core output (``ATTN_OUT``:
 the delta rule's ``o`` or the attention's weighted sum, [B, T, heads x
-head dim]). Inside a layer: each query block of the attention recomputes
-its scores; the delta rule runs in segments of ``scan_segment`` tokens,
+head dim]; compiled for a TPU the attention names its rows' log-sum-exp so
+too, and its forward kernel runs once a layer). Inside a layer: each query
+block of the attention's XLA form recomputes its scores (the kernels'
+backward pass recomputes a tile's from the log-sum-exp,
+``ops/flash_gqa.py``); the delta rule runs in segments of ``scan_segment`` tokens,
 each recomputed from the state it started with, so that one segment's
 chunk-parallel intermediates and one state a segment are all that is
 alive (no per-token state ever is); what comes before the delta rule
@@ -78,6 +82,7 @@ from jax.ad_checkpoint import checkpoint_name
 from oktopk_tpu.models.deepseek_v2 import (ATTN_OUT, HIGHEST, Kernel, MoE,
                                            held_ids)
 from oktopk_tpu.obs.anatomy import phase_scope
+from oktopk_tpu.ops import flash_gqa
 
 
 # ---- norms ------------------------------------------------------------------
@@ -141,23 +146,16 @@ def _attend_block_gqa(q, k, v, start, end, scale, first=0, window=None):
     return jnp.einsum("grqk,kgd->qgrd", p, v)
 
 
-def blocked_causal_gqa(q, k, v, scale: float, block: int,
-                       window: Optional[int] = None):
-    """Causal attention with grouped heads: q [B, T, H, d], k and v [B, T,
-    G, d] (query head h reads key-value head ``h // (H / G)``) -> [B, T, H,
-    d]. A sequence at a time and ``block`` queries at a time, each block's
-    scores recomputed in the backward pass and its output named
-    ``ATTN_OUT``, as ``deepseek_v2.blocked_causal_attention`` does for
-    MLA's split heads. ``window`` (None: all keys at or before the query):
-    query i reads keys ``i - window < j <= i``, and a block of queries
-    reads, scores and masks only the keys ``[max(0, start - window + 1),
-    end)`` that any of them can see, so a windowed layer's work follows the
-    band and not the causal triangle."""
+def _blocked_xla(q, k, v, scale: float, block: int, window: Optional[int]):
+    """:func:`blocked_causal_gqa` in plain XLA: a sequence at a time and
+    ``block`` queries at a time, each block's scores recomputed in the
+    backward pass and its output named ``ATTN_OUT``, as
+    ``deepseek_v2.blocked_causal_attention`` does for MLA's split heads. A
+    block of queries reads, scores and masks only the keys ``[max(0, start
+    - window + 1), end)`` that any of them can see, so a windowed layer's
+    work follows the band and not the causal triangle."""
     b, t, h, d = q.shape
     g = k.shape[2]
-    block = min(block, t)
-    if window is not None and window >= t:
-        window = None   # every key at or before a query is in its window
 
     def one_sequence(seq):
         qq, kk, vv = seq
@@ -174,6 +172,34 @@ def blocked_causal_gqa(q, k, v, scale: float, block: int,
         return out.reshape(t, h, d)
 
     return lax.map(one_sequence, (q, k, v))
+
+
+def blocked_causal_gqa(q, k, v, scale: float, block: int,
+                       window: Optional[int] = None):
+    """Causal attention with grouped heads: q [B, T, H, d], k and v [B, T,
+    G, d] (query head h reads key-value head ``h // (H / G)``) -> [B, T, H,
+    d]. ``window`` (None: all keys at or before the query): query i reads
+    keys ``i - window < j <= i``. Two forms of one function, and the
+    platform chooses (``ops/flash_gqa.on_this_platform``, which also
+    records the call for ``utils/profiling.snapshot``):
+
+    * compiled for a TPU, ``ops/flash_gqa.flash_gqa``: Pallas kernels,
+      forward and backward, whose score tiles live in VMEM and whose work
+      follows the band. The output and the rows' log-sum-exp are both named
+      ``ATTN_OUT``, so a layer recomputed from its saved names finds the
+      backward kernels' residuals and runs no forward kernel again.
+      ``block`` is not read there: the tiles are the kernel's own rule's;
+    * anywhere else :func:`_blocked_xla`, ``block`` queries at a time
+      (under ``OKTOPK_PALLAS_INTERPRET=1`` the kernels, interpreted: tests).
+    """
+    t = q.shape[1]
+    block = min(block, t)
+    if window is not None and window >= t:
+        window = None   # every key at or before a query is in its window
+    if flash_gqa.on_this_platform(t, q.shape[2] // k.shape[2], q.shape[3],
+                                  window, block):
+        return flash_gqa.flash_gqa(q, k, v, scale, window, save_as=ATTN_OUT)
+    return _blocked_xla(q, k, v, scale, block, window)
 
 
 class GatedAttention(nn.Module):

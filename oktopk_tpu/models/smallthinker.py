@@ -21,12 +21,16 @@ over the whole head) inside a window of ``sliding_window_size`` keys, the
 query's own included.
 
 **Attention** is ``qwen3_next.blocked_causal_gqa`` for both kinds (each
-key-value head serves ``heads / kv_heads`` query heads; a block of queries
-at a time, each block's scores recomputed in the backward pass and its
-output named ``ATTN_OUT``): a windowed layer passes its ``window`` and a
-block then reads, scores and masks only the keys ``[max(0, start - window +
-1), end)``, so its work follows the band; a global layer passes none, and
-its blocks' scores reach over the whole sequence.
+key-value head serves ``heads / kv_heads`` query heads): a windowed layer
+passes its ``window``, a global layer none. Compiled for a TPU that is the
+flash kernels of ``ops/flash_gqa.py``, forward and backward: a tile of
+scores lives in VMEM, a query tile visits only the key tiles that its band
+(a global layer: the causal triangle) crosses, and the output and the rows'
+log-sum-exp are both named ``ATTN_OUT``. Anywhere else it is the blocked
+XLA form: ``attn_block`` queries at a time, each block's scores recomputed
+in the backward pass and its output named ``ATTN_OUT``; a windowed block
+reads, scores and masks only the keys ``[max(0, start - window + 1),
+end)``, a global block's scores reach over the whole sequence.
 
 **Routed experts**: ``deepseek_v2.MoE`` with ``router_input`` (the router
 scores ``N_1(x)`` over ALL experts in float32 at ``highest``, top-k
@@ -42,9 +46,11 @@ times it: such a layer's pairs pass the buffer in some batches, and it
 then runs every held expert over all rows (``routed_experts``).
 
 **Recomputation** as ``models/qwen3_next.py``: a decoder layer is recomputed
-in the backward pass from its input and its attention's weighted sum
-(``ATTN_OUT``), each query block recomputes its scores, the routed experts'
-branch recomputes itself.
+in the backward pass from its input and what its attention named
+``ATTN_OUT`` (the weighted sum; on a TPU the log-sum-exp too, so that the
+forward kernel runs once a layer), the XLA form's query blocks recompute
+their scores and the kernels' backward pass recomputes a tile's from the
+log-sum-exp, the routed experts' branch recomputes itself.
 
 Parameter leaves are ``kernel``, ``embedding``, ``scale`` and ``experts``.
 Left out: the "secondary experts" of the model's description (the published
@@ -97,7 +103,9 @@ class SmallThinkerConfig:
     # which experts this chip holds (ids under moe_num_primary_experts);
     # None: all
     held_experts: Optional[Tuple[int, ...]] = None
-    attn_block: int = 512           # queries a block, both kinds of layer
+    # queries a block of the XLA form, both kinds of layer (the kernels'
+    # tiles are their own rule's)
+    attn_block: int = 512
     dtype: Any = jnp.float32
 
     def __post_init__(self):

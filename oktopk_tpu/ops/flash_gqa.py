@@ -1,0 +1,537 @@
+"""Causal or banded softmax attention with grouped heads as Pallas kernels
+whose scores never leave VMEM, forward or backward.
+
+q [B, T, H, d], k and v [B, T, G, d] (query head h reads key-value head ``h
+// R``, ``R = H / G``), ``window`` None (query i reads the keys ``j <= i``)
+or an integer (``i - window < j <= i``). The plain form
+(``models/qwen3_next._attend_block_gqa``) writes a float32 score block of
+[G, R, queries, keys] to HBM, masks, exponentiates, sums, normalises and
+reads it again for the weighted sum, twice before its backward pass; at
+T = 16,384 that traffic was 64 % of a step (PERF.md, Findings, PR 43).
+Here a tile of scores lives in VMEM from its product to its use:
+
+* **No copy of q, k, v or the output is made.** The arrays are read as
+  they lie, [B, T, heads x d]: a grid step takes the [tq, R x d] slab of
+  the R query heads that share a key-value head and one [tk, d] tile of
+  that head's keys and values, and walks the R heads in the kernel. A
+  key-value tile is fetched once a group and query tile, not once a head.
+* **Work follows the band.** Query tile i visits the key tiles
+  ``first .. last`` of :func:`kv_tiles` and no other is fetched: the grid's
+  innermost extent is the longest such run, a step past a tile's run
+  re-addresses the tile it holds (no DMA) and computes nothing. Only the
+  tiles that the diagonal or the band's trailing edge crosses build a mask
+  (:func:`_interior`).
+* **Forward** (``oktopk_flash_gqa_fwd``): two sweeps over a query tile's
+  key tiles. The first takes the rows' running max and sum (float32
+  scratch); the second accumulates ``exp(x - lse) v`` in the float32 output
+  block itself and writes the rows' log-sum-exp beside it. One sweep with
+  an online rescale is a product cheaper and rounds another number (below).
+* **Backward**, two kernels that recompute a tile's probabilities from the
+  log-sum-exp (no second softmax pass): ``oktopk_flash_gqa_dq`` walks like
+  the forward one and accumulates dq over a query tile's key tiles;
+  ``oktopk_flash_gqa_dkv`` holds a key tile, walks the query tiles that see
+  it (:func:`q_tiles`) with the scores TRANSPOSED ([keys, queries]: the
+  row statistics lie along lanes and every product is a plain one) and
+  sums the R heads of the group into dk and dv. One fused kernel would
+  need dq (58 MB a group at T = 16,384) resident or written once a key
+  tile; two kernels cost two products more.
+* **Precision**: what the plain form's ``einsum`` is on this chip at JAX's
+  default precision, said here because Mosaic's own default for float32
+  operands is the six-pass product: operands rounded to bfloat16 AT each
+  product, ``preferred_element_type`` float32; scale, mask, max, exp and
+  sum in float32. And rounded WHERE the plain form and its transposes
+  round: the normalised probabilities for ``p v`` and for dv, the score
+  gradient times ``scale`` for dq and dk. A rounding of the same size put
+  elsewhere (``exp(x - running max)`` divided after the product, ``scale``
+  applied after it) doubled what the benchmark's gradient check reads, past
+  its limit: a value that differs by a relative 1e-5 before such a rounding
+  differs by ~sqrt(1e-5 x 2^-8) after it (PERF.md, Findings, PR 43). One
+  place is left: the rows' sum of ``p dp`` is taken as ``out . dout``
+  (``dout`` rounded as the product that makes ``dp`` rounds it), which has
+  ``p`` rounded inside ``out`` where the plain form's sum has it whole.
+
+A tile size is :func:`tile_rule`'s, from the shapes and the VMEM a step
+needs, never from a model's name. T is padded to a whole tile; the causal
+mask hides the padding (a padded key is after every real query, a padded
+query's cotangent is zero).
+
+Off a TPU backend the kernels run only interpreted (tests); see
+``models/qwen3_next.blocked_causal_gqa`` for who chooses.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+LANES = 128
+# what a masked score reads: finite, so that a row whose first visited tile
+# holds none of its keys gives exp(0) and no NaN; the first tile that holds
+# one wipes that with exp(MASK - max) = 0 exactly (every row sees its own
+# key, in the last tile it visits)
+MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
+# a + b^T over the last dims, and a . b
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+# of a v5e's 128 MiB of VMEM: what a kernel may be given, and what the tile
+# rule plans for (the compiler's own temporaries come on top)
+VMEM_LIMIT = 100 * 2 ** 20
+VMEM_PLAN = 48 * 2 ** 20
+
+
+# ---- which tiles ----------------------------------------------------------
+
+def _floor0(x):
+    """max(x, 0) of a Python or a traced integer."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _least(x, top: int):
+    return min(x, top) if isinstance(x, int) else jnp.minimum(x, top)
+
+
+def kv_tiles(i, tq: int, tk: int, window: Optional[int]):
+    """(first, last) key tile that query tile ``i`` reads: the keys
+    ``[max(0, start - window + 1), end)`` of its queries ``[start, end)``."""
+    start = i * tq
+    first = 0 if window is None else _floor0(start - window + 1) // tk
+    return first, (start + tq - 1) // tk
+
+
+def q_tiles(j, tq: int, tk: int, window: Optional[int], nq: int):
+    """(first, last) query tile that reads key tile ``j``: the queries
+    ``[start, end - 1 + window)`` of its keys ``[start, end)``, to the
+    sequence's end without a window."""
+    start = j * tk
+    last = (nq - 1 if window is None
+            else _least((start + tk - 1 + window - 1) // tq, nq - 1))
+    return start // tq, last
+
+
+def _interior(i, j, tq: int, tk: int, window: Optional[int]):
+    """Whether every pair of query tile ``i`` and key tile ``j`` is seen:
+    the tile's last key is at or before its first query, and its first key
+    inside the last query's window."""
+    inside = j * tk + tk - 1 <= i * tq
+    if window is not None:
+        inside &= i * tq + tq - 1 - j * tk < window
+    return inside
+
+
+def tile_counts(t: int, tq: int, tk: int, window: Optional[int]):
+    """(key tiles a sequence and head group visits, key tiles the causal
+    triangle holds), over the query tiles of ``t`` tokens."""
+    nq = -(-t // tq)
+    runs = [kv_tiles(i, tq, tk, window) for i in range(nq)]
+    return (sum(last - first + 1 for first, last in runs),
+            sum(last + 1 for _, last in runs))
+
+
+# every distinct attention call traced in this process, for
+# ``utils/profiling.snapshot``: what ran it and what it visits (static)
+_calls = {}
+
+
+def calls():
+    """``[{"kernel", "window", "tiles_visited", "tiles_causal"}, ...]``, an
+    entry a distinct call shape, in the order first traced."""
+    return [dict(c) for c in _calls.values()]
+
+
+def on_this_platform(t: int, r: int, d: int, window: Optional[int],
+                     block: int) -> bool:
+    """Whether the kernels run a call of this shape here, and the call's
+    record. They do where the program is compiled for a TPU and a head is
+    whole lane rows (a tile of k is [tk, d] of [T, G x d]: Mosaic cuts
+    lanes by 128), and, interpreted, where ``OKTOPK_PALLAS_INTERPRET=1``
+    asks (tests; on a TPU backend that raises, as in ``ops/compaction``).
+    Otherwise the caller's plain form does, ``block`` queries at a time
+    against ``block``-wide key tiles in the record."""
+    from oktopk_tpu.ops.compaction import _interpret_default
+    kernel = _interpret_default() or (
+        jax.default_backend() == "tpu" and d % LANES == 0)
+    tq, tk = tile_rule(t, r, d) if kernel else (block, block)
+    visited, causal = tile_counts(t, tq, tk, window)
+    _calls.setdefault((kernel, t, r, d, window, tq, tk), {
+        "kernel": kernel, "window": window, "tiles_visited": visited,
+        "tiles_causal": causal})
+    return kernel
+
+
+def tile_rule(t: int, r: int, d: int) -> Tuple[int, int]:
+    """(tq, tk): 512 keys a tile (four lane rows of scores; fewer where the
+    sequence is shorter), and as many queries, halved while what a step
+    keeps in VMEM passes ``VMEM_PLAN``: the float32 slabs of q, the output
+    and their cotangents ([tq, R x d], two buffers each), the key-value
+    tiles and their gradients, the running statistics and a few score
+    tiles. On a v5e (512, 512) was the fastest of seven sizes from 256 to
+    1,024 in all three of the benchmark's call shapes, forward and
+    backward (PERF.md, Findings, PR 43)."""
+    tk = min(512, -(-t // LANES) * LANES)
+    tq = tk
+
+    def planned(tq):
+        slab = tq * r * d * 4
+        return (3 * 2 * slab + 4 * 2 * tk * d * 4
+                + 2 * r * tq * LANES * 4 + 4 * tq * tk * 4)
+
+    while tq > LANES and planned(tq) > VMEM_PLAN:
+        tq //= 2
+    return tq, tk
+
+
+# ---- kernels ---------------------------------------------------------------
+
+class _Plan(NamedTuple):
+    """What a call is, beside its arrays: static, and hashable."""
+    scale: float
+    window: Optional[int]
+    tq: int
+    tk: int
+    g: int                  # key-value heads
+    r: int                  # query heads a key-value head
+    d: int
+    product: str            # the type a product's operands are rounded to
+    interpret: bool
+    save_as: Optional[str]
+
+    def dot(self, a, b, dims):
+        """One pass over operands rounded to ``product``, accumulated in
+        float32. Said, because Mosaic's own default for float32 operands
+        is the six-pass product."""
+        return lax.dot_general(a.astype(self.product),
+                               b.astype(self.product), dims,
+                               preferred_element_type=jnp.float32)
+
+    def scores(self, q, k, seen):
+        """A head's scaled scores [tq, tk] of its queries' slab columns,
+        masked where ``seen`` is given."""
+        x = self.dot(q, k, _NT) * self.scale
+        return x if seen is None else jnp.where(seen, x, MASK)
+
+    def seen(self, i, j, keys_first: bool):
+        """The mask of tile (i, j): [tq, tk], or [tk, tq]."""
+        shape = (self.tk, self.tq) if keys_first else (self.tq, self.tk)
+        rows = i * self.tq + lax.broadcasted_iota(
+            jnp.int32, shape, 1 if keys_first else 0)
+        cols = j * self.tk + lax.broadcasted_iota(
+            jnp.int32, shape, 0 if keys_first else 1)
+        seen = cols <= rows
+        if self.window is not None:
+            seen &= rows - cols < self.window
+        return seen
+
+    def masked_or_not(self, i, j, run, step):
+        """``step(masked)`` once where ``run``: unmasked on a tile all of
+        whose pairs are seen."""
+        import jax.experimental.pallas as pl
+        inside = _interior(i, j, self.tq, self.tk, self.window)
+        pl.when(run & inside)(functools.partial(step, False))
+        pl.when(run & jnp.logical_not(inside))(functools.partial(step, True))
+
+    def heads(self):
+        """The columns of each of a slab's R heads."""
+        return [slice(h * self.d, (h + 1) * self.d) for h in range(self.r)]
+
+
+def _wide(x, width: int):
+    """[rows, lanes] statistics, every lane alike, at ``width`` lanes."""
+    lanes = x.shape[1]
+    return (x[:, :width] if width <= lanes
+            else jnp.tile(x, (1, width // lanes)))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
+                p: _Plan, steps: int):
+    """Two sweeps over a query tile's key tiles, ``steps`` grid steps
+    each: the rows' max and sum, then the weighted sum of the NORMALISED
+    probabilities ``exp(x - lse)``, which is what the plain form rounds for
+    its ``p v`` (rounding ``exp(x - running max)`` and dividing after is
+    the same precision and another number: on the chip it doubled the
+    benchmark's ``grad1_diff_q1``, PERF.md, Findings, PR 43)."""
+    import jax.experimental.pallas as pl
+    i, s = pl.program_id(2), pl.program_id(3)
+    first, last = kv_tiles(i, p.tq, p.tk, p.window)
+    second = s >= steps
+    j = first + jnp.where(second, s - steps, s)
+
+    @pl.when(s == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_ref[...] = jnp.full_like(m_ref, MASK)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(s == steps)
+    def _():                      # between the sweeps: m becomes the lse
+        m_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+
+    def statistics(masked):
+        k = k_ref[...].astype(p.product)
+        seen = p.seen(i, j, False) if masked else None
+        for h, cols in enumerate(p.heads()):
+            x = p.scores(q_ref[:, cols], k, seen)
+            m_prev = m_ref[h]
+            m_next = jnp.maximum(m_prev, x.max(axis=1, keepdims=True))
+            e = jnp.exp(x - _wide(m_next, p.tk))
+            l_ref[h] = (jnp.exp(m_prev - m_next) * l_ref[h]
+                        + e.sum(axis=1, keepdims=True))
+            m_ref[h] = m_next
+
+    def weighted_sum(masked):
+        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        seen = p.seen(i, j, False) if masked else None
+        for h, cols in enumerate(p.heads()):
+            x = p.scores(q_ref[:, cols], k, seen)
+            o_ref[:, cols] += p.dot(jnp.exp(x - _wide(m_ref[h], p.tk)), v,
+                                    _NN)
+
+    run = j <= last
+    p.masked_or_not(i, j, run & jnp.logical_not(second), statistics)
+    p.masked_or_not(i, j, run & second, weighted_sum)
+
+    @pl.when(s == 2 * steps - 1)
+    def _():
+        lse_ref[...] = m_ref[...]
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
+               p: _Plan, steps: int):
+    import jax.experimental.pallas as pl
+    i, s = pl.program_id(2), pl.program_id(3)
+    first, last = kv_tiles(i, p.tq, p.tk, p.window)
+    j = first + s
+
+    @pl.when(s == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    def step(masked):
+        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        seen = p.seen(i, j, False) if masked else None
+        for h, cols in enumerate(p.heads()):
+            x = p.scores(q_ref[:, cols], k, seen)
+            e = jnp.exp(x - jnp.expand_dims(lse_ref[h, 0], -1))
+            de = p.dot(do_ref[:, cols], v, _NT)
+            dx = e * (de - jnp.expand_dims(delta_ref[h, 0], -1)) * p.scale
+            dq_ref[:, cols] += p.dot(dx, k, _NN)
+
+    p.masked_or_not(i, j, j <= last, step)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, *, p: _Plan, steps: int, nq: int):
+    import jax.experimental.pallas as pl
+    j, s = pl.program_id(2), pl.program_id(3)
+    first, last = q_tiles(j, p.tq, p.tk, p.window, nq)
+    i = first + s
+
+    @pl.when(s == 0)
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    def step(masked):
+        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        seen = p.seen(i, j, True) if masked else None
+        dk, dv = dk_ref[...], dv_ref[...]
+        for h, cols in enumerate(p.heads()):
+            q = q_ref[:, cols].astype(p.product)
+            do = do_ref[:, cols].astype(p.product)
+            x = p.dot(k, q, _NT) * p.scale               # [keys, queries]
+            if masked:
+                x = jnp.where(seen, x, MASK)
+            e = jnp.exp(x - lse_ref[h])
+            dv += p.dot(e, do, _NN)
+            dx = e * (p.dot(v, do, _NT) - delta_ref[h]) * p.scale
+            dk += p.dot(dx, q, _NN)
+        dk_ref[...], dv_ref[...] = dk, dv
+
+    p.masked_or_not(i, j, i <= last, step)
+
+
+# ---- calls -----------------------------------------------------------------
+
+def _longest(runs) -> int:
+    return max(last - first + 1 for first, last in runs)
+
+
+def _specs(p: _Plan, t: int):
+    """The block specs by name, for a grid (sequence, group, tile, step)."""
+    import jax.experimental.pallas as pl
+    tq, tk, window, nq = p.tq, p.tk, p.window, t // p.tq
+    sweep = _longest(kv_tiles(i, tq, tk, window) for i in range(nq))
+
+    def of_q(bb, gg, i, s):        # forward and dq: the query tile is held
+        return i
+
+    def kv_of_q(bb, gg, i, s):     # ... and its key tiles are walked
+        first, last = kv_tiles(i, tq, tk, window)
+        return jnp.minimum(first + s, last)
+
+    def k_twice(bb, gg, i, s):     # the forward pass's two sweeps
+        return kv_of_q(bb, gg, i, jnp.where(s >= sweep, s - sweep, s))
+
+    def v_second(bb, gg, i, s):    # ... the second alone reads v
+        return kv_of_q(bb, gg, i, jnp.maximum(s - sweep, 0))
+
+    def of_kv(bb, gg, j, s):       # dkv: the key tile is held
+        return j
+
+    def q_of_kv(bb, gg, j, s):     # ... and its query tiles are walked
+        first, last = q_tiles(j, tq, tk, window, nq)
+        return jnp.minimum(first + s, last)
+
+    def slab(tile):                # [tq, R x d] of q, out, dq, dout
+        return pl.BlockSpec((None, tq, p.r * p.d), lambda *a: (
+            a[0], tile(*a), a[1]))
+
+    def head(tile):                # [tk, d] of k, v, dk, dv
+        return pl.BlockSpec((None, tk, p.d), lambda *a: (
+            a[0], tile(*a), a[1]))
+
+    def rows(tile):                # [R, 1, tq] of lse, delta: along lanes
+        return pl.BlockSpec((None, p.r, 1, tq), lambda *a: (
+            a[0], a[1], 0, tile(*a)))
+
+    return dict(
+        sweep=sweep, q=slab(of_q), kv=head(kv_of_q), rows=rows(of_q),
+        k_twice=head(k_twice), v_second=head(v_second),
+        q_walked=slab(q_of_kv), kv_held=head(of_kv),
+        rows_walked=rows(q_of_kv),
+        # [R, tq, lanes] of the forward pass's log-sum-exp, one lane row a
+        # query as the running statistics lie
+        lse_out=pl.BlockSpec((None, p.r, tq, min(LANES, tk)), lambda *a: (
+            a[0], a[1], a[2], 0)))
+
+
+def _call(kernel, name, p: _Plan, grid, steps, in_specs, out_specs,
+          out_shape, scratch, args, sweeps=1):
+    """``kernel`` over ``grid`` + (``sweeps`` x ``steps``,): the innermost
+    extent is the longest run of tiles that a held tile walks."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        functools.partial(kernel, p=p, steps=steps),
+        grid=grid + (sweeps * steps,), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=p.interpret, name=name)(*args)
+
+
+def _forward(p: _Plan, q, k, v):
+    """q [B, T, H x d], k and v [B, T, G x d], T whole tiles -> the output
+    [B, T, H x d] and the log-sum-exp [B, H, T]."""
+    from jax.experimental.pallas import tpu as pltpu
+    b, t, _ = q.shape
+    sp = _specs(p, t)
+    lanes = min(LANES, p.tk)
+    out, lse = _call(
+        _fwd_kernel, "oktopk_flash_gqa_fwd", p, (b, p.g, t // p.tq),
+        sp["sweep"], [sp["q"], sp["k_twice"], sp["v_second"]],
+        [sp["q"], sp["lse_out"]],
+        [jax.ShapeDtypeStruct(q.shape, jnp.float32),
+         jax.ShapeDtypeStruct((b, p.g * p.r, t, lanes), jnp.float32)],
+        [pltpu.VMEM((p.r, p.tq, lanes), jnp.float32)] * 2, (q, k, v),
+        sweeps=2)
+    return out, lse[..., 0]
+
+
+def _backward(p: _Plan, q, k, v, out, lse, dout):
+    b, t, _ = q.shape
+    sp = _specs(p, t)
+    nq, nk = t // p.tq, t // p.tk
+    # sum_j p_j dp_j a row, as the softmax's own backward pass has it: with
+    # dout rounded as the product that makes dp rounds it, out . dout is
+    # that sum but for p's own rounding in out
+    bits = jnp.finfo(p.product)
+    delta = jnp.sum((out * lax.reduce_precision(
+        dout, bits.nexp, bits.nmant)).reshape(b, t, p.g * p.r, p.d), axis=-1)
+    delta = jnp.moveaxis(delta, 1, 2)[:, :, None]       # [B, H, 1, T]
+    lse = lse[:, :, None]
+
+    dq = _call(
+        _dq_kernel, "oktopk_flash_gqa_dq", p, (b, p.g, nq), sp["sweep"],
+        [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["rows"], sp["rows"]],
+        sp["q"], jax.ShapeDtypeStruct(q.shape, jnp.float32), [],
+        (q, k, v, dout, lse, delta))
+
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, nq=nq), "oktopk_flash_gqa_dkv", p,
+        (b, p.g, nk),
+        _longest(q_tiles(j, p.tq, p.tk, p.window, nq) for j in range(nk)),
+        [sp["q_walked"], sp["kv_held"], sp["kv_held"], sp["q_walked"],
+         sp["rows_walked"], sp["rows_walked"]],
+        [sp["kv_held"]] * 2,
+        [jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2, [],
+        (q, k, v, dout, lse, delta))
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash(p: _Plan, q, k, v):
+    return _forward(p, q, k, v)[0]
+
+
+def _flash_fwd(p: _Plan, q, k, v):
+    out, lse = _forward(p, q, k, v)
+    if p.save_as is not None:
+        # both, or a layer recomputed from its saved names runs this
+        # kernel again for the one it lacks
+        out = checkpoint_name(out, p.save_as)
+        lse = checkpoint_name(lse, p.save_as)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(p: _Plan, saved, dout):
+    return _backward(p, *saved, dout)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _product(interpret: bool):
+    """The type a product's operands are rounded to: what the platform's
+    own ``einsum`` of float32 operands does at JAX's default precision,
+    bfloat16 compiled for the chip, float32 where the interpreter stands in
+    for a CPU."""
+    return jnp.float32 if interpret else jnp.bfloat16
+
+
+def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
+              save_as: Optional[str] = None,
+              interpret: Optional[bool] = None, tiles: Optional[Tuple[int, int]] = None):
+    """softmax(q k^T scale, causal and inside ``window``) v with grouped
+    heads: q [B, T, H, d], k and v [B, T, G, d] float32 -> [B, T, H, d].
+    Differentiable in q, k and v. ``save_as``: the ``checkpoint_name`` that
+    the output and the log-sum-exp carry as residuals, for a caller whose
+    layer is recomputed from named values. ``interpret``: left out, the
+    kernels are compiled on a TPU backend and interpreted off one (a test
+    that compiles for a described chip says False). ``tiles`` (tq, tk) is
+    :func:`tile_rule`'s where not given (tests give small ones).
+    A product's operands are rounded as :func:`_product` says."""
+    b, t, h, d = q.shape
+    g = k.shape[2]
+    if window is not None and window >= t:
+        window = None
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tq, tk = tiles or tile_rule(t, h // g, d)
+    pad = -t % math.lcm(tq, tk)
+
+    def flat(x):
+        x = x.reshape(b, t, -1)
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    plan = _Plan(float(scale), window, tq, tk, g, h // g, d,
+                 jnp.dtype(_product(interpret)).name, interpret, save_as)
+    out = _flash(plan, flat(q), flat(k), flat(v))
+    return out[:, :t].reshape(b, t, h, d)

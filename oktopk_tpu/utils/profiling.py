@@ -380,11 +380,19 @@ def snapshot() -> Dict[str, Any]:
       whether the built step reduces the bucket a leaf at a time (a dense
       bucket whose flat vector nothing reads: none is built or cut up
       again, optim/distributed.py) or flattens it (every other bucket);
+    - ``attention``: every distinct grouped-head attention call traced in
+      this process (``models/qwen3_next.blocked_causal_gqa``): ``kernel``,
+      whether the Pallas kernels of ``ops/flash_gqa.py`` run it or the
+      blocked XLA form; its ``window`` (None: causal); ``tiles_visited``,
+      the key tiles a sequence and head group visits, against
+      ``tiles_causal``, what the causal triangle holds (static, from
+      shapes); empty for a model that has no such layer;
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
     """
     from oktopk_tpu.collectives.state import BRANCHES, COUNTERS
     from oktopk_tpu.obs.anatomy import SUB_SCOPES
+    from oktopk_tpu.ops import flash_gqa
     from oktopk_tpu.utils.compile_cache import compile_counters
 
     recs = list(SETUP.records)
@@ -399,6 +407,7 @@ def snapshot() -> Dict[str, Any]:
         "counter_names": list(COUNTERS),
         "branch_names": list(BRANCHES),
         "capacities": [c for src in sources for c in src.capacities()],
+        "attention": flash_gqa.calls(),
         "sub_scopes": {ph: list(subs) for ph, subs in SUB_SCOPES.items()},
         "step_counters": [{"step": s, "counters": c}
                           for s, c in fetch_counters(pairs)],

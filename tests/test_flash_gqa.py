@@ -1,0 +1,321 @@
+"""ops/flash_gqa.py: the grouped-head flash-attention kernels, interpreted,
+against the blocked XLA form's own block function as oracle; the tile
+arithmetic against a brute-force mask; what ``snapshot()`` says of the
+calls; the two models through the kernels; and the kernels compiled by
+Mosaic for a described v5e at the benchmark's widths (nothing runs)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from oktopk_tpu.models import qwen3_next, smallthinker
+from oktopk_tpu.models.deepseek_v2 import ATTN_OUT
+from oktopk_tpu.ops import flash_gqa
+from oktopk_tpu.utils import profiling
+
+
+def oracle(q, k, v, scale, window):
+    """``_attend_block_gqa`` over one block that is the whole sequence."""
+    _, t, h, d = q.shape
+    g = k.shape[2]
+
+    def one(qq, kk, vv):
+        return qwen3_next._attend_block_gqa(
+            qq.reshape(t, g, h // g, d), kk, vv, 0, t, scale, 0,
+            window).reshape(t, h, d)
+    return jax.vmap(one)(q, k, v)
+
+
+def inputs(b, t, r, d, g=2, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, t, g * r, d)),
+            jax.random.normal(ks[1], (b, t, g, d)),
+            jax.random.normal(ks[2], (b, t, g, d)),
+            jax.random.normal(ks[3], (b, t, g * r, d)))
+
+
+# name -> (tokens, (tq, tk), window)
+MASKS = {
+    "causal": (24, (8, 8), None),
+    "window_under_t": (32, (8, 16), 11),
+    "window_at_least_t": (24, (8, 8), 24),
+    "window_under_a_tile": (24, (8, 8), 3),
+    "t_no_whole_tiles": (21, (8, 8), 10),
+}
+
+
+class TestKernelsAgainstTheBlockFunction:
+    @pytest.mark.parametrize("b", [1, 2])
+    @pytest.mark.parametrize("r", [1, 7, 8])
+    @pytest.mark.parametrize("d", [128, 256])
+    @pytest.mark.parametrize("mask", list(MASKS))
+    def test_forward_and_three_gradients(self, mask, d, r, b):
+        t, tiles, window = MASKS[mask]
+        q, k, v, w = inputs(b, t, r, d)
+        scale = d ** -0.5
+
+        def through(fn):
+            def loss(q, k, v):
+                out = fn(q, k, v)
+                return jnp.sum(out * w), out
+            (_, out), grads = jax.jit(jax.value_and_grad(
+                loss, (0, 1, 2), has_aux=True))(q, k, v)
+            return (out,) + grads
+
+        got = through(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, scale, window, interpret=True, tiles=tiles))
+        want = through(lambda q, k, v: oracle(q, k, v, scale, window))
+        for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("mask", ["causal", "window_under_t"])
+    def test_rounds_where_the_plain_form_rounds(self, mask, monkeypatch):
+        """What the chip computes: operands rounded to bfloat16 at every
+        product, and at the SAME places as XLA:TPU's one-pass ``einsum`` of
+        the plain form and its transposes: the normalised probabilities for
+        ``p v`` and for dv, the scaled score gradient for dq and dk. (The
+        first kernel on the chip rounded ``exp(x - running max)`` and
+        scaled after the product: the same precision, other numbers, and
+        the benchmark's gradient check read twice its sound value.)"""
+        t, tiles, window = MASKS[mask]
+        q, k, v, w = inputs(1, t, 7, 128)
+        b, _, h, d = q.shape
+        g, scale = k.shape[2], d ** -0.5
+
+        def rnd(x):
+            return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+        def grouped(x):
+            return x.reshape(b, t, g, h // g, d)
+
+        rows, cols = np.arange(t)[:, None], np.arange(t)[None]
+        seen = cols <= rows
+        if window is not None:
+            seen &= rows - cols < window
+        x = jnp.einsum("bqgrd,bkgd->bgrqk", rnd(grouped(q)), rnd(k)) * scale
+        prob = jax.nn.softmax(jnp.where(seen, x, -jnp.inf), axis=-1)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", rnd(prob), rnd(v))
+        dprob = jnp.einsum("bqgrd,bkgd->bgrqk", rnd(grouped(w)), rnd(v))
+        # the rows' sum of p dp as the kernels take it: out . dout, with
+        # dout rounded as the product that makes dp rounds it (p's own
+        # rounding inside out is the one place they part from the plain
+        # form's backward pass)
+        delta = jnp.einsum("bqgrd,bqgrd->bgrq", out, rnd(grouped(w)))
+        dx = prob * (dprob - delta[..., None])
+        want = (out.reshape(q.shape),
+                jnp.einsum("bgrqk,bkgd->bqgrd", rnd(dx * scale),
+                           rnd(k)).reshape(q.shape),
+                jnp.einsum("bgrqk,bqgrd->bkgd", rnd(dx * scale),
+                           rnd(grouped(q))),
+                jnp.einsum("bgrqk,bqgrd->bkgd", rnd(prob),
+                           rnd(grouped(w))))
+
+        monkeypatch.setattr(flash_gqa, "_product",
+                            lambda interpret: jnp.bfloat16)
+        got, vjp = jax.vjp(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, scale, window, interpret=True, tiles=tiles), q, k, v)
+        # a rounding that falls elsewhere reads 2e-3 here
+        for name, a, e in zip(("out", "dq", "dk", "dv"), (got,) + vjp(w),
+                              want):
+            err = float(jnp.linalg.norm(a - e) / jnp.linalg.norm(e))
+            assert err < 2e-4, (name, err)
+
+    @pytest.mark.parametrize("mask", list(MASKS))
+    def test_log_sum_exp(self, mask):
+        t, (tq, tk), window = MASKS[mask]
+        if window is not None and window >= t:
+            window = None
+        b, g, r, d = 2, 2, 3, 128
+        q, k, v, _ = inputs(b, t, r, d)
+        pad = -t % max(tq, tk)
+        flat = [jnp.pad(x.reshape(b, t, -1), ((0, 0), (0, pad), (0, 0)))
+                for x in (q, k, v)]
+        plan = flash_gqa._Plan(0.2, window, tq, tk, g, r, d, "float32", True,
+                               None)
+        _, lse = flash_gqa._forward(plan, *flat)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, t, g, r, d),
+                       k) * 0.2
+        rows, cols = np.arange(t)[:, None], np.arange(t)[None]
+        seen = cols <= rows
+        if window is not None:
+            seen &= rows - cols < window
+        want = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+        np.testing.assert_allclose(lse[:, :, :t],
+                                   want.reshape(b, g * r, t), rtol=1e-5,
+                                   atol=1e-5)
+
+
+GEOMETRIES = [(64, 8, 8, None), (64, 8, 8, 20), (64, 16, 8, 20),
+              (64, 8, 16, 20), (64, 8, 8, 1), (64, 8, 8, 3), (64, 8, 8, 8),
+              (64, 8, 8, 9), (64, 16, 16, 17), (64, 32, 8, 40),
+              (16384, 512, 512, 4096), (16384, 512, 512, None),
+              (8192, 256, 512, None)]
+
+
+class TestWhichTiles:
+    @staticmethod
+    def pairs(t, tq, tk, window):
+        """[nq, nk, 2]: whether tile (i, j) holds a seen pair, and whether
+        all its pairs are seen."""
+        rows, cols = np.arange(t)[:, None], np.arange(t)[None]
+        seen = cols <= rows
+        if window is not None:
+            seen &= rows - cols < window
+        tiles = seen.reshape(t // tq, tq, t // tk, tk)
+        return tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+
+    @pytest.mark.parametrize("t,tq,tk,window", GEOMETRIES)
+    def test_every_tile_with_a_seen_pair_is_visited_and_no_other(
+            self, t, tq, tk, window):
+        if t > 4096:    # the benchmark's shapes at an eighth of a tile
+            t, tq, tk = t // 64, tq // 64, tk // 64
+            window = window and window // 64
+        some, every = self.pairs(t, tq, tk, window)
+        nq, nk = some.shape
+        for i in range(nq):
+            first, last = flash_gqa.kv_tiles(i, tq, tk, window)
+            assert [j for j in range(nk) if some[i, j]] == list(
+                range(first, last + 1))
+        for j in range(nk):
+            first, last = flash_gqa.q_tiles(j, tq, tk, window, nq)
+            assert [i for i in range(nq) if some[i, j]] == list(
+                range(first, last + 1))
+        for i in range(nq):
+            for j in range(nk):
+                assert bool(flash_gqa._interior(i, j, tq, tk, window)) == (
+                    bool(every[i, j]))
+        visited, causal = flash_gqa.tile_counts(t, tq, tk, window)
+        assert visited == some.sum()
+        assert causal == self.pairs(t, tq, tk, None)[0].sum()
+
+    def test_the_benchmarks_counts(self):
+        """smallthinker's band at 512-wide tiles: nine key tiles a query
+        tile past the eighth; the triangle 528."""
+        assert flash_gqa.tile_counts(16384, 512, 512, 4096) == (
+            sum(min(i + 1, 9) for i in range(32)), 528)
+        assert flash_gqa.tile_counts(16384, 512, 512, None) == (528, 528)
+
+    @pytest.mark.parametrize("t,r,d", [(16384, 7, 128), (8192, 8, 256),
+                                       (64, 2, 32), (300, 1, 128)])
+    def test_tile_rule_is_whole_lane_rows_inside_its_plan(self, t, r, d):
+        tq, tk = flash_gqa.tile_rule(t, r, d)
+        assert tq % 128 == 0 and tk % 128 == 0 and tk <= 512
+        assert tk - 128 < t or tk == 128
+
+
+@pytest.fixture
+def fresh_calls(monkeypatch):
+    monkeypatch.setattr(flash_gqa, "_calls", {})
+
+
+class TestSnapshot:
+    def test_a_call_is_recorded_once_a_shape(self, fresh_calls, monkeypatch):
+        q, k, v, _ = inputs(1, 64, 2, 32)
+        for _ in range(2):
+            qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
+        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 64)
+        assert profiling.snapshot()["attention"] == [
+            {"kernel": False, "window": 24,
+             "tiles_visited": flash_gqa.tile_counts(64, 16, 16, 24)[0],
+             "tiles_causal": 10},
+            {"kernel": False, "window": None, "tiles_visited": 10,
+             "tiles_causal": 10}]
+        monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
+        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
+        assert profiling.snapshot()["attention"][-1] == {
+            "kernel": True, "window": 24, "tiles_visited": 1,
+            "tiles_causal": 1}
+
+    def test_empty_without_such_a_layer(self, fresh_calls):
+        assert profiling.snapshot()["attention"] == []
+
+
+def _tiny(family):
+    if family == "smallthinker":
+        cfg = smallthinker.SmallThinkerConfig.tiny(
+            held_experts=(0, 1, 2, 3))
+        return smallthinker.SmallThinker(cfg), 4
+    cfg = qwen3_next.Qwen3NextConfig.tiny(held_experts=(0, 1, 2, 3))
+    return qwen3_next.Qwen3Next(cfg), 1
+
+
+class TestModelsThroughTheKernels:
+    @pytest.fixture(params=["smallthinker", "qwen3_next"])
+    def job(self, request):
+        model, layers = _tiny(request.param)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 512)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+
+        def loss(p):
+            logits, _ = model.apply(p, tokens)
+            return jnp.mean(jax.nn.logsumexp(logits, -1) - logits[..., 0])
+        return loss, params, layers
+
+    def test_loss_and_gradients_as_the_xla_form(self, job, monkeypatch):
+        loss, params, _ = job
+        want = jax.jit(jax.value_and_grad(loss))(params)
+        monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
+        got = jax.jit(jax.value_and_grad(loss))(params)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+        for a, e in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+            np.testing.assert_allclose(a, e, rtol=1e-4, atol=1e-6)
+
+    def test_one_forward_kernel_a_layer_under_the_layers_remat(
+            self, job, monkeypatch):
+        loss, params, layers = job
+        monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
+        text = str(jax.make_jaxpr(jax.grad(loss))(params))
+        for kernel in ("fwd", "dq", "dkv"):
+            assert len(re.findall(
+                rf"name=oktopk_flash_gqa_{kernel}\b", text)) == layers, kernel
+
+
+# ---- compiled for a described v5e (nothing runs) ---------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# the benchmark's three call shapes: (B, T, H, G, d, window)
+CALLS = {"smallthinker_window": (1, 16384, 28, 4, 128, 4096),
+         "smallthinker_global": (1, 16384, 28, 4, 128, None),
+         "qwen3next_full": (2, 8192, 16, 2, 256, None)}
+
+
+@pytest.mark.parametrize("call", list(CALLS))
+def test_mosaic_compiles_the_three_kernels_at_the_benchmarks_widths(
+        call, one_chip):
+    b, t, h, g, d, window = CALLS[call]
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.float32, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, t, g, d), jnp.float32, sharding=one_chip)
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_gqa.flash_gqa(
+            q, k, v, d ** -0.5, window, save_as=ATTN_OUT,
+            interpret=False) * w),
+            (0, 1, 2))(q, k, v)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grads).lower(q, k, k, q).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"oktopk_flash_gqa_{kernel}" in text
+    # no score block of [heads, queries, keys] is left in the program
+    assert not re.search(rf"f32\[[\d,]*{min(t, 512)},\d{{4,}}\]", text)
