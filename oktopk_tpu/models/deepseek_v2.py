@@ -31,7 +31,8 @@ pass needs their output, so the layer's recomputation of them is dead
 code), and no score block is ever kept.
 
 **Routed experts** (:class:`MoE`): ``s = softmax(h W_r)`` over ALL
-``n_routed_experts`` in float32 at ``highest`` precision, greedy top-k,
+``n_routed_experts`` in float32 at ``highest`` precision (``scoring``
+``"sigmoid"``: each expert's own sigmoid, ``models/laguna.py``), greedy top-k,
 weights unrenormalised unless ``norm_topk_prob`` (then over the k, held or
 not); the shared experts sit behind a sigmoid gate where ``shared_gate``
 (``models/qwen3_next.py``). ``held_experts`` says
@@ -281,6 +282,12 @@ class Kernel(nn.Module):
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+# a router's scores from its logits [T, experts], by the published
+# ``scoring_func``: over all experts, or each expert's own
+SCORINGS = {"softmax": partial(jax.nn.softmax, axis=-1),
+            "sigmoid": jax.nn.sigmoid}
+
+
 def swiglu(x, w_gate, w_up, w_down, act=jax.nn.silu):
     return (act(x @ w_gate) * (x @ w_up)) @ w_down
 
@@ -375,6 +382,8 @@ class MoE(nn.Module):
     shared_gate: bool = False
     # the routed experts' gate activation, a key of ACTIVATIONS
     hidden_act: str = "silu"
+    # what turns the router's logits into scores, a key of SCORINGS
+    scoring: str = "softmax"
 
     @nn.compact
     def __call__(self, h, router_input=None):
@@ -389,9 +398,8 @@ class MoE(nn.Module):
             w_r = self.param("kernel", nn.initializers.lecun_normal(),
                              (d, self.n_routed_experts))
             r = x if router_input is None else router_input.reshape(-1, d)
-            scores = jax.nn.softmax(
-                jnp.dot(r.astype(jnp.float32), w_r, precision=HIGHEST),
-                axis=-1)
+            scores = SCORINGS[self.scoring](
+                jnp.dot(r.astype(jnp.float32), w_r, precision=HIGHEST))
             top_w, top_i = lax.top_k(scores, k)
             if self.norm_topk_prob:
                 top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from oktopk_tpu.models.alexnet import AlexNet
 from oktopk_tpu.models.caffe_cifar import CaffeCifar
 from oktopk_tpu.models.densenet import DenseNet
+from oktopk_tpu.models.laguna import Laguna, LagunaConfig
 from oktopk_tpu.models.preresnet import PreResNet
 from oktopk_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from oktopk_tpu.models.resnext import ResNeXt
@@ -46,6 +47,8 @@ TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "qwen3_next_tiny": (64, 512),
     "smallthinker_21b_a3b": (16384, 151936),
     "smallthinker_tiny": (64, 512),
+    "laguna_xs2": (16384, 100352),
+    "laguna_tiny": (64, 512),
 }
 
 
@@ -98,6 +101,13 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
     "smallthinker_tiny": lambda **kw: (
         SmallThinker(SmallThinkerConfig.tiny(**kw)),
         _tokens(*TOKEN_LMS["smallthinker_tiny"])),
+    # Laguna-XS.2 at its published config.json; a chip's share comes as
+    # model_kwargs, as for deepseek_v2_lite.
+    "laguna_xs2": lambda **kw: (
+        Laguna(LagunaConfig(**kw)), _tokens(64, 100352)),
+    "laguna_tiny": lambda **kw: (
+        Laguna(LagunaConfig.tiny(**kw)),
+        _tokens(*TOKEN_LMS["laguna_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

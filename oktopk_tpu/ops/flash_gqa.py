@@ -510,8 +510,9 @@ def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
               save_as: Optional[str] = None,
               interpret: Optional[bool] = None, tiles: Optional[Tuple[int, int]] = None):
     """softmax(q k^T scale, causal and inside ``window``) v with grouped
-    heads: q [B, T, H, d], k and v [B, T, G, d] float32 -> [B, T, H, d].
-    Differentiable in q, k and v. ``save_as``: the ``checkpoint_name`` that
+    heads: q [B, T, H, d], k and v [B, T, G, d] -> [B, T, H, d] float32
+    (a narrower q, k or v is widened to float32 first). Differentiable in
+    q, k and v. ``save_as``: the ``checkpoint_name`` that
     the output and the log-sum-exp carry as residuals, for a caller whose
     layer is recomputed from named values. ``interpret``: left out, the
     kernels are compiled on a TPU backend and interpreted off one (a test
@@ -520,6 +521,10 @@ def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
     A product's operands are rounded as :func:`_product` says."""
     b, t, h, d = q.shape
     g = k.shape[2]
+    # the kernels read and write float32: a caller that computes in a
+    # narrower type is widened here (its products round to bfloat16 all the
+    # same) and the cast's own transpose narrows the three cotangents
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     if window is not None and window >= t:
         window = None
     if interpret is None:

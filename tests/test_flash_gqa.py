@@ -1,7 +1,7 @@
 """ops/flash_gqa.py: the grouped-head flash-attention kernels, interpreted,
 against the blocked XLA form's own block function as oracle; the tile
 arithmetic against a brute-force mask; what ``snapshot()`` says of the
-calls; the two models through the kernels; and the kernels compiled by
+calls; the three models through the kernels; and the kernels compiled by
 Mosaic for a described v5e at the benchmark's widths (nothing runs)."""
 
 import re
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oktopk_tpu.models import qwen3_next, smallthinker
+from oktopk_tpu.models import laguna, qwen3_next, smallthinker
 from oktopk_tpu.models.deepseek_v2 import ATTN_OUT
 from oktopk_tpu.ops import flash_gqa
 from oktopk_tpu.utils import profiling
@@ -45,6 +45,13 @@ MASKS = {
     "window_under_a_tile": (24, (8, 8), 3),
     "t_no_whole_tiles": (21, (8, 8), 10),
 }
+# laguna's: six query heads a key-value head, and a band one tile wide or
+# narrower (every visited tile is an edge tile and builds its mask)
+NARROW = {
+    "window_is_a_tile": (32, (8, 8), 8),
+    "window_under_a_tile": MASKS["window_under_a_tile"],
+    "causal": MASKS["causal"],
+}
 
 
 class TestKernelsAgainstTheBlockFunction:
@@ -71,6 +78,70 @@ class TestKernelsAgainstTheBlockFunction:
         for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
             np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
                                        err_msg=name)
+
+    @pytest.mark.parametrize("r", [6, 8])
+    @pytest.mark.parametrize("mask", list(NARROW))
+    def test_six_heads_a_group_and_a_band_one_tile_wide(self, mask, r):
+        """Values and the three gradients against the WHOLE masked score
+        matrix (no block function): laguna's two call shapes, R = 6 causal
+        and R = 8 in a window equal to the tile, and the window under a
+        tile; with a window of a tile no visited tile is whole."""
+        t, tiles, window = NARROW[mask]
+        q, k, v, w = inputs(1, t, r, 128)
+        scale = 128 ** -0.5
+        if window is not None:
+            nq = t // tiles[0]
+            assert not any(
+                flash_gqa._interior(i, j, *tiles, window)
+                for i in range(nq)
+                for j in range(flash_gqa.kv_tiles(i, *tiles, window)[0],
+                               flash_gqa.kv_tiles(i, *tiles, window)[1] + 1))
+
+        def masked(q, k, v):
+            kk, vv = (jnp.repeat(x, r, axis=2) for x in (k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * scale
+            i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+            seen = j <= i
+            if window is not None:
+                seen = seen & (i - j < window)
+            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+        def through(fn):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(w)
+
+        got = through(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, scale, window, interpret=True, tiles=tiles))
+        for name, a, e in zip(("out", "dq", "dk", "dv"), got,
+                              through(masked)):
+            np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+
+    def test_a_bfloat16_caller_gets_bfloat16_cotangents(self):
+        """A model that computes in bfloat16 (the benchmark's control) hands
+        the kernels bfloat16 q, k, v: they are widened on entry, the output
+        is float32 and the three gradients come back in the caller's type
+        (before PR 44 the backward rule returned float32 ones and the
+        gradient's program did not trace)."""
+        t, tiles, window = MASKS["window_under_t"]
+        q, k, v, w = inputs(1, t, 6, 128)
+        narrow = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+
+        def through(fn, args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(w)
+
+        got = through(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, 128 ** -0.5, window, interpret=True, tiles=tiles),
+            narrow)
+        want = through(lambda q, k, v: oracle(q, k, v, 128 ** -0.5, window),
+                       [x.astype(jnp.float32) for x in narrow])
+        assert got[0].dtype == jnp.float32
+        assert [g.dtype for g in got[1:]] == [jnp.bfloat16] * 3
+        for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a.astype(jnp.float32), e, rtol=1e-2,
+                                       atol=1e-2, err_msg=name)
 
     @pytest.mark.parametrize("mask", ["causal", "window_under_t"])
     def test_rounds_where_the_plain_form_rounds(self, mask, monkeypatch):
@@ -153,7 +224,7 @@ GEOMETRIES = [(64, 8, 8, None), (64, 8, 8, 20), (64, 16, 8, 20),
               (64, 8, 16, 20), (64, 8, 8, 1), (64, 8, 8, 3), (64, 8, 8, 8),
               (64, 8, 8, 9), (64, 16, 16, 17), (64, 32, 8, 40),
               (16384, 512, 512, 4096), (16384, 512, 512, None),
-              (8192, 256, 512, None)]
+              (8192, 256, 512, None), (16384, 512, 512, 512)]
 
 
 class TestWhichTiles:
@@ -198,9 +269,16 @@ class TestWhichTiles:
         assert flash_gqa.tile_counts(16384, 512, 512, 4096) == (
             sum(min(i + 1, 9) for i in range(32)), 528)
         assert flash_gqa.tile_counts(16384, 512, 512, None) == (528, 528)
+        # laguna's band is one tile wide: two key tiles a query tile past
+        # the first, neither whole (524,288 pairs computed a query tile for
+        # the 262,144 in the band: tile_rule does not follow the window)
+        assert flash_gqa.tile_rule(16384, 8, 128) == (512, 512)
+        assert flash_gqa.tile_rule(16384, 6, 128) == (512, 512)
+        assert flash_gqa.tile_counts(16384, 512, 512, 512) == (63, 528)
 
     @pytest.mark.parametrize("t,r,d", [(16384, 7, 128), (8192, 8, 256),
-                                       (64, 2, 32), (300, 1, 128)])
+                                       (64, 2, 32), (300, 1, 128),
+                                       (16384, 6, 128), (16384, 8, 128)])
     def test_tile_rule_is_whole_lane_rows_inside_its_plan(self, t, r, d):
         tq, tk = flash_gqa.tile_rule(t, r, d)
         assert tq % 128 == 0 and tk % 128 == 0 and tk <= 512
@@ -239,12 +317,15 @@ def _tiny(family):
         cfg = smallthinker.SmallThinkerConfig.tiny(
             held_experts=(0, 1, 2, 3))
         return smallthinker.SmallThinker(cfg), 4
+    if family == "laguna":
+        cfg = laguna.LagunaConfig.tiny(held_experts=(0, 1, 2, 3))
+        return laguna.Laguna(cfg), 5
     cfg = qwen3_next.Qwen3NextConfig.tiny(held_experts=(0, 1, 2, 3))
     return qwen3_next.Qwen3Next(cfg), 1
 
 
 class TestModelsThroughTheKernels:
-    @pytest.fixture(params=["smallthinker", "qwen3_next"])
+    @pytest.fixture(params=["smallthinker", "qwen3_next", "laguna"])
     def job(self, request):
         model, layers = _tiny(request.param)
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 512)
@@ -290,10 +371,12 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# the benchmark's three call shapes: (B, T, H, G, d, window)
+# the benchmark's five call shapes: (B, T, H, G, d, window)
 CALLS = {"smallthinker_window": (1, 16384, 28, 4, 128, 4096),
          "smallthinker_global": (1, 16384, 28, 4, 128, None),
-         "qwen3next_full": (2, 8192, 16, 2, 256, None)}
+         "qwen3next_full": (2, 8192, 16, 2, 256, None),
+         "laguna_full": (1, 16384, 48, 8, 128, None),
+         "laguna_sliding": (1, 16384, 64, 8, 128, 512)}
 
 
 @pytest.mark.parametrize("call", list(CALLS))
