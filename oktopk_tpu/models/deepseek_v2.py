@@ -13,22 +13,29 @@ Per layer, pre-norm residual blocks, no biases::
 ``k_pe`` (adjacent pairs, YaRN frequencies, :func:`yarn_inv_freq`); scores
 ``(q_nope k_nope^T + q_pe k_pe^T) * softmax_scale`` with the YaRN
 ``mscale_all_dim`` squared folded into the scale; causal softmax in float32.
-Attention runs a block of queries at a time against the keys at or before
-it (:func:`blocked_causal_attention`), each block recomputed in the backward
-pass, a sequence at a time, so no [heads, T, T] score tensor is ever alive.
+Attention is :func:`blocked_causal_attention`, two forms of one function
+and the platform chooses: compiled for a TPU the flash kernels of
+``ops/flash_gqa.py`` (``flash_mla``: a tile of scores lives in VMEM from its
+product to its use, forward and backward, and the q, k, v parts are read as
+they lie); anywhere else a block of queries at a time against the keys at
+or before it, each block recomputed in the backward pass, a sequence at a
+time. In neither is a [heads, T, T] score tensor ever alive.
 
 **Recomputation**, two levels. Every decoder layer is recomputed in the
 backward pass (``nn.remat``) from what the forward pass keeps of it: its
-input and its attention output (``ATTN_OUT``: [B, T, heads, v_head_dim] in
-the compute dtype, the size of the input, kept a query block at a time).
-Inside a layer each query block, the routed experts' branch and each
-sequence of a dense layer's SwiGLU recompute themselves
-(``jax.checkpoint``). So a block's scores, mask and softmax run twice
-before their backward pass (the forward pass and the block's own
-recomputation: the layer's starts ``o_proj`` from the kept blocks), the
-routed experts' products twice as well (nothing in the layer's backward
-pass needs their output, so the layer's recomputation of them is dead
-code), and no score block is ever kept.
+input and what carries the name ``ATTN_OUT``: its attention output ([B, T,
+heads, v_head_dim], the size of the input; the plain form tags it a query
+block at a time) and, on a TPU, the rows' log-sum-exp beside it, so that
+the recomputed layer runs no forward kernel again. Inside a layer the
+routed experts' branch and each sequence of a dense layer's SwiGLU
+recompute themselves (``jax.checkpoint``), and so does each query block of
+the plain form, whose scores, mask and softmax therefore run twice before
+their backward pass (the forward pass and the block's own recomputation:
+the layer's starts ``o_proj`` from the kept blocks); the kernels' backward
+pass recomputes a tile's probabilities from the log-sum-exp and keeps no
+score anywhere. The routed experts' products run twice as well (nothing in
+the layer's backward pass needs their output, so the layer's recomputation
+of them is dead code).
 
 **Routed experts** (:class:`MoE`): ``s = softmax(h W_r)`` over ALL
 ``n_routed_experts`` in float32 at ``highest`` precision (``scoring``
@@ -69,10 +76,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from oktopk_tpu.obs.anatomy import phase_scope
+from oktopk_tpu.ops import flash_gqa
 
 HIGHEST = lax.Precision.HIGHEST
 # what a decoder layer keeps across its own recomputation beside its input:
-# the output of blocked_causal_attention, tagged a query block at a time
+# the output of blocked_causal_attention (tagged a query block at a time in
+# the plain form) and, from the flash kernels, the rows' log-sum-exp
 ATTN_OUT = "attn_out"
 
 
@@ -132,9 +141,8 @@ def _attend_block(q_nope, q_pe, k_nope, k_pe, v, start, end, scale):
     return jnp.einsum("hqk,khd->qhd", p, v)
 
 
-def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
-                             block: int):
-    """Causal attention [B, T, H, d] -> [B, T, H, dv], a sequence at a time
+def _blocked_xla(q_nope, q_pe, k_nope, k_pe, v, scale: float, block: int):
+    """:func:`blocked_causal_attention` in plain XLA: a sequence at a time
     and ``block`` queries at a time. Each block's scores are recomputed in
     the backward pass (``jax.checkpoint``), so the largest score tensor
     alive is [H, block, T], of one sequence. Each block's output carries
@@ -143,7 +151,6 @@ def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
     because XLA:TPU packs [B, block, H, dv] pieces into the holes of its
     heap, and one [B, T, H, dv] array that lives as long raises it."""
     t = q_nope.shape[1]
-    block = min(block, t)
 
     def one_sequence(seq):
         qn, qp, kn, kp, vv = seq
@@ -157,6 +164,33 @@ def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
     return lax.map(one_sequence, (q_nope, q_pe, k_nope, k_pe, v))
+
+
+def blocked_causal_attention(q_nope, q_pe, k_nope, k_pe, v, scale: float,
+                             block: int):
+    """Causal attention with MLA's split heads: q_nope and k_nope [B, T, H,
+    d], q_pe [B, T, H, rope], k_pe [B, T, rope] (one head, shared by all),
+    v [B, T, H, dv] -> [B, T, H, dv]. Two forms of one function, and the
+    platform chooses (``ops/flash_gqa.split_on_this_platform``, which also
+    records the call for ``utils/profiling.snapshot``), as for grouped
+    heads in ``qwen3_next.blocked_causal_gqa``:
+
+    * compiled for a TPU, ``ops/flash_gqa.flash_mla``: the Pallas kernels,
+      forward and backward, whose score tiles live in VMEM. The output and
+      the rows' log-sum-exp are both named ``ATTN_OUT``, so a layer
+      recomputed from its saved names finds the backward kernels'
+      residuals and runs no forward kernel again. ``block`` is not read
+      there: the tiles are the kernel's own rule's;
+    * anywhere else :func:`_blocked_xla`, ``block`` queries at a time
+      (under ``OKTOPK_PALLAS_INTERPRET=1`` the kernels, interpreted: tests).
+    """
+    t, heads = q_nope.shape[1:3]
+    block = min(block, t)
+    if flash_gqa.split_on_this_platform(
+            t, heads, q_nope.shape[-1], q_pe.shape[-1], v.shape[-1], block):
+        return flash_gqa.flash_mla(q_nope, q_pe, k_nope, k_pe, v, scale,
+                                   save_as=ATTN_OUT)
+    return _blocked_xla(q_nope, q_pe, k_nope, k_pe, v, scale, block)
 
 
 # ---- routed experts ---------------------------------------------------------

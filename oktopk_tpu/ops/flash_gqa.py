@@ -1,13 +1,32 @@
-"""Causal or banded softmax attention with grouped heads as Pallas kernels
-whose scores never leave VMEM, forward or backward.
+"""Causal or banded softmax attention as Pallas kernels whose scores never
+leave VMEM, forward or backward: grouped heads (:func:`flash_gqa`) and
+MLA's split heads (:func:`flash_mla`), one set of kernels.
 
-q [B, T, H, d], k and v [B, T, G, d] (query head h reads key-value head ``h
-// R``, ``R = H / G``), ``window`` None (query i reads the keys ``j <= i``)
-or an integer (``i - window < j <= i``). The plain form
+Grouped heads: q [B, T, H, d], k and v [B, T, G, d] (query head h reads
+key-value head ``h // R``, ``R = H / G``), ``window`` None (query i reads
+the keys ``j <= i``) or an integer (``i - window < j <= i``). The plain form
 (``models/qwen3_next._attend_block_gqa``) writes a float32 score block of
 [G, R, queries, keys] to HBM, masks, exponentiates, sums, normalises and
 reads it again for the weighted sum, twice before its backward pass; at
 T = 16,384 that traffic was 64 % of a step (PERF.md, Findings, PR 43).
+
+Split heads (DeepSeek-V2's multi-head latent attention,
+``models/deepseek_v2.blocked_causal_attention``): a head's score is TWO
+products summed in float32 before the scale, ``q_nope k_nope^T`` over the
+head's own d-wide key and ``q_pe k_pe^T`` over a ``rope``-wide rotary key
+that is ONE head shared by all; values are ``dv`` wide; no two query heads
+share a key-value head. Nothing is packed or padded for it: the five
+arrays are read as they lie, the score width and the value width are told
+apart (:class:`_Plan`), and the rotary part is a second score term. Where
+grouped heads put the R query heads of one key-value head on a grid step,
+split heads put R heads WITH their R key-value column blocks on one
+(:func:`heads_a_step`): a step's mask and its [tk, rope] tile of the
+shared key are built and fetched once for the R of them, and the rotary
+slab [tq, R x rope] is whole lane rows. The rotary key's gradient is
+summed over a step's heads in the kernel and over the steps' packs after
+it. At T = 4,096 the plain form's score blocks were 45 % of a step
+(PERF.md, Findings, PR 45).
+
 Here a tile of scores lives in VMEM from its product to its use:
 
 * **No copy of q, k, v or the output is made.** The arrays are read as
@@ -56,7 +75,8 @@ mask hides the padding (a padded key is after every real query, a padded
 query's cotangent is zero).
 
 Off a TPU backend the kernels run only interpreted (tests); see
-``models/qwen3_next.blocked_causal_gqa`` for who chooses.
+``models/qwen3_next.blocked_causal_gqa`` and
+``models/deepseek_v2.blocked_causal_attention`` for who chooses.
 """
 
 from __future__ import annotations
@@ -144,27 +164,68 @@ def calls():
     return [dict(c) for c in _calls.values()]
 
 
-def on_this_platform(t: int, r: int, d: int, window: Optional[int],
-                     block: int) -> bool:
-    """Whether the kernels run a call of this shape here, and the call's
-    record. They do where the program is compiled for a TPU and a head is
-    whole lane rows (a tile of k is [tk, d] of [T, G x d]: Mosaic cuts
-    lanes by 128), and, interpreted, where ``OKTOPK_PALLAS_INTERPRET=1``
-    asks (tests; on a TPU backend that raises, as in ``ops/compaction``).
-    Otherwise the caller's plain form does, ``block`` queries at a time
-    against ``block``-wide key tiles in the record."""
+def _kernels_here(*lanes: int) -> bool:
+    """Whether the kernels run here: where the program is compiled for a
+    TPU and each of ``lanes`` (the widths that a block cuts the last axis
+    by) is whole lane rows, and, interpreted, where
+    ``OKTOPK_PALLAS_INTERPRET=1`` asks (tests; on a TPU backend that
+    raises, as in ``ops/compaction``)."""
     from oktopk_tpu.ops.compaction import _interpret_default
-    kernel = _interpret_default() or (
-        jax.default_backend() == "tpu" and d % LANES == 0)
-    tq, tk = tile_rule(t, r, d) if kernel else (block, block)
+    return _interpret_default() or (
+        jax.default_backend() == "tpu"
+        and all(n % LANES == 0 for n in lanes))
+
+
+def _record(kernel: bool, t: int, window: Optional[int], tq: int, tk: int,
+            *shape) -> bool:
     visited, causal = tile_counts(t, tq, tk, window)
-    _calls.setdefault((kernel, t, r, d, window, tq, tk), {
+    _calls.setdefault((kernel, t, *shape, window, tq, tk), {
         "kernel": kernel, "window": window, "tiles_visited": visited,
         "tiles_causal": causal})
     return kernel
 
 
-def tile_rule(t: int, r: int, d: int) -> Tuple[int, int]:
+def on_this_platform(t: int, r: int, d: int, window: Optional[int],
+                     block: int) -> bool:
+    """Whether the kernels run a grouped-head call of this shape here
+    (:func:`_kernels_here`: a head is whole lane rows, a tile of k being
+    [tk, d] of [T, G x d], which Mosaic cuts by 128 lanes), and the call's
+    record. Otherwise the caller's plain form does, ``block`` queries at a
+    time against ``block``-wide key tiles in the record."""
+    kernel = _kernels_here(d)
+    tq, tk = tile_rule(t, r, d) if kernel else (block, block)
+    return _record(kernel, t, window, tq, tk, r, d)
+
+
+def split_on_this_platform(t: int, heads: int, d: int, rope: int, dv: int,
+                           block: int) -> bool:
+    """:func:`on_this_platform` for a call with split heads
+    (:func:`flash_mla`): the no-position part and the value of a head are
+    whole lane rows, and so is the rotary slab of the heads that ride a
+    step (or it is all heads')."""
+    r = heads_a_step(heads, rope)
+    kernel = _kernels_here(d, dv, 0 if r == heads else r * rope)
+    tq, tk = tile_rule(t, r, d, dv, rope) if kernel else (block, block)
+    return _record(kernel, t, None, tq, tk, heads, d, rope, dv)
+
+
+# the most split heads that ride one grid step (PERF.md, Findings, PR 45)
+HEADS_A_STEP = 8
+
+
+def heads_a_step(heads: int, rope: int) -> int:
+    """Split heads have a key-value head each, so nothing ties them to a
+    grid step but what a step shares: the one rotary key tile, the mask
+    and the step's own cost. As many as divide ``heads``, keep the rotary
+    slab [tq, r x rope] whole lane rows and are not over ``HEADS_A_STEP``;
+    all of them where no such number is."""
+    fit = [r for r in range(1, min(heads, HEADS_A_STEP) + 1)
+           if heads % r == 0 and (r * rope) % LANES == 0]
+    return max(fit, default=heads)
+
+
+def tile_rule(t: int, r: int, d: int, dv: int = 0,
+              rope: int = 0) -> Tuple[int, int]:
     """(tq, tk): 512 keys a tile (four lane rows of scores; fewer where the
     sequence is shorter), and as many queries, halved while what a step
     keeps in VMEM passes ``VMEM_PLAN``: the float32 slabs of q, the output
@@ -172,13 +233,17 @@ def tile_rule(t: int, r: int, d: int) -> Tuple[int, int]:
     tiles and their gradients, the running statistics and a few score
     tiles. On a v5e (512, 512) was the fastest of seven sizes from 256 to
     1,024 in all three of the benchmark's call shapes, forward and
-    backward (PERF.md, Findings, PR 43)."""
+    backward (PERF.md, Findings, PR 43). ``dv``, ``rope``: split heads
+    (:class:`_Plan`), whose key-value tiles are R heads wide."""
     tk = min(512, -(-t // LANES) * LANES)
     tq = tk
+    dv = dv or d
+    own = r if rope else 1      # key-value heads a step
 
     def planned(tq):
-        slab = tq * r * d * 4
-        return (3 * 2 * slab + 4 * 2 * tk * d * 4
+        slabs = tq * r * (d + rope + 2 * dv) * 4
+        tiles = tk * (own * (d + dv) + rope) * 4
+        return (2 * slabs + 2 * 2 * tiles
                 + 2 * r * tq * LANES * 4 + 4 * tq * tk * 4)
 
     while tq > LANES and planned(tq) > VMEM_PLAN:
@@ -194,12 +259,23 @@ class _Plan(NamedTuple):
     window: Optional[int]
     tq: int
     tk: int
-    g: int                  # key-value heads
-    r: int                  # query heads a key-value head
-    d: int
+    g: int                  # grid groups: key-value heads, or packs of heads
+    r: int                  # query heads a group: a grid step walks them
+    d: int                  # a head's score width (split heads: without
+    # the rotary part)
     product: str            # the type a product's operands are rounded to
     interpret: bool
     save_as: Optional[str]
+    dv: int = 0             # a head's value width; 0: the score width
+    rope: int = 0           # split heads (module docstring): the width of the
+    # rotary part whose key is one head shared by all; 0: grouped heads
+
+    @property
+    def wv(self) -> int:
+        return self.dv or self.d
+
+    def name(self, kernel: str) -> str:
+        return f"oktopk_flash_{'mla' if self.rope else 'gqa'}_{kernel}"
 
     def dot(self, a, b, dims):
         """One pass over operands rounded to ``product``, accumulated in
@@ -209,11 +285,41 @@ class _Plan(NamedTuple):
                                b.astype(self.product), dims,
                                preferred_element_type=jnp.float32)
 
-    def scores(self, q, k, seen):
-        """A head's scaled scores [tq, tk] of its queries' slab columns,
-        masked where ``seen`` is given."""
-        x = self.dot(q, k, _NT) * self.scale
+    def scores(self, h: int, q_ref, k, seen, qr_ref=None, kr=None):
+        """Head ``h``'s scaled scores [tq, tk], of its columns of the
+        queries' slab against its key tile ``k``, masked where ``seen`` is
+        given. ``kr``: the shared rotary key, whose product with the head's
+        columns of ``qr_ref`` is summed to the first in float32, before
+        the scale."""
+        x = self.dot(q_ref[:, self.cols(h, self.d)], k, _NT)
+        if kr is not None:
+            x = x + self.dot(qr_ref[:, self.cols(h, self.rope)], kr, _NT)
+        x = x * self.scale
         return x if seen is None else jnp.where(seen, x, MASK)
+
+    def cols(self, h: int, width: int):
+        """Head ``h``'s columns of a slab or a pack ``width`` wide a head."""
+        return slice(h * width, (h + 1) * width)
+
+    def tile(self, ref, width: int):
+        """head -> its [tk, width] tile of keys or values, rounded for a
+        product: the group's one tile, loaded once before the heads are
+        walked, or (split heads) the head's own columns of the pack's."""
+        if not self.rope:
+            whole = ref[...].astype(self.product)
+            return lambda h: whole
+        return lambda h: ref[:, self.cols(h, width)].astype(self.product)
+
+    def rotary_key(self, ref):
+        """The one [tk, rope] tile of the shared rotary key, rounded for a
+        product and loaded once a step; None without a rotary part."""
+        return ref[...].astype(self.product) if self.rope else None
+
+    def split(self, refs):
+        """A kernel's refs after its plain inputs as ((q_pe, k_pe), the
+        rest): the rotary inputs come last of the inputs. (None, None)
+        without a rotary part."""
+        return (refs[:2], refs[2:]) if self.rope else ((None, None), refs)
 
     def seen(self, i, j, keys_first: bool):
         """The mask of tile (i, j): [tq, tk], or [tk, tq]."""
@@ -235,10 +341,6 @@ class _Plan(NamedTuple):
         pl.when(run & inside)(functools.partial(step, False))
         pl.when(run & jnp.logical_not(inside))(functools.partial(step, True))
 
-    def heads(self):
-        """The columns of each of a slab's R heads."""
-        return [slice(h * self.d, (h + 1) * self.d) for h in range(self.r)]
-
 
 def _wide(x, width: int):
     """[rows, lanes] statistics, every lane alike, at ``width`` lanes."""
@@ -247,8 +349,7 @@ def _wide(x, width: int):
             else jnp.tile(x, (1, width // lanes)))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
-                p: _Plan, steps: int):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, p: _Plan, steps: int):
     """Two sweeps over a query tile's key tiles, ``steps`` grid steps
     each: the rows' max and sum, then the weighted sum of the NORMALISED
     probabilities ``exp(x - lse)``, which is what the plain form rounds for
@@ -256,6 +357,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
     the same precision and another number: on the chip it doubled the
     benchmark's ``grad1_diff_q1``, PERF.md, Findings, PR 43)."""
     import jax.experimental.pallas as pl
+    (qr_ref, kr_ref), (o_ref, lse_ref, m_ref, l_ref) = p.split(rest)
     i, s = pl.program_id(2), pl.program_id(3)
     first, last = kv_tiles(i, p.tq, p.tk, p.window)
     second = s >= steps
@@ -272,10 +374,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
         m_ref[...] = m_ref[...] + jnp.log(l_ref[...])
 
     def statistics(masked):
-        k = k_ref[...].astype(p.product)
+        k, kr = p.tile(k_ref, p.d), p.rotary_key(kr_ref)
         seen = p.seen(i, j, False) if masked else None
-        for h, cols in enumerate(p.heads()):
-            x = p.scores(q_ref[:, cols], k, seen)
+        for h in range(p.r):
+            x = p.scores(h, q_ref, k(h), seen, qr_ref, kr)
             m_prev = m_ref[h]
             m_next = jnp.maximum(m_prev, x.max(axis=1, keepdims=True))
             e = jnp.exp(x - _wide(m_next, p.tk))
@@ -284,12 +386,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
             m_ref[h] = m_next
 
     def weighted_sum(masked):
-        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        k, v = p.tile(k_ref, p.d), p.tile(v_ref, p.wv)
+        kr = p.rotary_key(kr_ref)
         seen = p.seen(i, j, False) if masked else None
-        for h, cols in enumerate(p.heads()):
-            x = p.scores(q_ref[:, cols], k, seen)
-            o_ref[:, cols] += p.dot(jnp.exp(x - _wide(m_ref[h], p.tk)), v,
-                                    _NN)
+        for h in range(p.r):
+            x = p.scores(h, q_ref, k(h), seen, qr_ref, kr)
+            o_ref[:, p.cols(h, p.wv)] += p.dot(
+                jnp.exp(x - _wide(m_ref[h], p.tk)), v(h), _NN)
 
     run = j <= last
     p.masked_or_not(i, j, run & jnp.logical_not(second), statistics)
@@ -300,57 +403,85 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
         lse_ref[...] = m_ref[...]
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                p: _Plan, steps: int):
     import jax.experimental.pallas as pl
+    (qr_ref, kr_ref), (dq_ref, *dqr_ref) = p.split(rest)
     i, s = pl.program_id(2), pl.program_id(3)
     first, last = kv_tiles(i, p.tq, p.tk, p.window)
     j = first + s
 
     @pl.when(s == 0)
     def _():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        for ref in (dq_ref, *dqr_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     def step(masked):
-        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        k, v = p.tile(k_ref, p.d), p.tile(v_ref, p.wv)
+        kr = p.rotary_key(kr_ref)
         seen = p.seen(i, j, False) if masked else None
-        for h, cols in enumerate(p.heads()):
-            x = p.scores(q_ref[:, cols], k, seen)
+        for h in range(p.r):
+            x = p.scores(h, q_ref, k(h), seen, qr_ref, kr)
             e = jnp.exp(x - jnp.expand_dims(lse_ref[h, 0], -1))
-            de = p.dot(do_ref[:, cols], v, _NT)
+            de = p.dot(do_ref[:, p.cols(h, p.wv)], v(h), _NT)
             dx = e * (de - jnp.expand_dims(delta_ref[h, 0], -1)) * p.scale
-            dq_ref[:, cols] += p.dot(dx, k, _NN)
+            if p.rope:  # rounded once, for its two products
+                dx = dx.astype(p.product)
+                dqr_ref[0][:, p.cols(h, p.rope)] += p.dot(dx, kr, _NN)
+            dq_ref[:, p.cols(h, p.d)] += p.dot(dx, k(h), _NN)
 
     p.masked_or_not(i, j, j <= last, step)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, p: _Plan, steps: int, nq: int):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                p: _Plan, steps: int, nq: int):
     import jax.experimental.pallas as pl
+    (qr_ref, kr_ref), (dk_ref, dv_ref, *dkr_ref) = p.split(rest)
     j, s = pl.program_id(2), pl.program_id(3)
     first, last = q_tiles(j, p.tq, p.tk, p.window, nq)
     i = first + s
 
     @pl.when(s == 0)
     def _():
-        dk_ref[...] = jnp.zeros_like(dk_ref)
-        dv_ref[...] = jnp.zeros_like(dv_ref)
+        for ref in (dk_ref, dv_ref, *dkr_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     def step(masked):
-        k, v = k_ref[...].astype(p.product), v_ref[...].astype(p.product)
+        k, v = p.tile(k_ref, p.d), p.tile(v_ref, p.wv)
+        kr = p.rotary_key(kr_ref)
         seen = p.seen(i, j, True) if masked else None
-        dk, dv = dk_ref[...], dv_ref[...]
-        for h, cols in enumerate(p.heads()):
-            q = q_ref[:, cols].astype(p.product)
-            do = do_ref[:, cols].astype(p.product)
-            x = p.dot(k, q, _NT) * p.scale               # [keys, queries]
+        # a group's heads sum into its one key-value head; split heads
+        # have columns of their own, and sum into the one rotary key
+        if p.rope:
+            dkr = dkr_ref[0][...]
+        else:
+            dk, dv = dk_ref[...], dv_ref[...]
+        for h in range(p.r):
+            q = q_ref[:, p.cols(h, p.d)].astype(p.product)
+            do = do_ref[:, p.cols(h, p.wv)].astype(p.product)
+            x = p.dot(k(h), q, _NT)                      # [keys, queries]
+            if p.rope:
+                qr = qr_ref[:, p.cols(h, p.rope)].astype(p.product)
+                x = x + p.dot(kr, qr, _NT)
+            x = x * p.scale
             if masked:
                 x = jnp.where(seen, x, MASK)
             e = jnp.exp(x - lse_ref[h])
-            dv += p.dot(e, do, _NN)
-            dx = e * (p.dot(v, do, _NT) - delta_ref[h]) * p.scale
-            dk += p.dot(dx, q, _NN)
-        dk_ref[...], dv_ref[...] = dk, dv
+            if p.rope:
+                dv_ref[:, p.cols(h, p.wv)] += p.dot(e, do, _NN)
+            else:
+                dv += p.dot(e, do, _NN)
+            dx = e * (p.dot(v(h), do, _NT) - delta_ref[h]) * p.scale
+            if p.rope:  # rounded once, for its two products
+                dx = dx.astype(p.product)
+                dk_ref[:, p.cols(h, p.d)] += p.dot(dx, q, _NN)
+                dkr += p.dot(dx, qr, _NN)
+            else:
+                dk += p.dot(dx, q, _NN)
+        if p.rope:
+            dkr_ref[0][...] = dkr
+        else:
+            dk_ref[...], dv_ref[...] = dk, dv
 
     p.masked_or_not(i, j, i <= last, step)
 
@@ -387,27 +518,43 @@ def _specs(p: _Plan, t: int):
         first, last = q_tiles(j, tq, tk, window, nq)
         return jnp.minimum(first + s, last)
 
-    def slab(tile):                # [tq, R x d] of q, out, dq, dout
-        return pl.BlockSpec((None, tq, p.r * p.d), lambda *a: (
+    def slab(tile, width=p.d):     # [tq, R x width] of q, out, dq, dout
+        return pl.BlockSpec((None, tq, p.r * width), lambda *a: (
             a[0], tile(*a), a[1]))
 
-    def head(tile):                # [tk, d] of k, v, dk, dv
-        return pl.BlockSpec((None, tk, p.d), lambda *a: (
-            a[0], tile(*a), a[1]))
+    def head(tile, width=p.d):     # [tk, width] of k, v, dk, dv: one head's,
+        # or (split heads) the pack's R heads side by side
+        return pl.BlockSpec((None, tk, (p.r if p.rope else 1) * width),
+                            lambda *a: (a[0], tile(*a), a[1]))
 
     def rows(tile):                # [R, 1, tq] of lse, delta: along lanes
         return pl.BlockSpec((None, p.r, 1, tq), lambda *a: (
             a[0], a[1], 0, tile(*a)))
 
-    return dict(
-        sweep=sweep, q=slab(of_q), kv=head(kv_of_q), rows=rows(of_q),
-        k_twice=head(k_twice), v_second=head(v_second),
-        q_walked=slab(q_of_kv), kv_held=head(of_kv),
-        rows_walked=rows(q_of_kv),
+    def shared(tile):              # [tk, rope] of the one rotary key
+        return pl.BlockSpec((None, tk, p.rope), lambda *a: (
+            a[0], tile(*a), 0))
+
+    sp = dict(
+        sweep=sweep, rows=rows(of_q), rows_walked=rows(q_of_kv),
+        q=slab(of_q), o=slab(of_q, p.wv), q_walked=slab(q_of_kv),
+        o_walked=slab(q_of_kv, p.wv), kv=head(kv_of_q),
+        v=head(kv_of_q, p.wv), k_twice=head(k_twice),
+        v_second=head(v_second, p.wv), kv_held=head(of_kv),
+        v_held=head(of_kv, p.wv),
         # [R, tq, lanes] of the forward pass's log-sum-exp, one lane row a
         # query as the running statistics lie
         lse_out=pl.BlockSpec((None, p.r, tq, min(LANES, tk)), lambda *a: (
             a[0], a[1], a[2], 0)))
+    if p.rope:
+        sp.update(
+            qr=slab(of_q, p.rope), qr_walked=slab(q_of_kv, p.rope),
+            kr=shared(kv_of_q), kr_twice=shared(k_twice),
+            kr_held=shared(of_kv),
+            # a pack's sum into the shared key: [B, packs, T, rope]
+            dkr=pl.BlockSpec((None, None, tk, p.rope), lambda *a: (
+                a[0], a[1], a[2], 0)))
+    return sp
 
 
 def _call(kernel, name, p: _Plan, grid, steps, in_specs, out_specs,
@@ -424,28 +571,33 @@ def _call(kernel, name, p: _Plan, grid, steps, in_specs, out_specs,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
-        interpret=p.interpret, name=name)(*args)
+        interpret=p.interpret, name=p.name(name))(*args)
 
 
-def _forward(p: _Plan, q, k, v):
+def _forward(p: _Plan, q, k, v, rotary=()):
     """q [B, T, H x d], k and v [B, T, G x d], T whole tiles -> the output
-    [B, T, H x d] and the log-sum-exp [B, H, T]."""
+    [B, T, H x d] and the log-sum-exp [B, H, T]. Split heads: k and v
+    [B, T, H x d], ``rotary`` (q_pe [B, T, H x rope], k_pe [B, T, rope]),
+    the output [B, T, H x dv]."""
     from jax.experimental.pallas import tpu as pltpu
     b, t, _ = q.shape
     sp = _specs(p, t)
     lanes = min(LANES, p.tk)
+    heads = p.g * p.r
     out, lse = _call(
-        _fwd_kernel, "oktopk_flash_gqa_fwd", p, (b, p.g, t // p.tq),
-        sp["sweep"], [sp["q"], sp["k_twice"], sp["v_second"]],
-        [sp["q"], sp["lse_out"]],
-        [jax.ShapeDtypeStruct(q.shape, jnp.float32),
-         jax.ShapeDtypeStruct((b, p.g * p.r, t, lanes), jnp.float32)],
-        [pltpu.VMEM((p.r, p.tq, lanes), jnp.float32)] * 2, (q, k, v),
-        sweeps=2)
+        _fwd_kernel, "fwd", p, (b, p.g, t // p.tq), sp["sweep"],
+        [sp["q"], sp["k_twice"], sp["v_second"]]
+        + ([sp["qr"], sp["kr_twice"]] if rotary else []),
+        [sp["o"], sp["lse_out"]],
+        [jax.ShapeDtypeStruct((b, t, heads * p.wv), jnp.float32),
+         jax.ShapeDtypeStruct((b, heads, t, lanes), jnp.float32)],
+        [pltpu.VMEM((p.r, p.tq, lanes), jnp.float32)] * 2,
+        (q, k, v) + tuple(rotary), sweeps=2)
     return out, lse[..., 0]
 
 
-def _backward(p: _Plan, q, k, v, out, lse, dout):
+def _backward(p: _Plan, q, k, v, rotary, out, lse, dout):
+    """(dq, dk, dv, the rotary pair's gradients or ())."""
     b, t, _ = q.shape
     sp = _specs(p, t)
     nq, nk = t // p.tq, t // p.tk
@@ -454,41 +606,50 @@ def _backward(p: _Plan, q, k, v, out, lse, dout):
     # that sum but for p's own rounding in out
     bits = jnp.finfo(p.product)
     delta = jnp.sum((out * lax.reduce_precision(
-        dout, bits.nexp, bits.nmant)).reshape(b, t, p.g * p.r, p.d), axis=-1)
+        dout, bits.nexp, bits.nmant)).reshape(b, t, p.g * p.r, p.wv), axis=-1)
     delta = jnp.moveaxis(delta, 1, 2)[:, :, None]       # [B, H, 1, T]
     lse = lse[:, :, None]
 
-    dq = _call(
-        _dq_kernel, "oktopk_flash_gqa_dq", p, (b, p.g, nq), sp["sweep"],
-        [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["rows"], sp["rows"]],
-        sp["q"], jax.ShapeDtypeStruct(q.shape, jnp.float32), [],
-        (q, k, v, dout, lse, delta))
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, jnp.float32)
 
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, nq=nq), "oktopk_flash_gqa_dkv", p,
-        (b, p.g, nk),
+    dq = _call(
+        _dq_kernel, "dq", p, (b, p.g, nq), sp["sweep"],
+        [sp["q"], sp["kv"], sp["v"], sp["o"], sp["rows"], sp["rows"]]
+        + ([sp["qr"], sp["kr"]] if rotary else []),
+        [sp["q"], sp["qr"]] if rotary else sp["q"],
+        [like(q), like(rotary[0])] if rotary else like(q), [],
+        (q, k, v, dout, lse, delta) + tuple(rotary))
+
+    dkv = _call(
+        functools.partial(_dkv_kernel, nq=nq), "dkv", p, (b, p.g, nk),
         _longest(q_tiles(j, p.tq, p.tk, p.window, nq) for j in range(nk)),
-        [sp["q_walked"], sp["kv_held"], sp["kv_held"], sp["q_walked"],
-         sp["rows_walked"], sp["rows_walked"]],
-        [sp["kv_held"]] * 2,
-        [jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2, [],
-        (q, k, v, dout, lse, delta))
-    return dq, dk, dv
+        [sp["q_walked"], sp["kv_held"], sp["v_held"], sp["o_walked"],
+         sp["rows_walked"], sp["rows_walked"]]
+        + ([sp["qr_walked"], sp["kr_held"]] if rotary else []),
+        [sp["kv_held"], sp["v_held"]] + ([sp["dkr"]] if rotary else []),
+        [like(k), like(v)] + ([jax.ShapeDtypeStruct(
+            (b, p.g, t, p.rope), jnp.float32)] if rotary else []), [],
+        (q, k, v, dout, lse, delta) + tuple(rotary))
+    if not rotary:
+        return dq, *dkv, ()
+    # the packs' sums into the one shared key
+    return dq[0], dkv[0], dkv[1], (dq[1], jnp.sum(dkv[2], axis=1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash(p: _Plan, q, k, v):
-    return _forward(p, q, k, v)[0]
+def _flash(p: _Plan, q, k, v, rotary):
+    return _forward(p, q, k, v, rotary)[0]
 
 
-def _flash_fwd(p: _Plan, q, k, v):
-    out, lse = _forward(p, q, k, v)
+def _flash_fwd(p: _Plan, q, k, v, rotary):
+    out, lse = _forward(p, q, k, v, rotary)
     if p.save_as is not None:
         # both, or a layer recomputed from its saved names runs this
         # kernel again for the one it lacks
         out = checkpoint_name(out, p.save_as)
         lse = checkpoint_name(lse, p.save_as)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, rotary, out, lse)
 
 
 def _flash_bwd(p: _Plan, saved, dout):
@@ -504,6 +665,17 @@ def _product(interpret: bool):
     bfloat16 compiled for the chip, float32 where the interpreter stands in
     for a CPU."""
     return jnp.float32 if interpret else jnp.bfloat16
+
+
+def _padded(t: int, tq: int, tk: int, arrays):
+    """``arrays`` [B, T, ...] as [B, T in whole tiles, columns]."""
+    pad = -t % math.lcm(tq, tk)
+
+    def flat(x):
+        x = x.reshape(x.shape[0], t, -1)
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    return [flat(x) for x in arrays]
 
 
 def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
@@ -530,13 +702,35 @@ def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     tq, tk = tiles or tile_rule(t, h // g, d)
-    pad = -t % math.lcm(tq, tk)
-
-    def flat(x):
-        x = x.reshape(b, t, -1)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
-
     plan = _Plan(float(scale), window, tq, tk, g, h // g, d,
                  jnp.dtype(_product(interpret)).name, interpret, save_as)
-    out = _flash(plan, flat(q), flat(k), flat(v))
+    out = _flash(plan, *_padded(t, tq, tk, (q, k, v)), ())
     return out[:, :t].reshape(b, t, h, d)
+
+
+def flash_mla(q_nope, q_pe, k_nope, k_pe, v, scale: float, *,
+              save_as: Optional[str] = None,
+              interpret: Optional[bool] = None,
+              tiles: Optional[Tuple[int, int]] = None,
+              heads: Optional[int] = None):
+    """softmax((q_nope k_nope^T + q_pe k_pe^T) scale, causal) v with split
+    heads (module docstring): q_nope and k_nope [B, T, H, d], q_pe [B, T,
+    H, rope], k_pe [B, T, rope], v [B, T, H, dv] -> [B, T, H, dv] float32.
+    Differentiable in all five; ``k_pe``'s gradient is summed over the
+    heads. ``heads``: the heads that ride a grid step
+    (:func:`heads_a_step`'s where not given). The rest as
+    :func:`flash_gqa`."""
+    b, t, h, d = q_nope.shape
+    rope, dv = q_pe.shape[-1], v.shape[-1]
+    arrays = [x.astype(jnp.float32)
+              for x in (q_nope, k_nope, v, q_pe, k_pe)]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    r = heads or heads_a_step(h, rope)
+    tq, tk = tiles or tile_rule(t, r, d, dv, rope)
+    plan = _Plan(float(scale), None, tq, tk, h // r, r, d,
+                 jnp.dtype(_product(interpret)).name, interpret, save_as,
+                 dv, rope)
+    q, k, vv, qr, kr = _padded(t, tq, tk, arrays)
+    out = _flash(plan, q, k, vv, (qr, kr))
+    return out[:, :t].reshape(b, t, h, dv)

@@ -380,8 +380,10 @@ def snapshot() -> Dict[str, Any]:
       whether the built step reduces the bucket a leaf at a time (a dense
       bucket whose flat vector nothing reads: none is built or cut up
       again, optim/distributed.py) or flattens it (every other bucket);
-    - ``attention``: every distinct grouped-head attention call traced in
-      this process (``models/qwen3_next.blocked_causal_gqa``): ``kernel``,
+    - ``attention``: every distinct softmax-attention call traced in this
+      process, grouped heads (``models/qwen3_next.blocked_causal_gqa``) and
+      MLA's split heads (``models/deepseek_v2.blocked_causal_attention``)
+      alike: ``kernel``,
       whether the Pallas kernels of ``ops/flash_gqa.py`` run it or the
       blocked XLA form; its ``window`` (None: causal); ``tiles_visited``,
       the key tiles a sequence and head group visits, against

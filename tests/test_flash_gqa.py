@@ -402,3 +402,30 @@ def test_mosaic_compiles_the_three_kernels_at_the_benchmarks_widths(
         assert f"oktopk_flash_gqa_{kernel}" in text
     # no score block of [heads, queries, keys] is left in the program
     assert not re.search(rf"f32\[[\d,]*{min(t, 512)},\d{{4,}}\]", text)
+
+
+def test_mosaic_compiles_the_three_kernels_at_mlas_widths(one_chip):
+    """``dsv2lite_dense_x1``'s call: 4 sequences of 4,096 tokens, 16 split
+    heads of 128 + 64 | 128 and the one shared rotary key, at the rule's
+    heads a step and tiles."""
+    b, t, h, d, rope, dv = 4, 4096, 16, 128, 64, 128
+    shapes = [(b, t, h, d), (b, t, h, rope), (b, t, h, d), (b, t, rope),
+              (b, t, h, dv), (b, t, h, dv)]
+
+    def grads(q_nope, q_pe, k_nope, k_pe, v, w):
+        return jax.grad(lambda *x: jnp.sum(flash_gqa.flash_mla(
+            *x, (d + rope) ** -0.5, save_as=ATTN_OUT, interpret=False) * w),
+            (0, 1, 2, 3, 4))(q_nope, q_pe, k_nope, k_pe, v)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grads).lower(*[jax.ShapeDtypeStruct(
+            s, jnp.float32, sharding=one_chip) for s in shapes]
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"oktopk_flash_mla_{kernel}" in text
+    # no score block of [heads, queries, keys] is left in the program
+    assert not re.search(r"f32\[[\d,]*512,\d{4,}\]", text)
