@@ -44,6 +44,9 @@ BRANCH_COUNTERS = (
 MODEL_COUNTERS = (
     "expert_rows",      # token-expert pairs computed at held experts
     "expert_rows_max",  # ... at the busiest held expert of any layer
+    # 1,000 x the tokens' mean exit step, sum_t t p_t (a looped model:
+    # where the exit gate puts its mass, between 1,000 and 1,000 R)
+    "exit_step_milli_max",
 )
 COUNTERS = BRANCH_COUNTERS + ("local_k", "global_k") + MODEL_COUNTERS
 

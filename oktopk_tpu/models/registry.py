@@ -12,6 +12,7 @@ from oktopk_tpu.models.alexnet import AlexNet
 from oktopk_tpu.models.caffe_cifar import CaffeCifar
 from oktopk_tpu.models.densenet import DenseNet
 from oktopk_tpu.models.laguna import Laguna, LagunaConfig
+from oktopk_tpu.models.ouro import Ouro, OuroConfig
 from oktopk_tpu.models.preresnet import PreResNet
 from oktopk_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from oktopk_tpu.models.resnext import ResNeXt
@@ -35,9 +36,10 @@ def _tokens(t, vocab):
 
 
 # Token language models (next-token cross-entropy over ``tokens`` /
-# ``targets`` [B, T]; ``apply`` returns the logits first): the sequence
-# length and vocabulary of their data. The trainer's one branch for the
-# family and the synthetic data both read this.
+# ``targets`` [B, T]; ``apply`` returns the logits first, or, for a model
+# whose class says ``computes_loss``, takes the targets too and returns its
+# loss first): the sequence length and vocabulary of their data. The
+# trainer's one branch for the family and the synthetic data both read this.
 TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "lstm": (35, 10000),
     "lstm_tiny": (35, 1024),
@@ -49,6 +51,8 @@ TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "smallthinker_tiny": (64, 512),
     "laguna_xs2": (16384, 100352),
     "laguna_tiny": (64, 512),
+    "ouro_2_6b": (4096, 49152),
+    "ouro_tiny": (64, 512),
 }
 
 
@@ -108,6 +112,13 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
     "laguna_tiny": lambda **kw: (
         Laguna(LagunaConfig.tiny(**kw)),
         _tokens(*TOKEN_LMS["laguna_tiny"])),
+    # Ouro-2.6B at its published config.json (one stack run total_ut_steps
+    # times on the same weights); a chip's share of the depth comes as
+    # model_kwargs.
+    "ouro_2_6b": lambda **kw: (
+        Ouro(OuroConfig(**kw)), _tokens(64, 49152)),
+    "ouro_tiny": lambda **kw: (
+        Ouro(OuroConfig.tiny(**kw)), _tokens(*TOKEN_LMS["ouro_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

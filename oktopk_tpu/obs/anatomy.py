@@ -81,23 +81,26 @@ SUB_GLOBAL = "global"            # select: phase-(b) winner selection
 SUB_FEEDBACK = "feedback"        # select: controller feedback
 # ... and inside ``fwd_bwd``, entered by the model itself
 # (models/deepseek_v2.py, models/qwen3_next.py, models/smallthinker.py,
-# models/laguna.py), so forward, recomputed and backward operations alike
-# carry them: a model that enters none leaves the phase unscoped. Four lie
-# inside another, and a reader takes the innermost: ``delta_rule`` (the
-# chunked recurrence alone) inside ``linear_attention`` (its projections,
-# convolution, gates and norm); ``window_scores`` (a windowed layer's
+# models/laguna.py, models/ouro.py), so forward, recomputed and backward
+# operations alike carry them: a model that enters none leaves the phase
+# unscoped. Four lie inside another, and a reader takes the innermost:
+# ``delta_rule`` (the chunked recurrence alone) inside
+# ``linear_attention`` (its projections, convolution, gates and norm);
+# ``window_scores`` (a windowed layer's
 # scores, softmax and weighted sum alone) inside ``window_attention`` (its
 # projections, rotary and output projection); and, in models/laguna.py,
 # ``full_scores`` (a full layer's scores, softmax and weighted sum alone)
 # inside ``attention``, and ``attn_gate`` (the per-head output gate's
 # projection, sigmoid and product) inside ``attention`` or
-# ``window_attention``, whichever the layer is.
+# ``window_attention``, whichever the layer is. ``exit_gate`` is a looped
+# model's alone (models/ouro.py): the exit gate's product inside a block of
+# the head, and the exits' distribution, mixing and entropy after the loop.
 SUB_SCOPES = {
     "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
     "stage": (SUB_REPARTITION, SUB_FINALIZE),
     "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head",
                 "linear_attention", "delta_rule", "window_attention",
-                "window_scores", "full_scores", "attn_gate"),
+                "window_scores", "full_scores", "attn_gate", "exit_gate"),
 }
 
 # phases whose time is wire time; everything else in the contract is

@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import optax
 
+from oktopk_tpu.collectives.state import MODEL_COUNTERS
+
 
 def softmax_cross_entropy(logits, labels):
     """Mean CE over integer labels [B] (CNN classification). Logits cast
@@ -20,22 +22,35 @@ def softmax_cross_entropy(logits, labels):
 
 
 def lm_cross_entropy(logits, targets):
-    """Mean CE over [B, T] targets (PTB language modelling; perplexity =
-    exp(loss))."""
+    """Mean CE over [B, T] targets (perplexity = exp(loss)): the loss of
+    every token language model that hands back its logits, the PTB LSTM,
+    ``deepseek_v2``, ``qwen3_next``, ``smallthinker`` and ``laguna``. A
+    model that computes its own loss (``computes_loss``: ``models/ouro.py``,
+    four exits at a head too wide for whole logits) does not come through
+    here."""
     return optax.softmax_cross_entropy_with_integer_labels(
         logits.astype(jnp.float32), targets).mean()
 
 
 def model_counters(stats):
-    """What a token language model says of itself beside its logits, as the
-    loss function's ``{"counters": ...}`` in ``collectives/state.
-    MODEL_COUNTERS``' order: a routed-expert model gives ``{"expert_rows":
-    i32[expert layers, held experts]}``, the token-expert pairs each held
-    expert computed. ``{}`` for anything else (the LSTM's carry)."""
+    """What a token language model says of itself beside its logits or its
+    loss, as the loss function's ``{"counters": ...}`` in ``collectives/
+    state.MODEL_COUNTERS``' order. A routed-expert model gives
+    ``{"expert_rows": i32[expert layers, held experts]}``, the token-expert
+    pairs each held expert computed; a looped model
+    ``exit_step_milli_max`` under its own name. What a model does not
+    report stays 0. ``{}`` for anything that is no dict (the LSTM's
+    carry)."""
     if not isinstance(stats, dict):
         return {}
-    rows = stats["expert_rows"]
-    return {"counters": jnp.stack([jnp.sum(rows), jnp.max(rows, initial=0)])}
+    found = {k: stats[k] for k in MODEL_COUNTERS if k in stats}
+    if "expert_rows" in stats:
+        rows = stats["expert_rows"]
+        found.update(expert_rows=jnp.sum(rows),
+                     expert_rows_max=jnp.max(rows, initial=0))
+    return {"counters": jnp.stack([
+        jnp.asarray(found.get(k, 0)).astype(jnp.int32)
+        for k in MODEL_COUNTERS])}
 
 
 def ctc_loss(logits, logit_lengths, labels, label_lengths, blank_id: int = 0):

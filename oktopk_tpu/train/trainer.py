@@ -585,7 +585,7 @@ class Trainer:
                 # one program instead of an eager pass: on the chip each
                 # small operation of an eager pass is a compilation
                 init = jax.jit(init, static_argnames=("train",))
-            return init(rngs, batch["tokens"], train=False)
+            return init(rngs, *self._lm_inputs(batch), train=False)
         if self.cfg.dnn.startswith("bert"):
             return self.model.init(rngs, batch["input_ids"],
                                    batch["token_type_ids"],
@@ -616,6 +616,21 @@ class Trainer:
         return {"image": img,
                 "label": jnp.zeros((bs,), jnp.int32)}
 
+    @property
+    def _own_loss(self) -> bool:
+        """Does the token language model compute its own loss (its class
+        says so by ``computes_loss``: a loss that is not one cross-entropy
+        of one logits tensor, computed where the hidden state is)? It is
+        then called with the targets too and returns ``(loss, extra)``,
+        ``extra["eval_loss"]`` being what an evaluation reports."""
+        return getattr(self.model, "computes_loss", False)
+
+    def _lm_inputs(self, batch):
+        """What a token language model is called with."""
+        if self._own_loss:
+            return batch["tokens"], batch["targets"]
+        return (batch["tokens"],)
+
     def _loss_fn(self, params, model_state, batch, rng):
         dnn = self.cfg.dnn
         variables = {"params": params, **model_state}
@@ -623,13 +638,14 @@ class Trainer:
         rngs = {"dropout": rng}
 
         if dnn in TOKEN_LMS:
-            # every token language model: ``apply`` gives the logits and
-            # one thing more (the LSTM its carry, dropped here as ever; a
-            # routed-expert model a dict of what it counted)
-            (logits, extra), mut = self.model.apply(
-                variables, batch["tokens"], train=True, mutable=mutable,
-                rngs=rngs)
-            loss = losses.lm_cross_entropy(logits, batch["targets"])
+            # every token language model: ``apply`` gives the logits, or
+            # the model's own loss, and one thing more (the LSTM its carry,
+            # dropped here as ever; any other a dict of what it counted)
+            (out, extra), mut = self.model.apply(
+                variables, *self._lm_inputs(batch), train=True,
+                mutable=mutable, rngs=rngs)
+            loss = (out if self._own_loss
+                    else losses.lm_cross_entropy(out, batch["targets"]))
             return loss, (dict(mut), losses.model_counters(extra))
         if dnn.startswith("bert"):
             (mlm, nsp), mut = self.model.apply(
@@ -1014,9 +1030,10 @@ class Trainer:
         variables = {"params": params, **self.state.model_state}
         dnn = self.cfg.dnn
         if dnn in TOKEN_LMS:
-            logits, _ = self.model.apply(variables, batch["tokens"],
-                                         train=False)
-            loss = losses.lm_cross_entropy(logits, batch["targets"])
+            out, extra = self.model.apply(
+                variables, *self._lm_inputs(batch), train=False)
+            loss = (extra["eval_loss"] if self._own_loss
+                    else losses.lm_cross_entropy(out, batch["targets"]))
             return {"loss": loss, "ppl": jnp.exp(loss)}
         if dnn.startswith("bert"):
             mlm, nsp = self.model.apply(

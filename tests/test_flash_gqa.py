@@ -278,7 +278,8 @@ class TestWhichTiles:
 
     @pytest.mark.parametrize("t,r,d", [(16384, 7, 128), (8192, 8, 256),
                                        (64, 2, 32), (300, 1, 128),
-                                       (16384, 6, 128), (16384, 8, 128)])
+                                       (16384, 6, 128), (16384, 8, 128),
+                                       (4096, 1, 128)])
     def test_tile_rule_is_whole_lane_rows_inside_its_plan(self, t, r, d):
         tq, tk = flash_gqa.tile_rule(t, r, d)
         assert tq % 128 == 0 and tk % 128 == 0 and tk <= 512
@@ -371,12 +372,14 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# the benchmark's five call shapes: (B, T, H, G, d, window)
+# the benchmark's six call shapes: (B, T, H, G, d, window); the last is a
+# group of ONE head (models/ouro.py: 16 query heads over 16 key-value heads)
 CALLS = {"smallthinker_window": (1, 16384, 28, 4, 128, 4096),
          "smallthinker_global": (1, 16384, 28, 4, 128, None),
          "qwen3next_full": (2, 8192, 16, 2, 256, None),
          "laguna_full": (1, 16384, 48, 8, 128, None),
-         "laguna_sliding": (1, 16384, 64, 8, 128, 512)}
+         "laguna_sliding": (1, 16384, 64, 8, 128, 512),
+         "ouro_full": (2, 4096, 16, 16, 128, None)}
 
 
 @pytest.mark.parametrize("call", list(CALLS))

@@ -256,6 +256,9 @@ TRAINERS = {
                               batch_size=2, lr=0.05, compressor="dense",
                               grad_clip=1.0),
                          {"held_experts": [0, 1, 2, 3]}),
+    # a model that lives in a loop's body (one stack run four times)
+    "ouro_tiny": (dict(dnn="ouro_tiny", dataset="ptb", batch_size=2,
+                       lr=0.05, compressor="dense", grad_clip=1.0), {}),
 }
 
 
@@ -302,6 +305,25 @@ class TestCompiledStep:
         _, _, _, _, got = built
         none = sum(o.how == "none" for o in got.values())
         assert none < 0.05 * len(got)
+
+    def test_a_loop_bodys_instructions_carry_their_sub_scope(self, built):
+        """``ouro_tiny``: the layers are instructions of a ``while``'s body
+        computation; their own ``op_name`` gives the sub-scope, and what
+        the compiler put beside them in the body inherits one."""
+        tr, _, _, text, got = built
+        if tr.cfg.dnn != "ouro_tiny":
+            pytest.skip("the model of the other trainers is no loop's body")
+        _, insts, caller_of = anatomy._parse_hlo(text)
+        loops = {comp for comp, caller in caller_of.items()
+                 if insts[caller].opcode == "while"}
+        assert len(loops) >= 2      # forward and backward, body and condition
+        inside = [n for n, i in insts.items() if i.computation in loops]
+        subs = {got[n].sub for n in inside if got[n].phase == "fwd_bwd"}
+        assert subs >= {"attention", "full_scores", "mlp", "head",
+                        "exit_gate"}
+        assert sum(got[n].how == "none" for n in inside) == 0
+        owned = [n for n in inside if got[n].how != "own"]
+        assert owned and all(got[n].phase for n in owned)
 
 
 # (instruction, start, end) in seconds: a loop that spans two leaves and a
