@@ -29,8 +29,12 @@ passes in the loop's own carry.
 
 **Attention** is ``qwen3_next.blocked_causal_gqa`` at a group of ONE head
 (``num_key_value_heads`` = ``num_attention_heads``): on a TPU the flash
-kernels of ``ops/flash_gqa.py``, anywhere else the blocked XLA form. Its
-output is named ``ATTN_OUT`` there. Rotary is ``laguna.rotary_table`` of a
+kernels of ``ops/flash_gqa.py``, whose grid step takes eight of the 16
+key-value heads side by side with their eight query heads (a head that
+shares its key tile with no other still shares a step's cost:
+``flash_gqa.kv_heads_a_step``, from the shapes), anywhere else the blocked
+XLA form. Its output is named ``ATTN_OUT`` there. Rotary is
+``laguna.rotary_table`` of a
 plain record on all of a head's dims.
 
 **Recomputation** as ``models/laguna.py``: a decoder layer is recomputed in

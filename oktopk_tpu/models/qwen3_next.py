@@ -196,7 +196,7 @@ def blocked_causal_gqa(q, k, v, scale: float, block: int,
     block = min(block, t)
     if window is not None and window >= t:
         window = None   # every key at or before a query is in its window
-    if flash_gqa.on_this_platform(t, q.shape[2] // k.shape[2], q.shape[3],
+    if flash_gqa.on_this_platform(t, q.shape[2], k.shape[2], q.shape[3],
                                   window, block):
         return flash_gqa.flash_gqa(q, k, v, scale, window, save_as=ATTN_OUT)
     return _blocked_xla(q, k, v, scale, block, window)

@@ -17,23 +17,33 @@ head's own d-wide key and ``q_pe k_pe^T`` over a ``rope``-wide rotary key
 that is ONE head shared by all; values are ``dv`` wide; no two query heads
 share a key-value head. Nothing is packed or padded for it: the five
 arrays are read as they lie, the score width and the value width are told
-apart (:class:`_Plan`), and the rotary part is a second score term. Where
-grouped heads put the R query heads of one key-value head on a grid step,
-split heads put R heads WITH their R key-value column blocks on one
-(:func:`heads_a_step`): a step's mask and its [tk, rope] tile of the
-shared key are built and fetched once for the R of them, and the rotary
-slab [tq, R x rope] is whole lane rows. The rotary key's gradient is
-summed over a step's heads in the kernel and over the steps' packs after
-it. At T = 4,096 the plain form's score blocks were 45 % of a step
-(PERF.md, Findings, PR 45).
+apart (:class:`_Plan`), and the rotary part is a second score term. A grid
+step takes R heads WITH their R key-value column blocks (the pack of the
+next paragraph, :func:`heads_a_step`): a step's mask and its [tk, rope]
+tile of the shared key are built and fetched once for the R of them, and
+the rotary slab [tq, R x rope] is whole lane rows. The rotary key's
+gradient is summed over a step's heads in the kernel and over the steps'
+packs after it. At T = 4,096 the plain form's score blocks were 45 % of a
+step (PERF.md, Findings, PR 45).
+
+**What rides a grid step** is a PACK of ``own`` key-value heads side by
+side and the ``own x R`` query heads that read them, query head h of the
+pack reading columns ``h // R`` of the pack's tile. ``own`` comes from the
+call's shapes (:func:`kv_heads_a_step`): 1 where a key-value head's own
+group fills a step (R = 6, 7, 8: the group's one tile is loaded once and
+its R heads share it), several where a group is small, because a head that
+shares its key tile with no other still shares a step's own cost, its mask
+and the steps past a tile's run; at a group of ONE head and one head a
+step those were over a quarter of the kernels' time (PERF.md, Findings,
+PR 47). MLA is the pack ``own = R`` with a rotary part.
 
 Here a tile of scores lives in VMEM from its product to its use:
 
 * **No copy of q, k, v or the output is made.** The arrays are read as
-  they lie, [B, T, heads x d]: a grid step takes the [tq, R x d] slab of
-  the R query heads that share a key-value head and one [tk, d] tile of
-  that head's keys and values, and walks the R heads in the kernel. A
-  key-value tile is fetched once a group and query tile, not once a head.
+  they lie, [B, T, heads x d]: a grid step takes the [tq, own x R x d]
+  slab of a pack's query heads and one [tk, own x d] tile of its keys and
+  of its values, and walks the heads in the kernel. A key-value tile is
+  fetched once a group and query tile, not once a head.
 * **Work follows the band.** Query tile i visits the key tiles
   ``first .. last`` of :func:`kv_tiles` and no other is fetched: the grid's
   innermost extent is the longest such run, a step past a tile's run
@@ -51,7 +61,8 @@ Here a tile of scores lives in VMEM from its product to its use:
   ``oktopk_flash_gqa_dkv`` holds a key tile, walks the query tiles that see
   it (:func:`q_tiles`) with the scores TRANSPOSED ([keys, queries]: the
   row statistics lie along lanes and every product is a plain one) and
-  sums the R heads of the group into dk and dv. One fused kernel would
+  sums the R heads of a group into its key-value head's dk and dv (in a
+  pack: that head's columns). One fused kernel would
   need dq (58 MB a group at T = 16,384) resident or written once a key
   tile; two kernels cost two products more.
 * **Precision**: what the plain form's ``einsum`` is on this chip at JAX's
@@ -91,6 +102,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 LANES = 128
+# keys a tile, and the most queries (:func:`tile_rule`)
+TILE = 512
 # what a masked score reads: finite, so that a row whose first visited tile
 # holds none of its keys gives exp(0) and no NaN; the first tile that holds
 # one wipes that with exp(MASK - max) = 0 exactly (every row sees its own
@@ -159,8 +172,9 @@ _calls = {}
 
 
 def calls():
-    """``[{"kernel", "window", "tiles_visited", "tiles_causal"}, ...]``, an
-    entry a distinct call shape, in the order first traced."""
+    """``[{"kernel", "window", "tiles_visited", "tiles_causal",
+    "kv_heads_a_step"}, ...]``, an entry a distinct call shape, in the
+    order first traced."""
     return [dict(c) for c in _calls.values()]
 
 
@@ -176,25 +190,30 @@ def _kernels_here(*lanes: int) -> bool:
         and all(n % LANES == 0 for n in lanes))
 
 
-def _record(kernel: bool, t: int, window: Optional[int], tq: int, tk: int,
-            *shape) -> bool:
+def _record(kernel: bool, own: int, t: int, window: Optional[int], tq: int,
+            tk: int, *shape) -> bool:
+    """``own``: the key-value heads that ride a grid step of the kernels;
+    the plain form has no grid and says 0."""
     visited, causal = tile_counts(t, tq, tk, window)
     _calls.setdefault((kernel, t, *shape, window, tq, tk), {
         "kernel": kernel, "window": window, "tiles_visited": visited,
-        "tiles_causal": causal})
+        "tiles_causal": causal, "kv_heads_a_step": own if kernel else 0})
     return kernel
 
 
-def on_this_platform(t: int, r: int, d: int, window: Optional[int],
+def on_this_platform(t: int, h: int, g: int, d: int, window: Optional[int],
                      block: int) -> bool:
-    """Whether the kernels run a grouped-head call of this shape here
-    (:func:`_kernels_here`: a head is whole lane rows, a tile of k being
-    [tk, d] of [T, G x d], which Mosaic cuts by 128 lanes), and the call's
-    record. Otherwise the caller's plain form does, ``block`` queries at a
-    time against ``block``-wide key tiles in the record."""
+    """Whether the kernels run a call of ``h`` query heads grouped over
+    ``g`` key-value heads here (:func:`_kernels_here`: a head is whole lane
+    rows, a tile of k being [tk, own x d] of [T, G x d], which Mosaic cuts
+    by 128 lanes), and the call's record. Otherwise the caller's plain form
+    does, ``block`` queries at a time against ``block``-wide key tiles in
+    the record."""
     kernel = _kernels_here(d)
-    tq, tk = tile_rule(t, r, d) if kernel else (block, block)
-    return _record(kernel, t, window, tq, tk, r, d)
+    own = kv_heads_a_step(h, g, d)
+    tq, tk = (tile_rule(t, own * (h // g), d, own=own) if kernel
+              else (block, block))
+    return _record(kernel, own, t, window, tq, tk, h, g, d)
 
 
 def split_on_this_platform(t: int, heads: int, d: int, rope: int, dv: int,
@@ -205,12 +224,17 @@ def split_on_this_platform(t: int, heads: int, d: int, rope: int, dv: int,
     step (or it is all heads')."""
     r = heads_a_step(heads, rope)
     kernel = _kernels_here(d, dv, 0 if r == heads else r * rope)
-    tq, tk = tile_rule(t, r, d, dv, rope) if kernel else (block, block)
-    return _record(kernel, t, None, tq, tk, heads, d, rope, dv)
+    tq, tk = (tile_rule(t, r, d, dv, rope, own=r) if kernel
+              else (block, block))
+    return _record(kernel, r, t, None, tq, tk, heads, d, rope, dv)
 
 
-# the most split heads that ride one grid step (PERF.md, Findings, PR 45)
+# the most heads that ride one grid step (PERF.md, Findings, PR 45), and the
+# float32 query slab [tq, heads x d] at which a step fell off a cliff in
+# PR 43 ([1,024, 2,048]) and in PR 45 ([512, 16 x 128] beside MLA's rotary
+# slab): a pack stays under it (alone such a slab ran, PR 47: left as it is)
 HEADS_A_STEP = 8
+SLAB_CLIFF = 4 * 2 ** 20
 
 
 def heads_a_step(heads: int, rope: int) -> int:
@@ -224,21 +248,35 @@ def heads_a_step(heads: int, rope: int) -> int:
     return max(fit, default=heads)
 
 
-def tile_rule(t: int, r: int, d: int, dv: int = 0,
-              rope: int = 0) -> Tuple[int, int]:
+def kv_heads_a_step(h: int, g: int, d: int) -> int:
+    """Grouped heads: the key-value heads that ride one grid step, each
+    with its R = h / g query heads. One where its own group fills a step;
+    where groups are small, as many as divide ``g``, put no more than
+    ``HEADS_A_STEP`` query heads on the step, keep their slab at ``TILE``
+    queries under ``SLAB_CLIFF`` and their tile [tk, own x d] whole lane
+    rows. From the call's shapes alone."""
+    r = h // g
+    fit = [own for own in range(2, HEADS_A_STEP // r + 1)
+           if g % own == 0 and (own * d) % LANES == 0
+           and TILE * own * r * d * 4 < SLAB_CLIFF]
+    return max(fit, default=1)
+
+
+def tile_rule(t: int, r: int, d: int, dv: int = 0, rope: int = 0,
+              own: int = 1) -> Tuple[int, int]:
     """(tq, tk): 512 keys a tile (four lane rows of scores; fewer where the
     sequence is shorter), and as many queries, halved while what a step
     keeps in VMEM passes ``VMEM_PLAN``: the float32 slabs of q, the output
-    and their cotangents ([tq, R x d], two buffers each), the key-value
-    tiles and their gradients, the running statistics and a few score
-    tiles. On a v5e (512, 512) was the fastest of seven sizes from 256 to
+    and their cotangents ([tq, r x d] for the ``r`` query heads of a step,
+    two buffers each), the key-value tiles of its ``own`` key-value heads
+    and their gradients, the running statistics and a few score tiles.
+    On a v5e (512, 512) was the fastest of seven sizes from 256 to
     1,024 in all three of the benchmark's call shapes, forward and
     backward (PERF.md, Findings, PR 43). ``dv``, ``rope``: split heads
-    (:class:`_Plan`), whose key-value tiles are R heads wide."""
-    tk = min(512, -(-t // LANES) * LANES)
+    (:class:`_Plan`)."""
+    tk = min(TILE, -(-t // LANES) * LANES)
     tq = tk
     dv = dv or d
-    own = r if rope else 1      # key-value heads a step
 
     def planned(tq):
         slabs = tq * r * (d + rope + 2 * dv) * 4
@@ -259,8 +297,8 @@ class _Plan(NamedTuple):
     window: Optional[int]
     tq: int
     tk: int
-    g: int                  # grid groups: key-value heads, or packs of heads
-    r: int                  # query heads a group: a grid step walks them
+    g: int                  # grid groups: packs of key-value heads
+    r: int                  # query heads a pack: a grid step walks them
     d: int                  # a head's score width (split heads: without
     # the rotary part)
     product: str            # the type a product's operands are rounded to
@@ -269,6 +307,8 @@ class _Plan(NamedTuple):
     dv: int = 0             # a head's value width; 0: the score width
     rope: int = 0           # split heads (module docstring): the width of the
     # rotary part whose key is one head shared by all; 0: grouped heads
+    own: int = 1            # key-value heads a pack: query head h of a step
+    # reads the pack's key-value head h // (r / own)
 
     @property
     def wv(self) -> int:
@@ -301,14 +341,19 @@ class _Plan(NamedTuple):
         """Head ``h``'s columns of a slab or a pack ``width`` wide a head."""
         return slice(h * width, (h + 1) * width)
 
+    def kv_cols(self, h: int, width: int):
+        """The columns of query head ``h``'s key-value head in the pack's
+        tile of keys, values or their gradients."""
+        return self.cols(h * self.own // self.r, width)
+
     def tile(self, ref, width: int):
         """head -> its [tk, width] tile of keys or values, rounded for a
         product: the group's one tile, loaded once before the heads are
-        walked, or (split heads) the head's own columns of the pack's."""
-        if not self.rope:
+        walked, or its key-value head's columns of the pack's."""
+        if self.own == 1:
             whole = ref[...].astype(self.product)
             return lambda h: whole
-        return lambda h: ref[:, self.cols(h, width)].astype(self.product)
+        return lambda h: ref[:, self.kv_cols(h, width)].astype(self.product)
 
     def rotary_key(self, ref):
         """The one [tk, rope] tile of the shared rotary key, rounded for a
@@ -450,11 +495,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         k, v = p.tile(k_ref, p.d), p.tile(v_ref, p.wv)
         kr = p.rotary_key(kr_ref)
         seen = p.seen(i, j, True) if masked else None
-        # a group's heads sum into its one key-value head; split heads
-        # have columns of their own, and sum into the one rotary key
+        # a group's heads sum into their key-value head: where a step
+        # holds one, into values written once; in a pack, into that head's
+        # columns. Split heads sum into the one rotary key besides
+        packed = p.own > 1
         if p.rope:
             dkr = dkr_ref[0][...]
-        else:
+        if not packed:
             dk, dv = dk_ref[...], dv_ref[...]
         for h in range(p.r):
             q = q_ref[:, p.cols(h, p.d)].astype(p.product)
@@ -467,20 +514,22 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             if masked:
                 x = jnp.where(seen, x, MASK)
             e = jnp.exp(x - lse_ref[h])
-            if p.rope:
-                dv_ref[:, p.cols(h, p.wv)] += p.dot(e, do, _NN)
+            if packed:
+                dv_ref[:, p.kv_cols(h, p.wv)] += p.dot(e, do, _NN)
             else:
                 dv += p.dot(e, do, _NN)
             dx = e * (p.dot(v(h), do, _NT) - delta_ref[h]) * p.scale
             if p.rope:  # rounded once, for its two products
                 dx = dx.astype(p.product)
-                dk_ref[:, p.cols(h, p.d)] += p.dot(dx, q, _NN)
-                dkr += p.dot(dx, qr, _NN)
+            if packed:
+                dk_ref[:, p.kv_cols(h, p.d)] += p.dot(dx, q, _NN)
             else:
                 dk += p.dot(dx, q, _NN)
+            if p.rope:
+                dkr += p.dot(dx, qr, _NN)
         if p.rope:
             dkr_ref[0][...] = dkr
-        else:
+        if not packed:
             dk_ref[...], dv_ref[...] = dk, dv
 
     p.masked_or_not(i, j, i <= last, step)
@@ -518,13 +567,13 @@ def _specs(p: _Plan, t: int):
         first, last = q_tiles(j, tq, tk, window, nq)
         return jnp.minimum(first + s, last)
 
-    def slab(tile, width=p.d):     # [tq, R x width] of q, out, dq, dout
+    def slab(tile, width=p.d):     # [tq, r x width] of q, out, dq, dout
         return pl.BlockSpec((None, tq, p.r * width), lambda *a: (
             a[0], tile(*a), a[1]))
 
-    def head(tile, width=p.d):     # [tk, width] of k, v, dk, dv: one head's,
-        # or (split heads) the pack's R heads side by side
-        return pl.BlockSpec((None, tk, (p.r if p.rope else 1) * width),
+    def head(tile, width=p.d):     # [tk, own x width] of k, v, dk, dv: the
+        # pack's key-value heads side by side
+        return pl.BlockSpec((None, tk, p.own * width),
                             lambda *a: (a[0], tile(*a), a[1]))
 
     def rows(tile):                # [R, 1, tq] of lse, delta: along lanes
@@ -680,7 +729,9 @@ def _padded(t: int, tq: int, tk: int, arrays):
 
 def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
               save_as: Optional[str] = None,
-              interpret: Optional[bool] = None, tiles: Optional[Tuple[int, int]] = None):
+              interpret: Optional[bool] = None,
+              tiles: Optional[Tuple[int, int]] = None,
+              heads: Optional[int] = None):
     """softmax(q k^T scale, causal and inside ``window``) v with grouped
     heads: q [B, T, H, d], k and v [B, T, G, d] -> [B, T, H, d] float32
     (a narrower q, k or v is widened to float32 first). Differentiable in
@@ -689,7 +740,9 @@ def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
     layer is recomputed from named values. ``interpret``: left out, the
     kernels are compiled on a TPU backend and interpreted off one (a test
     that compiles for a described chip says False). ``tiles`` (tq, tk) is
-    :func:`tile_rule`'s where not given (tests give small ones).
+    :func:`tile_rule`'s where not given (tests give small ones), and
+    ``heads``, the key-value heads that ride a grid step,
+    :func:`kv_heads_a_step`'s (tests force one).
     A product's operands are rounded as :func:`_product` says."""
     b, t, h, d = q.shape
     g = k.shape[2]
@@ -701,9 +754,12 @@ def flash_gqa(q, k, v, scale: float, window: Optional[int] = None, *,
         window = None
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    tq, tk = tiles or tile_rule(t, h // g, d)
-    plan = _Plan(float(scale), window, tq, tk, g, h // g, d,
-                 jnp.dtype(_product(interpret)).name, interpret, save_as)
+    own = heads or kv_heads_a_step(h, g, d)
+    r = own * (h // g)
+    tq, tk = tiles or tile_rule(t, r, d, own=own)
+    plan = _Plan(float(scale), window, tq, tk, g // own, r, d,
+                 jnp.dtype(_product(interpret)).name, interpret, save_as,
+                 own=own)
     out = _flash(plan, *_padded(t, tq, tk, (q, k, v)), ())
     return out[:, :t].reshape(b, t, h, d)
 
@@ -727,10 +783,10 @@ def flash_mla(q_nope, q_pe, k_nope, k_pe, v, scale: float, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     r = heads or heads_a_step(h, rope)
-    tq, tk = tiles or tile_rule(t, r, d, dv, rope)
+    tq, tk = tiles or tile_rule(t, r, d, dv, rope, own=r)
     plan = _Plan(float(scale), None, tq, tk, h // r, r, d,
                  jnp.dtype(_product(interpret)).name, interpret, save_as,
-                 dv, rope)
+                 dv, rope, own=r)
     q, k, vv, qr, kr = _padded(t, tq, tk, arrays)
     out = _flash(plan, q, k, vv, (qr, kr))
     return out[:, :t].reshape(b, t, h, dv)

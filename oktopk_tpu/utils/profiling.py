@@ -387,7 +387,9 @@ def snapshot() -> Dict[str, Any]:
       whether the Pallas kernels of ``ops/flash_gqa.py`` run it or the
       blocked XLA form; its ``window`` (None: causal); ``tiles_visited``,
       the key tiles a sequence and head group visits, against
-      ``tiles_causal``, what the causal triangle holds (static, from
+      ``tiles_causal``, what the causal triangle holds;
+      ``kv_heads_a_step``, the key-value heads that ride one grid step of
+      the kernels with their query heads (0: the XLA form) (static, from
       shapes); empty for a model that has no such layer;
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.

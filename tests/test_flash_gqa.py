@@ -52,6 +52,15 @@ NARROW = {
     "window_under_a_tile": MASKS["window_under_a_tile"],
     "causal": MASKS["causal"],
 }
+# small groups, whose key-value heads ride a grid step several at a time:
+# (R, G) -> the pack that ``kv_heads_a_step`` gives at d = 128
+PACKS = {(1, 16): 8, (1, 3): 3, (2, 4): 4, (3, 4): 2, (1, 9): 3}
+
+
+def through(fn, q, k, v, w):
+    """(out, dq, dk, dv) of ``fn`` under the cotangent ``w``."""
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(w)
 
 
 class TestKernelsAgainstTheBlockFunction:
@@ -78,6 +87,49 @@ class TestKernelsAgainstTheBlockFunction:
         for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
             np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
                                        err_msg=name)
+
+    @pytest.mark.parametrize("mask", ["causal", "window_under_t"])
+    @pytest.mark.parametrize("r,g", list(PACKS))
+    def test_packs_of_key_value_heads(self, r, g, mask):
+        """Where a group is small a grid step takes several key-value heads
+        with their query heads: a group of ONE head (ouro's 16 over 16; 3
+        and 9, which 8 does not divide), and groups of 2 and 3 whose heads
+        sum into their key-value head's columns of the pack's dk and dv."""
+        t, tiles, window = MASKS[mask]
+        q, k, v, w = inputs(2, t, r, 128, g=g)
+        assert flash_gqa.kv_heads_a_step(r * g, g, 128) == PACKS[r, g]
+        got = through(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, 0.09, window, interpret=True, tiles=tiles), q, k, v, w)
+        want = through(lambda q, k, v: oracle(q, k, v, 0.09, window),
+                       q, k, v, w)
+        for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("t,tiles,window", [
+        (32, (8, 16), None), (32, (8, 16), 11), (48, (16, 16), 20)])
+    @pytest.mark.parametrize("g,d", [(16, 128), (3, 128), (9, 128),
+                                     (16, 256)])
+    def test_a_pack_at_a_group_of_one_is_one_head_a_step_to_the_last_bit(
+            self, g, d, t, tiles, window):
+        """The same products on the same operands in the same order over a
+        head's key tiles, and at R = 1 no sum crosses heads: the output and
+        the three gradients of the pack are the numbers of the same call
+        forced to one key-value head a grid step. (Key tiles of 16: at 8,
+        under one vector of this CPU, XLA:CPU's own code for the interpreted
+        kernel parts by 1-4 ulp in a few rows with the fusion it lands in;
+        from 16 on, 48 geometries read equal.)"""
+        q, k, v, w = inputs(2, t, 1, d, g=g)
+        assert flash_gqa.kv_heads_a_step(g, g, d) > 1
+
+        def both(q, k, v, w):
+            return [through(lambda q, k, v: flash_gqa.flash_gqa(
+                q, k, v, d ** -0.5, window, interpret=True, tiles=tiles,
+                heads=heads), q, k, v, w) for heads in (None, 1)]
+
+        packed, one = jax.jit(both)(q, k, v, w)
+        for name, a, e in zip(("out", "dq", "dk", "dv"), packed, one):
+            np.testing.assert_array_equal(a, e, err_msg=name)
 
     @pytest.mark.parametrize("r", [6, 8])
     @pytest.mark.parametrize("mask", list(NARROW))
@@ -300,14 +352,20 @@ class TestSnapshot:
         assert profiling.snapshot()["attention"] == [
             {"kernel": False, "window": 24,
              "tiles_visited": flash_gqa.tile_counts(64, 16, 16, 24)[0],
-             "tiles_causal": 10},
+             "tiles_causal": 10, "kv_heads_a_step": 0},
             {"kernel": False, "window": None, "tiles_visited": 10,
-             "tiles_causal": 10}]
+             "tiles_causal": 10, "kv_heads_a_step": 0}]
         monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
         qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
         assert profiling.snapshot()["attention"][-1] == {
             "kernel": True, "window": 24, "tiles_visited": 1,
-            "tiles_causal": 1}
+            "tiles_causal": 1, "kv_heads_a_step": 1}
+        # a group of one head over four key-value heads: the four ride a step
+        q, k, v, _ = inputs(1, 64, 1, 128, g=4)
+        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16)
+        assert profiling.snapshot()["attention"][-1] == {
+            "kernel": True, "window": None, "tiles_visited": 1,
+            "tiles_causal": 1, "kv_heads_a_step": 4}
 
     def test_empty_without_such_a_layer(self, fresh_calls):
         assert profiling.snapshot()["attention"] == []
@@ -380,6 +438,40 @@ CALLS = {"smallthinker_window": (1, 16384, 28, 4, 128, 4096),
          "laguna_full": (1, 16384, 48, 8, 128, None),
          "laguna_sliding": (1, 16384, 64, 8, 128, 512),
          "ouro_full": (2, 4096, 16, 16, 128, None)}
+
+
+@pytest.mark.parametrize("call", list(CALLS))
+def test_key_value_heads_a_step_at_the_benchmarks_calls(call):
+    """A group of six, seven or eight query heads fills a grid step: one
+    key-value head a step, the program those cells ran before there were
+    packs. ouro's group of one head: eight key-value heads a step, two
+    packs a sequence. Either way the rule's tiles, with no halving (inside
+    ``VMEM_PLAN``), and a tile of k whole lane rows."""
+    _, t, h, g, d, _ = CALLS[call]
+    own = flash_gqa.kv_heads_a_step(h, g, d)
+    assert own == (8 if call == "ouro_full" else 1)
+    assert g % own == 0 and (own * d) % flash_gqa.LANES == 0
+    assert own == 1 or own * (h // g) <= flash_gqa.HEADS_A_STEP
+    assert flash_gqa.tile_rule(t, own * (h // g), d, own=own) == (512, 512)
+
+
+@pytest.mark.parametrize("h,g,d,want", [
+    (16, 16, 256, 4),       # a slab of 8 x 256 at 512 queries is the cliff
+    (8, 4, 128, 4), (12, 4, 128, 2), (9, 9, 128, 3), (3, 3, 128, 3),
+    (5, 5, 128, 5), (7, 7, 128, 7), (11, 11, 128, 1), (64, 32, 128, 4),
+    (16, 16, 64, 8), (6, 6, 64, 6), (3, 3, 64, 1), (4, 2, 32, 1),
+    (40, 8, 128, 1), (16, 1, 128, 1)])
+def test_key_value_heads_a_step_from_the_shapes(h, g, d, want):
+    """The largest divisor of G whose query heads are no more than
+    ``HEADS_A_STEP``, whose slab stays under the cliff and whose tile is
+    whole lane rows; one where there is none."""
+    own = flash_gqa.kv_heads_a_step(h, g, d)
+    assert own == want and g % own == 0
+    if own > 1:
+        assert own * (h // g) <= flash_gqa.HEADS_A_STEP
+        assert (own * d) % flash_gqa.LANES == 0
+        assert (flash_gqa.TILE * own * (h // g) * d * 4
+                < flash_gqa.SLAB_CLIFF)
 
 
 @pytest.mark.parametrize("call", list(CALLS))

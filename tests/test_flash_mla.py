@@ -151,7 +151,7 @@ class TestRules:
         tiles, inside the plan; 36 of the triangle's 36 tiles."""
         r = flash_gqa.heads_a_step(16, 64)
         assert 16 % r == 0 and (r * 64) % 128 == 0
-        tq, tk = flash_gqa.tile_rule(4096, r, 128, 128, 64)
+        tq, tk = flash_gqa.tile_rule(4096, r, 128, 128, 64, own=r)
         assert tq % 128 == 0 and tk % 128 == 0 and tk <= 512
         visited, causal = flash_gqa.tile_counts(4096, tq, tk, None)
         assert visited == causal
@@ -179,12 +179,12 @@ def test_the_call_is_recorded(fresh_calls, monkeypatch):
         ds.blocked_causal_attention(*x, 0.2, 16)
     assert profiling.snapshot()["attention"] == [
         {"kernel": False, "window": None, "tiles_visited": 10,
-         "tiles_causal": 10}]
+         "tiles_causal": 10, "kv_heads_a_step": 0}]
     monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
     ds.blocked_causal_attention(*x, 0.2, 16)
     assert profiling.snapshot()["attention"][-1] == {
         "kernel": True, "window": None, "tiles_visited": 1,
-        "tiles_causal": 1}
+        "tiles_causal": 1, "kv_heads_a_step": flash_gqa.heads_a_step(4, 8)}
 
 
 class TestTheModelThroughTheKernels:
@@ -222,12 +222,13 @@ class TestTheModelThroughTheKernels:
         assert "oktopk_flash_gqa" not in text
 
 
-# ---- the grouped-head programs are the parent's ----------------------------
+# ---- the programs that had to stay are the parent's -------------------------
 
-# the benchmark's five grouped-head call shapes, (B, T, H, G, d, window), and
-# the digest of each one's forward and backward lowered for the TPU platform,
-# recorded from a checkout of the parent commit 1dc7418 by
-# ``lowered_digest`` below
+# the benchmark's five call shapes with six to eight query heads a group,
+# (B, T, H, G, d, window), and the digest of each one's forward and backward
+# lowered for the TPU platform, recorded from a checkout of the parent
+# commit 1dc7418 of PR 45 by ``lowered_digest`` below; PR 47's parent
+# c1f60e8 reads the same five
 GROUPED = {
     "smallthinker_window": ((1, 16384, 28, 4, 128, 4096), 
         "ff954608def0c7be84a22dfe956ed72a11b580a2343663f0d7b36970f9b3b147"),
@@ -240,16 +241,24 @@ GROUPED = {
     "laguna_sliding": ((1, 16384, 64, 8, 128, 512), 
         "ea95e30657b1d0d300eb191424a2f477d55db3b4d42e7f427cfbdd348dd61d6e"),
 }
+# recorded from a checkout of c1f60e8, PR 47's parent, by the same lines:
+# ``dsv2lite_dense_x1``'s call (B, T, heads, d, rope, dv), and
+# ``ouro_dense_x1``'s, a group of ONE head, as it was at one key-value head
+# a grid step
+MLA = ((4, 4096, 16, 128, 64, 128), 
+       "2a39a05ea1906e9c07333c904c8fc43c7b955d1564ca0a8801d3f26c8bf48365")
+OURO = ((2, 4096, 16, 16, 128, None), 
+        "e82a4bab2847258507c30695fc88777082afc6a61877043b7535e94a513859e2")
 
 
-def lowered_digest(b, t, h, g, d, window, monkeypatch):
-    """SHA-256 of ``flash_gqa``'s forward and backward at one call shape,
-    lowered for the TPU platform (no chip, nothing compiled): the StableHLO
-    text, and each kernel's Mosaic module WITHOUT its source locations in
-    place of the serialized body, which embeds the call stack's line
-    numbers and so changes with any line added above a kernel."""
+def lowered_digest(grads, shapes, monkeypatch):
+    """SHA-256 of ``grads`` (a call's forward and backward) at float32
+    arguments of ``shapes``, lowered for the TPU platform (no chip, nothing
+    compiled): the StableHLO text, and each kernel's Mosaic module WITHOUT
+    its source locations in place of the serialized body, which embeds the
+    call stack's line numbers and so changes with any line added above a
+    kernel."""
     from jax._src import tpu_custom_call
-    from oktopk_tpu.models.deepseek_v2 import ATTN_OUT
     bodies = []
     serialize = tpu_custom_call._lower_mosaic_module_to_asm
 
@@ -258,26 +267,59 @@ def lowered_digest(b, t, h, g, d, window, monkeypatch):
         return serialize(module, **kw)
 
     monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
-    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.float32)
-    k = jax.ShapeDtypeStruct((b, t, g, d), jnp.float32)
-
-    def grads(q, k, v, w):
-        return jax.grad(lambda q, k, v: jnp.sum(flash_gqa.flash_gqa(
-            q, k, v, d ** -0.5, window, save_as=ATTN_OUT,
-            interpret=False) * w), (0, 1, 2))(q, k, v)
-
-    text = jax.jit(grads).trace(q, k, k, q).lower(
+    text = jax.jit(grads).trace(*[
+        jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes]).lower(
         lowering_platforms=("tpu",)).as_text()
     assert len(bodies) == 3
     text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", text)
     return hashlib.sha256("\n".join([text] + bodies).encode()).hexdigest()
 
 
+def grouped_digest(b, t, h, g, d, window, monkeypatch):
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_gqa.flash_gqa(
+            q, k, v, d ** -0.5, window, save_as=ds.ATTN_OUT,
+            interpret=False) * w), (0, 1, 2))(q, k, v)
+
+    return lowered_digest(grads, [(b, t, h, d), (b, t, g, d), (b, t, g, d),
+                                  (b, t, h, d)], monkeypatch)
+
+
+def split_digest(b, t, h, d, rope, dv, monkeypatch):
+    def grads(q_nope, q_pe, k_nope, k_pe, v, w):
+        return jax.grad(lambda *x: jnp.sum(flash_gqa.flash_mla(
+            *x, (d + rope) ** -0.5, save_as=ds.ATTN_OUT,
+            interpret=False) * w), (0, 1, 2, 3, 4))(
+            q_nope, q_pe, k_nope, k_pe, v)
+
+    return lowered_digest(grads, [
+        (b, t, h, d), (b, t, h, rope), (b, t, h, d), (b, t, rope),
+        (b, t, h, dv), (b, t, h, dv)], monkeypatch)
+
+
 @pytest.mark.parametrize("call", list(GROUPED))
 def test_the_grouped_head_programs_are_the_parents(call, monkeypatch):
-    """MLA's layout went into the kernels that grouped heads run: at the
-    call shapes of ``smallthinker_dense_x1``, ``qwen3next_dense_x1`` and
-    ``laguna_xs2_dense_x1`` nothing of what XLA:TPU and Mosaic are handed
-    changed, so those cells' steps are the parent's."""
+    """MLA's layout (PR 45) and the packs of key-value heads (PR 47) went
+    into the kernels that grouped heads run: at the call shapes of
+    ``smallthinker_dense_x1``, ``qwen3next_dense_x1`` and
+    ``laguna_xs2_dense_x1``, whose groups of six to eight heads fill a
+    step, nothing of what XLA:TPU and Mosaic are handed changed, so those
+    cells' steps are the parent's."""
     shape, at_parent = GROUPED[call]
-    assert lowered_digest(*shape, monkeypatch) == at_parent
+    assert grouped_digest(*shape, monkeypatch) == at_parent
+
+
+def test_mlas_program_is_the_parents(monkeypatch):
+    """MLA became the pack ``own = r`` with a rotary part and no branch of
+    its own: ``dsv2lite_dense_x1``'s call is handed to XLA:TPU and Mosaic
+    as the parent handed it."""
+    shape, at_parent = MLA
+    assert split_digest(*shape, monkeypatch) == at_parent
+
+
+def test_a_group_of_one_head_is_another_program(monkeypatch):
+    """... and ``ouro_dense_x1``'s call is not: eight key-value heads ride
+    its grid step where one did."""
+    shape, at_parent = OURO
+    assert flash_gqa.kv_heads_a_step(*shape[2:5]) == 8
+    assert grouped_digest(*shape, monkeypatch) != at_parent
