@@ -34,7 +34,9 @@ starts at zero and goes token by token::
 computed in the chunked (WY) form of the published
 ``chunk_gated_delta_rule`` (:func:`chunk_gated_delta_rule`): inside a chunk
 of ``chunk_size`` tokens the unit-lower-triangular system is solved for all
-chunks at once, and a ``lax.scan`` over the chunks carries ``S``. All of it
+chunks at once, and a walk over the chunks carries ``S``: compiled for a
+TPU the two Pallas kernels of ``ops/delta_rule.py``, whose state stays in
+VMEM from chunk to chunk, elsewhere a ``lax.scan``. All of it
 in float32 with its products at ``highest`` precision. Then ``o_t =
 rmsnorm(o_t) * w_n * silu(z_t)`` a head and ``W_out``.
 
@@ -82,7 +84,7 @@ from jax.ad_checkpoint import checkpoint_name
 from oktopk_tpu.models.deepseek_v2 import (ATTN_OUT, HIGHEST, Kernel, MoE,
                                            held_ids)
 from oktopk_tpu.obs.anatomy import phase_scope
-from oktopk_tpu.ops import flash_gqa
+from oktopk_tpu.ops import delta_rule, flash_gqa
 
 
 # ---- norms ------------------------------------------------------------------
@@ -257,6 +259,26 @@ def inv_unit_lower(low):
     return inv
 
 
+def _scan_chunks(u, w, qk, q_dec, k_dec, last, state):
+    """A segment's chunks walked from ``state`` in plain XLA, a
+    ``lax.scan`` step a chunk: the stacked u [N, B, Hv, C, dv], w, q_dec,
+    k_dec [N, B, Hv, C, dk], qk [N, B, Hv, C, C] and last [N, B, Hv] of
+    :func:`chunk_gated_delta_rule` -> o [N, B, Hv, C, dv] and the state
+    after the last chunk."""
+    ein = partial(jnp.einsum, precision=HIGHEST)
+
+    def one_chunk(s, xs):
+        u_i, w_i, qk_i, q_i, k_i, last_i = xs
+        v_new = u_i - ein("bhcd,bhde->bhce", w_i, s)
+        o = ein("bhcd,bhde->bhce", q_i, s) + ein("bhij,bhje->bhie", qk_i,
+                                                  v_new)
+        s = s * last_i[..., None, None] + ein("bhcd,bhce->bhde", k_i, v_new)
+        return s, o
+
+    state, o = lax.scan(one_chunk, state, (u, w, qk, q_dec, k_dec, last))
+    return o, state
+
+
 def chunk_gated_delta_rule(q, k, v, g, beta, state, chunk: int):
     """The published ``chunk_gated_delta_rule`` on a stretch of tokens that
     starts from ``state``. q, k [B, T, Hk, dk] (normalised, q scaled); v [B,
@@ -269,6 +291,13 @@ def chunk_gated_delta_rule(q, k, v, g, beta, state, chunk: int):
     L)^-1``, ``u = T (v beta)``, ``w = T (k beta exp(G))``; then a chunk at
     a time, with S the state before it: ``v' = u - w S``, ``o = (q exp(G))
     S + lower(q k^T * D) v'``, ``S <- S exp(G_C) + (k exp(G_C - G))^T v'``.
+    Two forms of that walk, and the platform and the shapes choose
+    (``ops/delta_rule.on_this_platform``, which also records the call for
+    ``utils/profiling.snapshot``): compiled for a TPU with dk, dv whole
+    lane rows and the chunk whole sublanes, ``ops/delta_rule.delta_rule``,
+    Pallas kernels forward and backward that keep S in VMEM; anywhere else
+    :func:`_scan_chunks` (under ``OKTOPK_PALLAS_INTERPRET=1`` the kernels,
+    interpreted: tests).
     """
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
@@ -302,16 +331,10 @@ def chunk_gated_delta_rule(q, k, v, g, beta, state, chunk: int):
     k_dec = k * jnp.exp(gc[..., -1:] - gc)[..., None]
     last = jnp.exp(gc[..., -1])
 
-    def one_chunk(s, xs):
-        u_i, w_i, qk_i, q_i, k_i, last_i = xs
-        v_new = u_i - ein("bhcd,bhde->bhce", w_i, s)
-        o = ein("bhcd,bhde->bhce", q_i, s) + ein("bhij,bhje->bhie", qk_i,
-                                                  v_new)
-        s = s * last_i[..., None, None] + ein("bhcd,bhce->bhde", k_i, v_new)
-        return s, o
-
-    state, o = lax.scan(one_chunk, state.astype(jnp.float32),
-                        (u, w, qk, q_dec, k_dec, last))
+    walk = (delta_rule.delta_rule
+            if delta_rule.on_this_platform(n, chunk, hv, dk, dv)
+            else _scan_chunks)
+    o, state = walk(u, w, qk, q_dec, k_dec, last, state.astype(jnp.float32))
     # [N, B, Hv, C, dv] -> [B, T, Hv, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(b, t, hv, dv)
     return o, state

@@ -391,12 +391,21 @@ def snapshot() -> Dict[str, Any]:
       ``kv_heads_a_step``, the key-value heads that ride one grid step of
       the kernels with their query heads (0: the XLA form) (static, from
       shapes); empty for a model that has no such layer;
+    - ``delta_rule``: every distinct walk of the gated delta rule over a
+      segment's chunks traced in this process
+      (``models/qwen3_next.chunk_gated_delta_rule``): ``kernel``, whether
+      the Pallas kernels of ``ops/delta_rule.py`` carry the state or the
+      plain ``lax.scan``; ``segment``, the tokens walked, in chunks of
+      ``chunk``; ``value_heads``, each with a state of [``dk``, ``dv``];
+      ``heads_a_step`` and ``chunks_a_block``, the heads and chunks of one
+      grid step of the kernels (0: the scan) (static, from shapes); empty
+      for a model that has no such layer;
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
     """
     from oktopk_tpu.collectives.state import BRANCHES, COUNTERS
     from oktopk_tpu.obs.anatomy import SUB_SCOPES
-    from oktopk_tpu.ops import flash_gqa
+    from oktopk_tpu.ops import delta_rule, flash_gqa
     from oktopk_tpu.utils.compile_cache import compile_counters
 
     recs = list(SETUP.records)
@@ -412,6 +421,7 @@ def snapshot() -> Dict[str, Any]:
         "branch_names": list(BRANCHES),
         "capacities": [c for src in sources for c in src.capacities()],
         "attention": flash_gqa.calls(),
+        "delta_rule": delta_rule.calls(),
         "sub_scopes": {ph: list(subs) for ph, subs in SUB_SCOPES.items()},
         "step_counters": [{"step": s, "counters": c}
                           for s, c in fetch_counters(pairs)],

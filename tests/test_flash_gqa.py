@@ -2,7 +2,9 @@
 against the blocked XLA form's own block function as oracle; the tile
 arithmetic against a brute-force mask; what ``snapshot()`` says of the
 calls; the three models through the kernels; and the kernels compiled by
-Mosaic for a described v5e at the benchmark's widths (nothing runs)."""
+Mosaic for a described v5e at the benchmark's widths (nothing runs), those
+of ``ops/delta_rule.py`` with them: this is the one file that describes a
+chip."""
 
 import re
 
@@ -524,3 +526,33 @@ def test_mosaic_compiles_the_three_kernels_at_mlas_widths(one_chip):
         assert f"oktopk_flash_mla_{kernel}" in text
     # no score block of [heads, queries, keys] is left in the program
     assert not re.search(r"f32\[[\d,]*512,\d{4,}\]", text)
+
+
+def test_mosaic_compiles_the_delta_rules_kernels_at_the_benchmarks_widths(
+        one_chip):
+    """``qwen3next_dense_x1``'s walk: a segment of 16 chunks of 64 tokens,
+    2 sequences, 32 value heads with a state of [128, 128], at the rule's
+    heads and chunks a grid step; forward (the kernel that keeps the
+    states) and backward."""
+    from oktopk_tpu.ops import delta_rule
+    n, b, hv, c, dk, dv = 16, 2, 32, 64, 128, 128
+    stacked = [(n, b, hv, c, dv), (n, b, hv, c, dk), (n, b, hv, c, c),
+               (n, b, hv, c, dk), (n, b, hv, c, dk), (n, b, hv)]
+    state = (b, hv, dk, dv)
+
+    def grads(*x):
+        out, vjp = jax.vjp(lambda *a: delta_rule.delta_rule(
+            *a, interpret=False), *x[:7])
+        return out, vjp(x[7:])
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(grads).lower(*[jax.ShapeDtypeStruct(
+            s, jnp.float32, sharding=one_chip)
+            for s in stacked + [state, stacked[0], state]]
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("fwd", "bwd"):
+        assert f"oktopk_delta_rule_{kernel}" in text

@@ -2,7 +2,8 @@
 interpreted, against ``deepseek_v2._attend_block`` as oracle; where they
 round; the model through them; the rules for tiles and for the heads that
 ride a step; what ``snapshot()`` says of the call; and that the
-grouped-head programs are the parent commit's. (The kernels compiled by
+grouped-head programs, and the delta rule's plain form, are the parent
+commit's. (The kernels compiled by
 Mosaic at the benchmark's widths are in ``tests/test_flash_gqa.py``, the
 one file that describes a chip.)"""
 
@@ -251,13 +252,13 @@ OURO = ((2, 4096, 16, 16, 128, None),
         "e82a4bab2847258507c30695fc88777082afc6a61877043b7535e94a513859e2")
 
 
-def lowered_digest(grads, shapes, monkeypatch):
+def lowered_digest(grads, shapes, monkeypatch, kernels=3):
     """SHA-256 of ``grads`` (a call's forward and backward) at float32
     arguments of ``shapes``, lowered for the TPU platform (no chip, nothing
-    compiled): the StableHLO text, and each kernel's Mosaic module WITHOUT
-    its source locations in place of the serialized body, which embeds the
-    call stack's line numbers and so changes with any line added above a
-    kernel."""
+    compiled): the StableHLO text, and each of the ``kernels`` kernels'
+    Mosaic module WITHOUT its source locations in place of the serialized
+    body, which embeds the call stack's line numbers and so changes with
+    any line added above a kernel."""
     from jax._src import tpu_custom_call
     bodies = []
     serialize = tpu_custom_call._lower_mosaic_module_to_asm
@@ -270,7 +271,7 @@ def lowered_digest(grads, shapes, monkeypatch):
     text = jax.jit(grads).trace(*[
         jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes]).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert len(bodies) == 3
+    assert len(bodies) == kernels
     text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "", text)
     return hashlib.sha256("\n".join([text] + bodies).encode()).hexdigest()
 
@@ -323,3 +324,32 @@ def test_a_group_of_one_head_is_another_program(monkeypatch):
     shape, at_parent = OURO
     assert flash_gqa.kv_heads_a_step(*shape[2:5]) == 8
     assert grouped_digest(*shape, monkeypatch) != at_parent
+
+
+# ``qwen3next_dense_x1``'s delta rule (B, T, key heads, value heads, dk, dv,
+# chunk, segment) in the form it takes OFF a TPU backend, forward and five
+# gradients: recorded from a checkout of fbb78f3, PR 48's parent, by
+# ``delta_rule_digest`` below
+DELTA_RULE = ((2, 8192, 16, 32, 128, 128, 64, 1024),
+              "abb9c9a60e0656e035f8ece899c543e0505158606644ec6ba837ace1edccf463")
+
+
+def delta_rule_digest(b, t, hk, hv, dk, dv, chunk, segment, monkeypatch):
+    from oktopk_tpu.models import qwen3_next
+
+    def grads(q, k, v, g, beta, w):
+        return jax.grad(lambda *x: jnp.sum(qwen3_next.gated_delta_rule(
+            *x, chunk, segment) * w), (0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    return lowered_digest(grads, [
+        (b, t, hk, dk), (b, t, hk, dk), (b, t, hv, dv), (b, t, hv),
+        (b, t, hv), (b, t, hv, dv)], monkeypatch, kernels=0)
+
+
+def test_the_delta_rules_plain_form_is_the_parents(monkeypatch):
+    """PR 48 put Pallas kernels behind the walk over a segment's chunks
+    where the program is compiled for a TPU; off one (this process: the
+    chooser asks the backend, not the platform lowered for)
+    ``gated_delta_rule`` is the parent's text, ``lax.scan`` and all."""
+    shape, at_parent = DELTA_RULE
+    assert delta_rule_digest(*shape, monkeypatch) == at_parent
