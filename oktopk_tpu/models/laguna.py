@@ -27,21 +27,21 @@ sliding) repeated, ``num_attention_heads_per_layer`` 48 in a full layer and
 MLP is dense. A model of fewer layers reads the first ``num_hidden_layers``
 entries of each.
 
-**Rotary**, one record a kind (:class:`Rope`, the two entries of the
-published ``rope_parameters``): the half-split convention of
-``qwen3_next.rotate_half_partial`` over the first ``head_dim x
+**Rotary**, one record a kind (``attention.Rope``, the two entries of the
+published ``rope_parameters``): the half-split convention
+(``attention.rotate_half_partial``) over the first ``head_dim x
 partial_rotary_factor`` dims of a head; a ``yarn`` record's frequencies are
-``deepseek_v2.yarn_inv_freq`` over those dims (:func:`rotary_table`).
+YaRN's over those dims (``attention.rotary_table``).
 
-**Attention** is ``qwen3_next.blocked_causal_gqa`` for both kinds: compiled
-for a TPU the flash kernels of ``ops/flash_gqa.py`` (a sliding layer's band
-is ``sliding_window`` keys wide), anywhere else the blocked XLA form
-``attn_block`` queries at a time. Its output is named ``ATTN_OUT`` there;
-the gate multiplies after it, so the kernels and what a layer keeps are
-those of the two other models that call it.
+**Attention** is ``models/attention.py``'s ``blocked_causal_gqa`` for both
+kinds: compiled for a TPU the flash kernels of ``ops/flash_gqa.py`` (a
+sliding layer's band is ``sliding_window`` keys wide), anywhere else the
+blocked XLA form ``attn_block`` queries at a time. Its output is named
+``ATTN_OUT`` there; the gate multiplies after it, so the kernels and what a
+layer keeps are those of the other models that call it.
 
-**Routed experts**: ``deepseek_v2.MoE`` with ``scoring="sigmoid"`` (the
-router's float32 ``highest`` logits through a sigmoid where the other
+**Routed experts**: ``models/moe.py``'s ``MoE`` with ``scoring="sigmoid"``
+(the router's float32 ``highest`` logits through a sigmoid where the other
 models' go through a softmax; top-k renormalised over the k, held or not,
 times the scaling factor), its shared expert ungated; the held experts'
 pairs through the one sorted buffer and grouped products under
@@ -69,55 +69,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from oktopk_tpu.models.deepseek_v2 import (ATTN_OUT, MoE, RMSNorm, SwiGLU,
-                                           held_ids, yarn_inv_freq)
-from oktopk_tpu.models.qwen3_next import (blocked_causal_gqa,
-                                          rotate_half_partial)
+from oktopk_tpu.models.attention import (ATTN_OUT, Rope, blocked_causal_gqa,
+                                         rotary_table, rotate_half_partial)
+from oktopk_tpu.models.layers import RMSNorm, SwiGLU
+from oktopk_tpu.models.moe import MoE, held_ids
 from oktopk_tpu.obs.anatomy import phase_scope
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
 # the published lists' period: one full layer, three sliding ones
 PERIOD = (FULL, SLIDING, SLIDING, SLIDING)
-
-
-@dataclasses.dataclass(frozen=True)
-class Rope:
-    """One entry of the published ``rope_parameters`` under its own key
-    names. ``rope_type`` ``default``: plain frequencies, and the fields
-    after ``partial_rotary_factor`` are not read."""
-    rope_theta: float
-    partial_rotary_factor: float = 1.0
-    rope_type: str = "default"
-    factor: float = 1.0
-    original_max_position_embeddings: int = 0
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: float = 1.0
-
-    def __post_init__(self):
-        if self.rope_type not in ("default", "yarn"):
-            raise ValueError(f"rope_type {self.rope_type!r}")
-
-
-def rotary_table(rope: Rope, head_dim: int, tokens: int):
-    """(cos, sin) [T, rotary dims // 2] of positions 0 .. T-1, float32:
-    the first ``head_dim x partial_rotary_factor`` dims of a head turn;
-    under YaRN at that many dims' frequencies, and both tables times the
-    record's ``attention_factor``."""
-    rot = int(head_dim * rope.partial_rotary_factor)
-    if rope.rope_type == "yarn":
-        inv_freq = jnp.asarray(yarn_inv_freq(
-            rot, rope.rope_theta, rope.factor,
-            rope.original_max_position_embeddings, rope.beta_fast,
-            rope.beta_slow))
-        amp = rope.attention_factor
-    else:
-        inv_freq = 1.0 / rope.rope_theta ** (
-            jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-        amp = 1.0
-    angles = jnp.arange(tokens, dtype=jnp.float32)[:, None] * inv_freq[None]
-    return jnp.cos(angles) * amp, jnp.sin(angles) * amp
 
 
 @dataclasses.dataclass(frozen=True)

@@ -27,15 +27,15 @@ plain gain, no bias but the gate's::
 step holds ONE body of L layers, and a weight's gradient is summed over the
 passes in the loop's own carry.
 
-**Attention** is ``qwen3_next.blocked_causal_gqa`` at a group of ONE head
-(``num_key_value_heads`` = ``num_attention_heads``): on a TPU the flash
+**Attention** is ``models/attention.py``'s ``blocked_causal_gqa`` at a
+group of ONE head (``num_key_value_heads`` = ``num_attention_heads``): on a
+TPU the flash
 kernels of ``ops/flash_gqa.py``, whose grid step takes eight of the 16
 key-value heads side by side with their eight query heads (a head that
 shares its key tile with no other still shares a step's cost:
 ``flash_gqa.kv_heads_a_step``, from the shapes), anywhere else the blocked
 XLA form. Its output is named ``ATTN_OUT`` there. Rotary is
-``laguna.rotary_table`` of a
-plain record on all of a head's dims.
+``attention.rotary_table`` of a plain record on all of a head's dims.
 
 **Recomputation** as ``models/laguna.py``: a decoder layer is recomputed in
 the backward pass from its input and ``ATTN_OUT`` (with the rows'
@@ -68,10 +68,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from oktopk_tpu.models.deepseek_v2 import ATTN_OUT, RMSNorm, SwiGLU
-from oktopk_tpu.models.laguna import Rope, rotary_table
-from oktopk_tpu.models.qwen3_next import (blocked_causal_gqa,
-                                          rotate_half_partial)
+from oktopk_tpu.models.attention import (ATTN_OUT, Rope, blocked_causal_gqa,
+                                         rotary_table, rotate_half_partial)
+from oktopk_tpu.models.layers import RMSNorm, SwiGLU
 from oktopk_tpu.obs.anatomy import phase_scope
 
 

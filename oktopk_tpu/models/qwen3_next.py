@@ -11,13 +11,11 @@ full_attention_interval == 0``. No biases::
 **Gated attention** (:class:`GatedAttention`): ``[q | gate] = h W_q`` a head
 at a time, ``k = h W_k``, ``v = h W_v``; ``q`` and ``k`` through ``N`` over a
 head; rotary on the first ``partial_rotary_factor`` of a head's dims (the
-half-split convention, :func:`rotate_half_partial`); causal softmax of
-``q k^T / sqrt(head_dim)`` in float32, each key-value head serving
-``heads / kv_heads`` query heads (:func:`blocked_causal_gqa`: flash kernels
-compiled for a TPU, elsewhere a sibling of
-``deepseek_v2.blocked_causal_attention``, which is written for MLA's split
-heads and whose program has to stay what it is); ``(out *
-sigmoid(gate)) W_o``.
+half-split convention, ``attention.rotate_half_partial``); causal softmax
+of ``q k^T / sqrt(head_dim)`` in float32, each key-value head serving
+``heads / kv_heads`` query heads (``models/attention.py``'s
+``blocked_causal_gqa``: flash kernels compiled for a TPU, elsewhere a block
+of queries at a time); ``(out * sigmoid(gate)) W_o``.
 
 **Gated DeltaNet** (:class:`GatedDeltaNet`): ``[q | k | v | z] = h W_qkvz``
 and ``[b | a] = h W_ba``, both in THIS flat order (the published checkpoint
@@ -44,20 +42,19 @@ rmsnorm(o_t) * w_n * silu(z_t)`` a head and ``W_out``.
 (``nn.remat``) from its input and its mixer's core output (``ATTN_OUT``:
 the delta rule's ``o`` or the attention's weighted sum, [B, T, heads x
 head dim]; compiled for a TPU the attention names its rows' log-sum-exp so
-too, and its forward kernel runs once a layer). Inside a layer: each query
-block of the attention's XLA form recomputes its scores (the kernels'
-backward pass recomputes a tile's from the log-sum-exp,
-``ops/flash_gqa.py``); the delta rule runs in segments of ``scan_segment`` tokens,
+too, and its forward kernel runs once a layer). Inside a layer: the
+attention keeps no score (``models/attention.py``); the delta rule runs in
+segments of ``scan_segment`` tokens,
 each recomputed from the state it started with, so that one segment's
 chunk-parallel intermediates and one state a segment are all that is
 alive (no per-token state ever is); what comes before the delta rule
 (projections, convolution, gates, norms) is recomputed a sequence at a
 time; and the routed experts' branch recomputes itself
-(``deepseek_v2.routed_experts``). So the recurrence runs twice before its
+(``moe.routed_experts``). So the recurrence runs twice before its
 backward pass (forward, its segment's recomputation), the input
 projections three times.
 
-**Routed experts**: ``deepseek_v2.MoE`` as it stands (router over ALL
+**Routed experts**: ``models/moe.py``'s ``MoE`` (router over ALL
 ``num_experts`` in float32 at ``highest``, top-k renormalised over the k,
 held or not; the held experts' pairs through one sorted buffer and grouped
 products), with its shared expert behind a sigmoid gate.
@@ -81,10 +78,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from oktopk_tpu.models.deepseek_v2 import (ATTN_OUT, HIGHEST, Kernel, MoE,
-                                           held_ids)
+from oktopk_tpu.models.attention import (ATTN_OUT, blocked_causal_gqa,
+                                         rotate_half_partial)
+from oktopk_tpu.models.layers import HIGHEST, Kernel
+from oktopk_tpu.models.moe import MoE, held_ids
 from oktopk_tpu.obs.anatomy import phase_scope
-from oktopk_tpu.ops import delta_rule, flash_gqa
+from oktopk_tpu.ops import delta_rule
 
 
 # ---- norms ------------------------------------------------------------------
@@ -116,93 +115,6 @@ class Bias(nn.Module):
 
 
 # ---- gated softmax attention -------------------------------------------------
-
-def rotate_half_partial(x, cos, sin):
-    """Rotary on the first ``2 * cos.shape[-1]`` dims of every head, the
-    half-split convention: with ``(x1, x2)`` the two halves of those dims,
-    ``(x1 cos - x2 sin, x2 cos + x1 sin)``; the other dims pass. ``x``
-    [..., T, heads, dim]; ``cos``/``sin`` [T, rotary dims // 2]."""
-    half = cos.shape[-1]
-    x1, x2, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
-    cos, sin = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def _attend_block_gqa(q, k, v, start, end, scale, first=0, window=None):
-    """One sequence's queries ``start .. end`` against its keys ``first ..
-    end``. q [block, G, R, d] (G key-value heads, R query heads each); k, v
-    [T, G, d], cut here (a caller who recomputes this keeps no cut copy).
-    ``window``: query i reads the keys j with ``i - j < window`` only."""
-    k, v = k[first:end], v[first:end]
-    s = jnp.einsum("qgrd,kgd->grqk", q, k).astype(jnp.float32) * scale
-    rows = start + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
-    cols = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-    if first:   # no add of a zero: without a window nothing is lowered for it
-        cols = cols + first
-    seen = cols <= rows
-    if window is not None:
-        seen = seen & (rows - cols < window)
-    s = jnp.where(seen, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("grqk,kgd->qgrd", p, v)
-
-
-def _blocked_xla(q, k, v, scale: float, block: int, window: Optional[int]):
-    """:func:`blocked_causal_gqa` in plain XLA: a sequence at a time and
-    ``block`` queries at a time, each block's scores recomputed in the
-    backward pass and its output named ``ATTN_OUT``, as
-    ``deepseek_v2.blocked_causal_attention`` does for MLA's split heads. A
-    block of queries reads, scores and masks only the keys ``[max(0, start
-    - window + 1), end)`` that any of them can see, so a windowed layer's
-    work follows the band and not the causal triangle."""
-    b, t, h, d = q.shape
-    g = k.shape[2]
-
-    def one_sequence(seq):
-        qq, kk, vv = seq
-        qq = qq.reshape(t, g, h // g, d)
-        outs = []
-        for start in range(0, t, block):
-            end = min(start + block, t)
-            first = 0 if window is None else max(0, start - window + 1)
-            fn = jax.checkpoint(partial(
-                _attend_block_gqa, start=start, end=end, scale=scale,
-                first=first, window=window))
-            outs.append(checkpoint_name(fn(qq[start:end], kk, vv), ATTN_OUT))
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-        return out.reshape(t, h, d)
-
-    return lax.map(one_sequence, (q, k, v))
-
-
-def blocked_causal_gqa(q, k, v, scale: float, block: int,
-                       window: Optional[int] = None):
-    """Causal attention with grouped heads: q [B, T, H, d], k and v [B, T,
-    G, d] (query head h reads key-value head ``h // (H / G)``) -> [B, T, H,
-    d]. ``window`` (None: all keys at or before the query): query i reads
-    keys ``i - window < j <= i``. Two forms of one function, and the
-    platform chooses (``ops/flash_gqa.on_this_platform``, which also
-    records the call for ``utils/profiling.snapshot``):
-
-    * compiled for a TPU, ``ops/flash_gqa.flash_gqa``: Pallas kernels,
-      forward and backward, whose score tiles live in VMEM and whose work
-      follows the band. The output and the rows' log-sum-exp are both named
-      ``ATTN_OUT``, so a layer recomputed from its saved names finds the
-      backward kernels' residuals and runs no forward kernel again.
-      ``block`` is not read there: the tiles are the kernel's own rule's;
-    * anywhere else :func:`_blocked_xla`, ``block`` queries at a time
-      (under ``OKTOPK_PALLAS_INTERPRET=1`` the kernels, interpreted: tests).
-    """
-    t = q.shape[1]
-    block = min(block, t)
-    if window is not None and window >= t:
-        window = None   # every key at or before a query is in its window
-    if flash_gqa.on_this_platform(t, q.shape[2], k.shape[2], q.shape[3],
-                                  window, block):
-        return flash_gqa.flash_gqa(q, k, v, scale, window, save_as=ATTN_OUT)
-    return _blocked_xla(q, k, v, scale, block, window)
-
 
 class GatedAttention(nn.Module):
     num_heads: int

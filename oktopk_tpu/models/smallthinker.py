@@ -16,12 +16,13 @@ shared expert. Layer ``l`` (from 0), RMSNorm with a plain gain, no biases::
 
 The published layouts are ``[0, 1, 1, 1]`` repeated: the first layer of
 every four is GLOBAL causal attention with NO position encoding, the other
-three are rotary (half-split convention, ``qwen3_next.rotate_half_partial``
+three are rotary (half-split convention, ``attention.rotate_half_partial``
 over the whole head) inside a window of ``sliding_window_size`` keys, the
 query's own included.
 
-**Attention** is ``qwen3_next.blocked_causal_gqa`` for both kinds (each
-key-value head serves ``heads / kv_heads`` query heads): a windowed layer
+**Attention** is ``models/attention.py``'s ``blocked_causal_gqa`` for both
+kinds (each key-value head serves ``heads / kv_heads`` query heads): a
+windowed layer
 passes its ``window``, a global layer none. Compiled for a TPU that is the
 flash kernels of ``ops/flash_gqa.py``, forward and backward: a tile of
 scores lives in VMEM, a query tile visits only the key tiles that its band
@@ -32,12 +33,12 @@ in the backward pass and its output named ``ATTN_OUT``; a windowed block
 reads, scores and masks only the keys ``[max(0, start - window + 1),
 end)``, a global block's scores reach over the whole sequence.
 
-**Routed experts**: ``deepseek_v2.MoE`` with ``router_input`` (the router
-scores ``N_1(x)`` over ALL experts in float32 at ``highest``, top-k
+**Routed experts**: ``models/moe.py``'s ``MoE`` with ``router_input`` (the
+router scores ``N_1(x)`` over ALL experts in float32 at ``highest``, top-k
 renormalised over the k, held or not; its operations depend on nothing the
 attention computes, so the compiler may run them under it) and
 ``hidden_act="relu"``; the held experts' pairs go through the one sorted
-buffer and grouped products that the two other expert models use, under
+buffer and grouped products that the other expert models use, under
 the same ``CAPACITY_FACTOR``. The model has no shared expert and no dense
 layer, so at untrained weights what attention adds alike to every token
 grows with depth, the router's input grows alike with it, and a layer's
@@ -68,9 +69,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from oktopk_tpu.models.deepseek_v2 import ATTN_OUT, MoE, RMSNorm, held_ids
-from oktopk_tpu.models.qwen3_next import (blocked_causal_gqa,
-                                          rotate_half_partial)
+from oktopk_tpu.models.attention import (ATTN_OUT, blocked_causal_gqa,
+                                         rotate_half_partial)
+from oktopk_tpu.models.layers import RMSNorm
+from oktopk_tpu.models.moe import MoE, held_ids
 from oktopk_tpu.obs.anatomy import phase_scope
 
 # the published layouts' period: one global layer without position, three

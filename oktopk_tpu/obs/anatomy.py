@@ -81,9 +81,10 @@ SUB_GLOBAL = "global"            # select: phase-(b) winner selection
 SUB_FEEDBACK = "feedback"        # select: controller feedback
 # ... and inside ``fwd_bwd``, entered by the model itself
 # (models/deepseek_v2.py, models/qwen3_next.py, models/smallthinker.py,
-# models/laguna.py, models/ouro.py), so forward, recomputed and backward
-# operations alike carry them: a model that enters none leaves the phase
-# unscoped. Four lie inside another, and a reader takes the innermost:
+# models/laguna.py, models/ouro.py; ``router``, ``experts`` and ``shared``
+# by the expert layer they share, models/moe.py), so forward, recomputed
+# and backward operations alike carry them: a model that enters none leaves
+# the phase unscoped. Four lie inside another, and a reader takes the innermost:
 # ``delta_rule`` (the chunked recurrence alone) inside
 # ``linear_attention`` (its projections, convolution, gates and norm);
 # ``window_scores`` (a windowed layer's

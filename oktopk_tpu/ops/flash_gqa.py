@@ -5,13 +5,13 @@ MLA's split heads (:func:`flash_mla`), one set of kernels.
 Grouped heads: q [B, T, H, d], k and v [B, T, G, d] (query head h reads
 key-value head ``h // R``, ``R = H / G``), ``window`` None (query i reads
 the keys ``j <= i``) or an integer (``i - window < j <= i``). The plain form
-(``models/qwen3_next._attend_block_gqa``) writes a float32 score block of
+(``models/attention._attend_block_gqa``) writes a float32 score block of
 [G, R, queries, keys] to HBM, masks, exponentiates, sums, normalises and
 reads it again for the weighted sum, twice before its backward pass; at
 T = 16,384 that traffic was 64 % of a step (PERF.md, Findings, PR 43).
 
 Split heads (DeepSeek-V2's multi-head latent attention,
-``models/deepseek_v2.blocked_causal_attention``): a head's score is TWO
+``models/attention.blocked_causal_attention``): a head's score is TWO
 products summed in float32 before the scale, ``q_nope k_nope^T`` over the
 head's own d-wide key and ``q_pe k_pe^T`` over a ``rope``-wide rotary key
 that is ONE head shared by all; values are ``dv`` wide; no two query heads
@@ -86,8 +86,8 @@ mask hides the padding (a padded key is after every real query, a padded
 query's cotangent is zero).
 
 Off a TPU backend the kernels run only interpreted (tests); see
-``models/qwen3_next.blocked_causal_gqa`` and
-``models/deepseek_v2.blocked_causal_attention`` for who chooses.
+``models/attention.py``'s ``blocked_causal_gqa`` and
+``blocked_causal_attention`` for who chooses.
 """
 
 from __future__ import annotations

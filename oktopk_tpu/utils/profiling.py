@@ -381,11 +381,10 @@ def snapshot() -> Dict[str, Any]:
       bucket whose flat vector nothing reads: none is built or cut up
       again, optim/distributed.py) or flattens it (every other bucket);
     - ``attention``: every distinct softmax-attention call traced in this
-      process, grouped heads (``models/qwen3_next.blocked_causal_gqa``) and
-      MLA's split heads (``models/deepseek_v2.blocked_causal_attention``)
-      alike: ``kernel``,
-      whether the Pallas kernels of ``ops/flash_gqa.py`` run it or the
-      blocked XLA form; its ``window`` (None: causal); ``tiles_visited``,
+      process, grouped heads (``models/attention.blocked_causal_gqa``) and
+      MLA's split heads (``models/attention.blocked_causal_attention``)
+      alike: ``kernel``, whether the Pallas kernels of
+      ``ops/flash_gqa.py`` run it or the blocked XLA form; its ``window`` (None: causal); ``tiles_visited``,
       the key tiles a sequence and head group visits, against
       ``tiles_causal``, what the causal triangle holds;
       ``kv_heads_a_step``, the key-value heads that ride one grid step of

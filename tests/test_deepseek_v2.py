@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from benchlib import discover, weights  # noqa: E402
 
 from oktopk_tpu.config import TrainConfig  # noqa: E402
+from oktopk_tpu.models import attention, layers, moe  # noqa: E402
 from oktopk_tpu.models import create_model  # noqa: E402
 from oktopk_tpu.models import deepseek_v2 as ds  # noqa: E402
 from oktopk_tpu.models.registry import TOKEN_LMS  # noqa: E402
@@ -151,7 +152,7 @@ class TestAgainstReference:
         """A block's scores, mask and softmax are in the gradient's program
         twice a layer before their backward pass: the forward pass's and
         the block's own ``jax.checkpoint``. The layer's recomputation
-        starts from the kept attention output (``ds.ATTN_OUT``) and runs
+        starts from the kept attention output (``attention.ATTN_OUT``) and runs
         none; with the layer's remat given no policy (built here, no
         option in the package) it runs a third. The experts' twelve
         grouped products a layer are the same either way."""
@@ -233,17 +234,17 @@ class TestShare:
                                         ("down_proj", (2 * f, d)))}}, 11)
         uncut = REF._experts(full, h.reshape(-1, d),
                              spec_of(cfg, held=range(e)))
-        shared = ds.swiglu(h.reshape(-1, d), *(
+        shared = layers.swiglu(h.reshape(-1, d), *(
             full["shared_ffn"][n]["kernel"]
             for n in ("gate_proj", "up_proj", "down_proj")))
         total, rows = shared, 0
         for chip in range(e):
-            moe = ds.MoE(e, (chip,), cfg.num_experts_per_tok, f, 0, 1.0,
-                         False, jnp.float32)
+            layer = moe.MoE(e, (chip,), cfg.num_experts_per_tok, f, 0, 1.0,
+                            False, jnp.float32)
             share = {k: ({"experts": v["experts"][chip:chip + 1]}
                          if k.startswith("routed") else v)
                      for k, v in full.items() if k != "shared_ffn"}
-            y, counts = moe.apply({"params": share}, h)
+            y, counts = layer.apply({"params": share}, h)
             total = total + y.reshape(-1, d)
             rows += int(counts.sum())
         assert rows == 2 * 48 * cfg.num_experts_per_tok  # every pair, once
@@ -269,12 +270,12 @@ class TestNoDroppedToken:
         weights_ = jnp.where(routed, jax.random.uniform(ks[4], (t, held)), 0.)
 
         def run(x, wg, wu, wd):
-            y, counts = ds.routed_experts(x, weights_, routed, wg, wu, wd,
-                                          capacity, k)
+            y, counts = moe.routed_experts(x, weights_, routed, wg, wu, wd,
+                                           capacity, k)
             return jnp.sum(y * y), (y, counts)
 
         def plain(x, wg, wu, wd):
-            y = ds.swiglu(x, wg[0], wu[0], wd[0]) * weights_[:, :1]
+            y = layers.swiglu(x, wg[0], wu[0], wd[0]) * weights_[:, :1]
             return jnp.sum(y * y), y
 
         (_, (y, counts)), grads = jax.value_and_grad(
@@ -288,10 +289,10 @@ class TestNoDroppedToken:
 
     def test_capacity_from_the_mean_number_of_pairs(self):
         # 16,384 tokens, 6 of 64 a token, 8 held: 12,288 pairs on average
-        assert ds.expert_capacity(16384, 8, 6, 64) == 18432
-        assert ds.expert_capacity(256, 4, 2, 8) == 384
+        assert moe.expert_capacity(16384, 8, 6, 64) == 18432
+        assert moe.expert_capacity(256, 4, 2, 8) == 384
         # never more rows than pairs there can be (256 tokens x 2 a token)
-        assert ds.expert_capacity(256, 8, 2, 8) == 512
+        assert moe.expert_capacity(256, 8, 2, 8) == 512
 
     @pytest.mark.parametrize("share", [0.1, 0.5, 0.75])
     def test_grouped_products_cover_the_buffer_at_any_routing(
@@ -311,10 +312,10 @@ class TestNoDroppedToken:
             seen.append(group_sizes)
             return plain(lhs, rhs, group_sizes, **kw)
 
-        monkeypatch.setattr(ds.lax, "ragged_dot", spy)
-        ds._grouped_branch(rows, x, routed.astype(x.dtype), routed, counts,
-                           jnp.ones((held, d, f)), jnp.ones((held, d, f)),
-                           jnp.ones((held, f, d)))
+        monkeypatch.setattr(moe.lax, "ragged_dot", spy)
+        moe._grouped_branch(rows, x, routed.astype(x.dtype), routed, counts,
+                            jnp.ones((held, d, f)), jnp.ones((held, d, f)),
+                            jnp.ones((held, f, d)))
         assert len(seen) == 3
         for groups in seen:
             assert int(groups.sum()) == rows
@@ -329,7 +330,7 @@ class TestNoDroppedToken:
         wd = jnp.zeros((held, f, d))
 
         def conds(capacity, k):
-            return str(jax.make_jaxpr(lambda: ds.routed_experts(
+            return str(jax.make_jaxpr(lambda: moe.routed_experts(
                 x, w, w > 0, wg, wu, wd, capacity, k))()).count("cond[")
         assert conds(384, 2) == 1 and conds(512, 2) == 0
 
@@ -348,7 +349,7 @@ class TestAttentionAndRotary:
         qp = jax.random.normal(ks[2], (b, t, h, dr))
         kp = jax.random.normal(ks[3], (b, t, dr))
         v = jax.random.normal(ks[4], (b, t, h, dv))
-        got = ds.blocked_causal_attention(qn, qp, kn, kp, v, 0.3, block)
+        got = attention.blocked_causal_attention(qn, qp, kn, kp, v, 0.3, block)
         q = jnp.concatenate([qn, qp], -1)
         k = jnp.concatenate(
             [kn, jnp.broadcast_to(kp[:, :, None], (b, t, h, dr))], -1)
@@ -359,7 +360,7 @@ class TestAttentionAndRotary:
 
     def test_yarn_frequencies_are_the_written_formula(self):
         dim, theta, factor, orig, fast, slow = 64, 10000.0, 40.0, 4096, 32, 1
-        got = ds.yarn_inv_freq(dim, theta, factor, orig, fast, slow)
+        got = attention.yarn_inv_freq(dim, theta, factor, orig, fast, slow)
 
         def dim_of(rot):
             return dim * math.log(orig / (rot * 2 * math.pi)) / (
@@ -374,21 +375,22 @@ class TestAttentionAndRotary:
         assert got[0] == pytest.approx(1.0) and got[-1] == pytest.approx(
             theta ** (-62 / 64) / 40, rel=1e-6)
         # cos/sin scale 1 (mscale == mscale_all_dim); softmax scale m^2
-        assert ds.yarn_mscale(40, 0.707) == pytest.approx(
+        assert attention.yarn_mscale(40, 0.707) == pytest.approx(
             0.1 * 0.707 * math.log(40) + 1)
 
     def test_rotation_turns_adjacent_pairs(self):
         t, dim = 5, 8
         x = jax.random.normal(jax.random.PRNGKey(2), (t, 1, dim))
         ang = (jnp.arange(t)[:, None] * jnp.asarray([1.0, .5, .25, .125]))
-        got = ds.rotate_pairs(x, jnp.cos(ang), jnp.sin(ang))
+        got = attention.rotate_pairs(x, jnp.cos(ang), jnp.sin(ang))
         z = (x[..., 0::2] + 1j * x[..., 1::2]) * jnp.exp(1j * ang)[:, None]
         np.testing.assert_allclose(got[..., 0::2], z.real, atol=1e-6)
         np.testing.assert_allclose(got[..., 1::2], z.imag, atol=1e-6)
         # ... as the reference's own rotary does (no scaling: factor 1)
         np.testing.assert_allclose(
-            ds.rotate_pairs(x, *(f(jnp.arange(t)[:, None] * REF._inv_freq(
-                dim, ROPE1)) for f in (jnp.cos, jnp.sin))),
+            attention.rotate_pairs(x, *(
+                f(jnp.arange(t)[:, None] * REF._inv_freq(dim, ROPE1))
+                for f in (jnp.cos, jnp.sin))),
             REF._rotary(x, ROPE1), atol=1e-6)
 
 
@@ -536,15 +538,15 @@ class TestNewArgumentsAtTheirDefaults:
         args = (cfg.n_routed_experts, (1, 2, 5), cfg.num_experts_per_tok,
                 cfg.moe_intermediate_size, 2, 1.0, False)
         h = jax.random.normal(jax.random.PRNGKey(3), (2, 48, cfg.hidden_size))
-        plain = ds.MoE(*args)
+        plain = moe.MoE(*args)
         params = plain.init(jax.random.PRNGKey(1), h)
         y, rows = plain.apply(params, h)
-        spelled = ds.MoE(*args, hidden_act="silu")
+        spelled = moe.MoE(*args, hidden_act="silu")
         y2, rows2 = spelled.apply(params, h, router_input=h)
         assert np.array_equal(y, y2) and np.array_equal(rows, rows2)
         # ... and each of them is read: another gate, another input of the
         # router
-        relu, _ = ds.MoE(*args, hidden_act="relu").apply(params, h)
+        relu, _ = moe.MoE(*args, hidden_act="relu").apply(params, h)
         _, moved = plain.apply(params, h, router_input=2.0 * h - 1.0)
         assert not np.array_equal(y, relu) and not np.array_equal(rows, moved)
 

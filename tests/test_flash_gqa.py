@@ -1,10 +1,10 @@
 """ops/flash_gqa.py: the grouped-head flash-attention kernels, interpreted,
 against the blocked XLA form's own block function as oracle; the tile
 arithmetic against a brute-force mask; what ``snapshot()`` says of the
-calls; the three models through the kernels; and the kernels compiled by
-Mosaic for a described v5e at the benchmark's widths (nothing runs), those
-of ``ops/delta_rule.py`` with them: this is the one file that describes a
-chip."""
+calls; and the kernels compiled by Mosaic for a described v5e at the
+benchmark's widths (nothing runs), those of ``ops/delta_rule.py`` with
+them: this is the one file that describes a chip. The models through the
+kernels are in ``tests/test_attention.py``."""
 
 import re
 
@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oktopk_tpu.models import laguna, qwen3_next, smallthinker
-from oktopk_tpu.models.deepseek_v2 import ATTN_OUT
+from oktopk_tpu.models import attention
+from oktopk_tpu.models.attention import ATTN_OUT
 from oktopk_tpu.ops import flash_gqa
 from oktopk_tpu.utils import profiling
 
@@ -25,7 +25,7 @@ def oracle(q, k, v, scale, window):
     g = k.shape[2]
 
     def one(qq, kk, vv):
-        return qwen3_next._attend_block_gqa(
+        return attention._attend_block_gqa(
             qq.reshape(t, g, h // g, d), kk, vv, 0, t, scale, 0,
             window).reshape(t, h, d)
     return jax.vmap(one)(q, k, v)
@@ -349,8 +349,8 @@ class TestSnapshot:
     def test_a_call_is_recorded_once_a_shape(self, fresh_calls, monkeypatch):
         q, k, v, _ = inputs(1, 64, 2, 32)
         for _ in range(2):
-            qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
-        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 64)
+            attention.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
+        attention.blocked_causal_gqa(q, k, v, 0.1, 16, 64)
         assert profiling.snapshot()["attention"] == [
             {"kernel": False, "window": 24,
              "tiles_visited": flash_gqa.tile_counts(64, 16, 16, 24)[0],
@@ -358,62 +358,19 @@ class TestSnapshot:
             {"kernel": False, "window": None, "tiles_visited": 10,
              "tiles_causal": 10, "kv_heads_a_step": 0}]
         monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
-        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
+        attention.blocked_causal_gqa(q, k, v, 0.1, 16, 24)
         assert profiling.snapshot()["attention"][-1] == {
             "kernel": True, "window": 24, "tiles_visited": 1,
             "tiles_causal": 1, "kv_heads_a_step": 1}
         # a group of one head over four key-value heads: the four ride a step
         q, k, v, _ = inputs(1, 64, 1, 128, g=4)
-        qwen3_next.blocked_causal_gqa(q, k, v, 0.1, 16)
+        attention.blocked_causal_gqa(q, k, v, 0.1, 16)
         assert profiling.snapshot()["attention"][-1] == {
             "kernel": True, "window": None, "tiles_visited": 1,
             "tiles_causal": 1, "kv_heads_a_step": 4}
 
     def test_empty_without_such_a_layer(self, fresh_calls):
         assert profiling.snapshot()["attention"] == []
-
-
-def _tiny(family):
-    if family == "smallthinker":
-        cfg = smallthinker.SmallThinkerConfig.tiny(
-            held_experts=(0, 1, 2, 3))
-        return smallthinker.SmallThinker(cfg), 4
-    if family == "laguna":
-        cfg = laguna.LagunaConfig.tiny(held_experts=(0, 1, 2, 3))
-        return laguna.Laguna(cfg), 5
-    cfg = qwen3_next.Qwen3NextConfig.tiny(held_experts=(0, 1, 2, 3))
-    return qwen3_next.Qwen3Next(cfg), 1
-
-
-class TestModelsThroughTheKernels:
-    @pytest.fixture(params=["smallthinker", "qwen3_next", "laguna"])
-    def job(self, request):
-        model, layers = _tiny(request.param)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 512)
-        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
-
-        def loss(p):
-            logits, _ = model.apply(p, tokens)
-            return jnp.mean(jax.nn.logsumexp(logits, -1) - logits[..., 0])
-        return loss, params, layers
-
-    def test_loss_and_gradients_as_the_xla_form(self, job, monkeypatch):
-        loss, params, _ = job
-        want = jax.jit(jax.value_and_grad(loss))(params)
-        monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
-        got = jax.jit(jax.value_and_grad(loss))(params)
-        np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
-        for a, e in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
-            np.testing.assert_allclose(a, e, rtol=1e-4, atol=1e-6)
-
-    def test_one_forward_kernel_a_layer_under_the_layers_remat(
-            self, job, monkeypatch):
-        loss, params, layers = job
-        monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
-        text = str(jax.make_jaxpr(jax.grad(loss))(params))
-        for kernel in ("fwd", "dq", "dkv"):
-            assert len(re.findall(
-                rf"name=oktopk_flash_gqa_{kernel}\b", text)) == layers, kernel
 
 
 # ---- compiled for a described v5e (nothing runs) ---------------------------

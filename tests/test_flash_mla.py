@@ -1,5 +1,5 @@
 """ops/flash_gqa.py with MLA's split heads (``flash_mla``): the kernels,
-interpreted, against ``deepseek_v2._attend_block`` as oracle; where they
+interpreted, against ``attention._attend_block`` as oracle; where they
 round; the model through them; the rules for tiles and for the heads that
 ride a step; what ``snapshot()`` says of the call; and that the
 grouped-head programs, and the delta rule's plain form, are the parent
@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from oktopk_tpu.models import attention
 from oktopk_tpu.models import deepseek_v2 as ds
 from oktopk_tpu.ops import flash_gqa
 from oktopk_tpu.utils import profiling
@@ -33,7 +34,7 @@ def inputs(b, t, h, d, rope, dv, seed=0):
 def oracle(q_nope, q_pe, k_nope, k_pe, v, scale):
     """``_attend_block`` over one block that is the whole sequence."""
     t = q_nope.shape[1]
-    return jax.vmap(lambda *seq: ds._attend_block(*seq, 0, t, scale))(
+    return jax.vmap(lambda *seq: attention._attend_block(*seq, 0, t, scale))(
         q_nope, q_pe, k_nope, k_pe, v)
 
 
@@ -177,12 +178,12 @@ def fresh_calls(monkeypatch):
 def test_the_call_is_recorded(fresh_calls, monkeypatch):
     *x, _ = inputs(1, 64, 4, 16, 8, 16)
     for _ in range(2):
-        ds.blocked_causal_attention(*x, 0.2, 16)
+        attention.blocked_causal_attention(*x, 0.2, 16)
     assert profiling.snapshot()["attention"] == [
         {"kernel": False, "window": None, "tiles_visited": 10,
          "tiles_causal": 10, "kv_heads_a_step": 0}]
     monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
-    ds.blocked_causal_attention(*x, 0.2, 16)
+    attention.blocked_causal_attention(*x, 0.2, 16)
     assert profiling.snapshot()["attention"][-1] == {
         "kernel": True, "window": None, "tiles_visited": 1,
         "tiles_causal": 1, "kv_heads_a_step": flash_gqa.heads_a_step(4, 8)}
@@ -279,7 +280,7 @@ def lowered_digest(grads, shapes, monkeypatch, kernels=3):
 def grouped_digest(b, t, h, g, d, window, monkeypatch):
     def grads(q, k, v, w):
         return jax.grad(lambda q, k, v: jnp.sum(flash_gqa.flash_gqa(
-            q, k, v, d ** -0.5, window, save_as=ds.ATTN_OUT,
+            q, k, v, d ** -0.5, window, save_as=attention.ATTN_OUT,
             interpret=False) * w), (0, 1, 2))(q, k, v)
 
     return lowered_digest(grads, [(b, t, h, d), (b, t, g, d), (b, t, g, d),
@@ -289,7 +290,7 @@ def grouped_digest(b, t, h, g, d, window, monkeypatch):
 def split_digest(b, t, h, d, rope, dv, monkeypatch):
     def grads(q_nope, q_pe, k_nope, k_pe, v, w):
         return jax.grad(lambda *x: jnp.sum(flash_gqa.flash_mla(
-            *x, (d + rope) ** -0.5, save_as=ds.ATTN_OUT,
+            *x, (d + rope) ** -0.5, save_as=attention.ATTN_OUT,
             interpret=False) * w), (0, 1, 2, 3, 4))(
             q_nope, q_pe, k_nope, k_pe, v)
 

@@ -29,10 +29,9 @@ from benchlib import (discover, kernels_lm, kernels_mixed_gqa,  # noqa: E402
                       weights)
 
 from oktopk_tpu.config import TrainConfig  # noqa: E402
+from oktopk_tpu.models import attention, layers, moe  # noqa: E402
 from oktopk_tpu.models import create_model  # noqa: E402
-from oktopk_tpu.models import deepseek_v2 as ds  # noqa: E402
 from oktopk_tpu.models import laguna as la  # noqa: E402
-from oktopk_tpu.models import qwen3_next as qn  # noqa: E402
 from oktopk_tpu.models.registry import TOKEN_LMS  # noqa: E402
 from oktopk_tpu.obs import anatomy  # noqa: E402
 from oktopk_tpu.train.trainer import Trainer  # noqa: E402
@@ -241,8 +240,8 @@ class TestTheLayersKind:
         ``attention_factor``; dims 64-127 pass. And the reference's own
         YaRN (written apart) gives the same frequencies."""
         rope = la.LagunaConfig().rope_full
-        cos, sin = la.rotary_table(rope, 128, 48)
-        freq = ds.yarn_inv_freq(64, 500000.0, 64.0, 4096, 64.0, 1.0)
+        cos, sin = attention.rotary_table(rope, 128, 48)
+        freq = attention.yarn_inv_freq(64, 500000.0, 64.0, 4096, 64.0, 1.0)
         assert cos.shape == sin.shape == (48, 32)
         ang = np.arange(48, dtype=np.float32)[:, None] * freq[None]
         amp = 0.1 * math.log(64.0) + 1.0
@@ -258,11 +257,12 @@ class TestTheLayersKind:
         assert freq[0] == pytest.approx(plain[0]) and (
             freq[-1] == pytest.approx(plain[-1] / 64))
         x = jax.random.normal(jax.random.PRNGKey(2), (48, 3, 128))
-        y = qn.rotate_half_partial(x, cos, sin)
+        y = attention.rotate_half_partial(x, cos, sin)
         assert np.array_equal(y[..., 64:], x[..., 64:])
         assert float(jnp.min(jnp.abs(y[1:, :, :64] - x[1:, :, :64]))) > 0
         # the sliding record: every dim, plain frequencies, no factor
-        cos, _ = la.rotary_table(la.LagunaConfig().rope_sliding, 128, 48)
+        cos, _ = attention.rotary_table(la.LagunaConfig().rope_sliding, 128,
+                                        48)
         np.testing.assert_allclose(cos, np.cos(
             np.arange(48)[:, None] * 10000.0 ** (-np.arange(0, 128, 2) / 128)
         ), rtol=1e-5, atol=1e-5)
@@ -356,7 +356,7 @@ class TestSigmoidRouting:
                               np.full(96, self.K))
         np.testing.assert_allclose(jnp.sum(w, axis=1), 2.5, rtol=1e-6)
         # they are the k largest sigmoids, renormalised: not a softmax
-        scores = jax.nn.sigmoid(jnp.dot(h, w_r, precision=ds.HIGHEST))
+        scores = jax.nn.sigmoid(jnp.dot(h, w_r, precision=layers.HIGHEST))
         top = jnp.where(w > 0, scores, 0.0)
         assert float(jnp.min(jnp.where(w > 0, scores, 1.0))) >= float(
             jnp.max(jnp.where(w > 0, 0.0, scores), axis=1).min())
@@ -369,9 +369,9 @@ class TestSigmoidRouting:
         and the shared one); the softmax it replaces is not."""
         full = moe_params(self.D, self.F, self.E, shared=self.F)
         h = jax.random.normal(jax.random.PRNGKey(5), (96, self.D))
-        moe = ds.MoE(self.E, tuple(range(self.E)), self.K, self.F, 1, 2.5,
-                     True, jnp.float32, scoring=scoring)
-        y, rows = moe.apply({"params": full}, h)
+        layer = moe.MoE(self.E, tuple(range(self.E)), self.K, self.F, 1, 2.5,
+                        True, jnp.float32, scoring=scoring)
+        y, rows = layer.apply({"params": full}, h)
         spec = {"num_experts_per_tok": self.K,
                 "moe_routed_scaling_factor": 2.5,
                 "held_experts": range(self.E)}
@@ -390,24 +390,24 @@ class TestSigmoidRouting:
         x = jax.random.normal(jax.random.PRNGKey(6), (96, self.D))
         held = (1, 2, 5, 6)
         share = share_of(full, held)
-        default = ds.MoE(self.E, held, self.K, self.F, 0, 1.0, True,
-                         jnp.float32)
+        default = moe.MoE(self.E, held, self.K, self.F, 0, 1.0, True,
+                          jnp.float32)
         assert default.scoring == "softmax"
         y, rows = default.apply({"params": share}, x)
         scores = jax.nn.softmax(
-            jnp.dot(x, full["kernel"], precision=ds.HIGHEST), axis=-1)
+            jnp.dot(x, full["kernel"], precision=layers.HIGHEST), axis=-1)
         top_w, top_i = jax.lax.top_k(scores, self.K)
         top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20) * 1.0
         hit = top_i[..., None] == jnp.asarray(held, jnp.int32)
-        want, counts = ds.routed_experts(
+        want, counts = moe.routed_experts(
             x, jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1),
             jnp.any(hit, axis=1), *(share[n]["experts"] for n in (
                 "routed_gate", "routed_up", "routed_down")),
-            ds.expert_capacity(96, len(held), self.K, self.E), self.K)
+            moe.expert_capacity(96, len(held), self.K, self.E), self.K)
         assert np.array_equal(y, want) and np.array_equal(rows, counts)
         with pytest.raises(KeyError):
-            ds.MoE(self.E, held, self.K, self.F, 0, 1.0, True, jnp.float32,
-                   scoring="tanh").apply({"params": share}, x)
+            moe.MoE(self.E, held, self.K, self.F, 0, 1.0, True, jnp.float32,
+                    scoring="tanh").apply({"params": share}, x)
 
 
 class TestShare:
@@ -436,10 +436,10 @@ class TestShare:
         total, rows = mid + shared, 0
         for chip in range(32):
             held = (2 * chip, 2 * chip + 1)
-            moe = ds.MoE(e, held, cfg.num_experts_per_tok, f, 1,
-                         cfg.moe_routed_scaling_factor, True, jnp.float32,
-                         scoring="sigmoid")
-            y, counts = moe.apply({"params": share_of(p["moe"], held)}, h2)
+            layer = moe.MoE(e, held, cfg.num_experts_per_tok, f, 1,
+                            cfg.moe_routed_scaling_factor, True, jnp.float32,
+                            scoring="sigmoid")
+            y, counts = layer.apply({"params": share_of(p["moe"], held)}, h2)
             total = total + (y - shared)
             rows += int(counts.sum())
             if chip == 0:   # ... and the layer is x' + that module's output
@@ -464,7 +464,7 @@ class TestShare:
 
     def test_capacity_of_the_cells_share(self):
         # 16,384 tokens, 8 of 256 a token, 8 held: 4,096 pairs on average
-        assert ds.expert_capacity(16384, 8, 8, 256) == 6144
+        assert moe.expert_capacity(16384, 8, 8, 256) == 6144
 
 
 def count(tree):

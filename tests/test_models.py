@@ -1,11 +1,14 @@
 """Model zoo shape/param tests (the reference has none — SURVEY.md §4)."""
 
+import ast
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oktopk_tpu.models import create_model
+from oktopk_tpu.models import create_model, registry
 from oktopk_tpu.models.bert import BertConfig, BertForPreTraining
 from oktopk_tpu.models.deepspeech import DeepSpeech
 from oktopk_tpu.models.lstm import PTBLSTM
@@ -13,6 +16,43 @@ from oktopk_tpu.models.lstm import PTBLSTM
 
 def nparams(params):
     return sum(x.size for x in jax.tree.leaves(params))
+
+
+# the decoder models, one file a model, and what they are built from: the
+# arrows point one way (ops/ <- shared parts <- a model <- registry.py)
+DECODERS = ("deepseek_v2", "qwen3_next", "smallthinker", "laguna", "ouro")
+SHARED = ("attention", "moe", "layers")
+
+
+def imported_from_models(name):
+    """The modules of ``oktopk_tpu.models`` that ``models/<name>.py``
+    imports, anywhere in the file."""
+    path = pathlib.Path(registry.__file__).parent / f"{name}.py"
+    found = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, f"{name}: a relative import"
+            names = ([f"{node.module}.{a.name}" for a in node.names]
+                     if node.module == "oktopk_tpu.models"
+                     else [node.module])
+        else:
+            continue
+        for n in names:
+            if n.startswith("oktopk_tpu.models"):
+                found.add(n.split(".")[2] if n.count(".") > 1 else "")
+    return found
+
+
+@pytest.mark.parametrize("name", DECODERS + SHARED)
+def test_no_decoder_model_is_built_out_of_another(name):
+    """A model file imports the three shared modules only; a shared module
+    imports no model file (nor the package, whose ``__init__`` imports them
+    all through the registry)."""
+    found = imported_from_models(name)
+    assert "attention" in found or name in SHARED   # the parse finds them
+    assert found <= set(SHARED) - {name}, found
 
 
 class TestConvNets:

@@ -30,9 +30,9 @@ from benchlib import discover, kernels_lm, kernels_loop, weights  # noqa: E402
 
 from oktopk_tpu.collectives.state import COUNTERS, MODEL_COUNTERS  # noqa: E402
 from oktopk_tpu.config import TrainConfig  # noqa: E402
+from oktopk_tpu.models import attention, layers  # noqa: E402
 from oktopk_tpu.models import create_model  # noqa: E402
 from oktopk_tpu.models import ouro  # noqa: E402
-from oktopk_tpu.models import qwen3_next as qn  # noqa: E402
 from oktopk_tpu.models.registry import TOKEN_LMS  # noqa: E402
 from oktopk_tpu.obs import anatomy  # noqa: E402
 from oktopk_tpu.train import losses  # noqa: E402
@@ -336,13 +336,13 @@ class _NoSandwich(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        norm = lambda name: ouro.RMSNorm(c.rms_norm_eps, c.dtype, name=name)
+        norm = lambda name: layers.RMSNorm(c.rms_norm_eps, c.dtype, name=name)
         a = ouro.Attention(c.num_attention_heads, c.num_key_value_heads,
                            c.head_dim, c.rope_theta, c.attn_block, c.dtype,
                            name="attn")(norm("attn_norm")(x))
         norm("attn_out_norm")(a)
         x = x + a
-        m = ouro.SwiGLU(c.intermediate_size, c.dtype, True, name="ffn")(
+        m = layers.SwiGLU(c.intermediate_size, c.dtype, True, name="ffn")(
             norm("ffn_norm")(x))
         norm("ffn_out_norm")(m)
         return x + m
@@ -404,10 +404,10 @@ class TestBrokenPathsAreCaught:
 
 class TestAttentionAtAGroupOfOneHead:
     def test_rotary_turns_all_of_a_heads_dims(self):
-        cos, sin = ouro.rotary_table(ouro.Rope(1e4), 32, 64)
+        cos, sin = attention.rotary_table(attention.Rope(1e4), 32, 64)
         assert cos.shape == (64, 16)
         x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 4, 32))
-        y = qn.rotate_half_partial(x, cos, sin)
+        y = attention.rotate_half_partial(x, cos, sin)
         np.testing.assert_allclose(y[0], REF._rotary(x[0], 1e4), rtol=1e-5,
                                    atol=1e-6)
         assert float(jnp.abs(y[0, 1:] - x[0, 1:]).min(axis=(0, 1)).max()) > 0
@@ -418,11 +418,17 @@ class TestAttentionAtAGroupOfOneHead:
         q, k, v, w = (jax.random.normal(key, (2, 64, 4, 32)) for key in ks)
 
         def through(q, k, v):
-            return jnp.sum(qn.blocked_causal_gqa(q, k, v, 32 ** -0.5, 16)
-                           * w)
+            return jnp.sum(attention.blocked_causal_gqa(
+                q, k, v, 32 ** -0.5, 16) * w)
         want = jax.jit(jax.value_and_grad(through, (0, 1, 2)))(q, k, v)
-        plain = jax.vmap(lambda q, k, v: qn._blocked_xla(
-            q[None], k[None], v[None], 32 ** -0.5, 16, None)[0])(q, k, v)
+
+        def attend(start, end):
+            return lambda *seq: attention._attend_block_gqa(
+                *seq, start, end, 32 ** -0.5)
+        # the walk over a sequence's blocks, a group of one head spelled out
+        plain = jax.vmap(lambda q, k, v: attention._blocked_xla(
+            attend, (q.reshape(64, 4, 1, 32),), (k, v), 16).reshape(
+                64, 4, 32))(q, k, v)
         np.testing.assert_allclose(jnp.sum(plain * w), want[0], rtol=1e-5)
         monkeypatch.setenv("OKTOPK_PALLAS_INTERPRET", "1")
         got = jax.jit(jax.value_and_grad(through, (0, 1, 2)))(q, k, v)

@@ -26,8 +26,8 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from benchlib import discover, kernels_gdn, kernels_lm, weights  # noqa: E402
 
 from oktopk_tpu.config import TrainConfig  # noqa: E402
+from oktopk_tpu.models import attention, layers, moe  # noqa: E402
 from oktopk_tpu.models import create_model  # noqa: E402
-from oktopk_tpu.models import deepseek_v2 as ds  # noqa: E402
 from oktopk_tpu.models import qwen3_next as qn  # noqa: E402
 from oktopk_tpu.models.registry import TOKEN_LMS  # noqa: E402
 from oktopk_tpu.obs import anatomy  # noqa: E402
@@ -231,7 +231,7 @@ class TestDeltaRule:
         inv = qn.inv_unit_lower(low)
         eye = jnp.eye(c)
         np.testing.assert_allclose(
-            jnp.matmul(inv, eye + low, precision=ds.HIGHEST),
+            jnp.matmul(inv, eye + low, precision=layers.HIGHEST),
             jnp.broadcast_to(eye, low.shape), atol=2e-5)
 
     def test_causal_convolution_is_left_padded(self):
@@ -270,17 +270,17 @@ class TestShare:
         uncut = REF.experts(full, h.reshape(-1, d),
                             spec_of(cfg, held=range(e)))
         x = h.reshape(-1, d)
-        total = ds.swiglu(x, *(full["shared_ffn"][n]["kernel"] for n in (
+        total = layers.swiglu(x, *(full["shared_ffn"][n]["kernel"] for n in (
             "gate_proj", "up_proj", "down_proj"))) * jax.nn.sigmoid(
                 x @ full["shared_gate"]["kernel"])
         rows = 0
         for chip in range(e):
-            moe = ds.MoE(e, (chip,), cfg.num_experts_per_tok, f, 0, 1.0,
-                         True, jnp.float32)
+            layer = moe.MoE(e, (chip,), cfg.num_experts_per_tok, f, 0, 1.0,
+                            True, jnp.float32)
             share = {k: ({"experts": v["experts"][chip:chip + 1]}
                          if k.startswith("routed") else v)
                      for k, v in full.items() if not k.startswith("shared")}
-            y, counts = moe.apply({"params": share}, h)
+            y, counts = layer.apply({"params": share}, h)
             total = total + y.reshape(-1, d)
             rows += int(counts.sum())
         assert rows == 2 * 48 * cfg.num_experts_per_tok  # every pair, once
@@ -303,20 +303,20 @@ class TestShare:
         assert float(jnp.max(held)) < 1.0 and float(jnp.min(held)) >= 0.0
         # ... and the program's layer weighs its held experts by them
         full = moe_params(d, 8, e)
-        moe = ds.MoE(e, (0, 1, 2, 3), k, 8, 0, 1.0, True, jnp.float32)
+        layer = moe.MoE(e, (0, 1, 2, 3), k, 8, 0, 1.0, True, jnp.float32)
         share = {n: ({"experts": v["experts"][:4]} if n.startswith("routed")
                      else v) for n, v in full.items()
                  if not n.startswith("shared")}
-        y, _ = moe.apply({"params": share}, h)
-        want = sum(ds.swiglu(h, full["routed_gate"]["experts"][i],
-                             full["routed_up"]["experts"][i],
-                             full["routed_down"]["experts"][i])
+        y, _ = layer.apply({"params": share}, h)
+        want = sum(layers.swiglu(h, full["routed_gate"]["experts"][i],
+                                 full["routed_up"]["experts"][i],
+                                 full["routed_down"]["experts"][i])
                    * w[:, i:i + 1] for i in range(4))
         np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-6)
 
     def test_capacity_of_the_cells_share(self):
         # 16,384 tokens, 10 of 512 a token, 16 held: 5,120 pairs on average
-        assert ds.expert_capacity(16384, 16, 10, 512) == 7680
+        assert moe.expert_capacity(16384, 16, 10, 512) == 7680
 
 
 class TestAttentionAndRotary:
@@ -325,7 +325,7 @@ class TestAttentionAndRotary:
         x = jax.random.normal(jax.random.PRNGKey(2), (t, 3, hd))
         freq = 1.0 / 1e7 ** (jnp.arange(0, rot, 2) / rot)
         ang = jnp.arange(t)[:, None] * freq
-        got = qn.rotate_half_partial(x, jnp.cos(ang), jnp.sin(ang))
+        got = attention.rotate_half_partial(x, jnp.cos(ang), jnp.sin(ang))
         # dims 64-255 pass untouched
         assert np.array_equal(np.asarray(got[..., rot:]),
                               np.asarray(x[..., rot:]))
@@ -333,7 +333,8 @@ class TestAttentionAndRotary:
         z = (x[..., :32] + 1j * x[..., 32:64]) * jnp.exp(1j * ang)[:, None]
         np.testing.assert_allclose(got[..., :32], z.real, atol=1e-5)
         np.testing.assert_allclose(got[..., 32:64], z.imag, atol=1e-5)
-        pairs = ds.rotate_pairs(x[..., :rot], jnp.cos(ang), jnp.sin(ang))
+        pairs = attention.rotate_pairs(x[..., :rot], jnp.cos(ang),
+                                       jnp.sin(ang))
         assert float(jnp.max(jnp.abs(pairs - got[..., :rot]))) > 0.1
         # ... as the reference's own rotary does
         spec = {"partial_rotary_factor": 0.25, "rope_theta": 1e7}
@@ -347,7 +348,7 @@ class TestAttentionAndRotary:
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
         q = jax.random.normal(ks[0], (b, t, h, d))
         k, v = (jax.random.normal(x, (b, t, g, d)) for x in ks[1:])
-        got = qn.blocked_causal_gqa(q, k, v, 0.3, block)
+        got = attention.blocked_causal_gqa(q, k, v, 0.3, block)
         kk, vv = (jnp.repeat(x, h // g, axis=2) for x in (k, v))
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.3
         s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
@@ -563,12 +564,12 @@ class TestWindowArgumentAtItsDefault:
         q = jax.random.normal(kq, (2, 64, 4, 32))
         k = jax.random.normal(kk, (2, 64, 2, 32))
         v = jax.random.normal(kv, (2, 64, 2, 32))
-        plain = jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+        plain = jax.make_jaxpr(lambda: attention.blocked_causal_gqa(
             q, k, v, 0.2, 16))()
         for window in (None, 64, 1000):
-            assert str(jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+            assert str(jax.make_jaxpr(lambda: attention.blocked_causal_gqa(
                 q, k, v, 0.2, 16, window))()) == str(plain)
-        assert str(jax.make_jaxpr(lambda: qn.blocked_causal_gqa(
+        assert str(jax.make_jaxpr(lambda: attention.blocked_causal_gqa(
             q, k, v, 0.2, 16, 63))()) != str(plain)
 
 

@@ -28,9 +28,8 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from benchlib import discover, kernels_lm, kernels_swa, weights  # noqa: E402
 
 from oktopk_tpu.config import TrainConfig  # noqa: E402
+from oktopk_tpu.models import attention, layers, moe  # noqa: E402
 from oktopk_tpu.models import create_model  # noqa: E402
-from oktopk_tpu.models import deepseek_v2 as ds  # noqa: E402
-from oktopk_tpu.models import qwen3_next as qn  # noqa: E402
 from oktopk_tpu.models import smallthinker as st  # noqa: E402
 from oktopk_tpu.models.registry import TOKEN_LMS  # noqa: E402
 from oktopk_tpu.obs import anatomy  # noqa: E402
@@ -211,7 +210,7 @@ class TestWindowedAttention:
         (64, 16), (100, 16), (24, 64), (None, 16)])
     def test_blocked_window_is_the_masked_full_scores(self, window, block):
         q, k, v = qkv()
-        got = qn.blocked_causal_gqa(q, k, v, 32 ** -0.5, block, window)
+        got = attention.blocked_causal_gqa(q, k, v, 32 ** -0.5, block, window)
         want = masked_attention(q, k, v, 32 ** -0.5, window)
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
@@ -219,7 +218,7 @@ class TestWindowedAttention:
     def test_its_gradients_too(self, window, block):
         q, k, v = qkv()
         w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
-        got = jax.grad(lambda *a: jnp.sum(w * qn.blocked_causal_gqa(
+        got = jax.grad(lambda *a: jnp.sum(w * attention.blocked_causal_gqa(
             *a, 32 ** -0.5, block, window)), argnums=(0, 1, 2))(q, k, v)
         want = jax.grad(lambda *a: jnp.sum(w * masked_attention(
             *a, 32 ** -0.5, window)), argnums=(0, 1, 2))(q, k, v)
@@ -228,7 +227,7 @@ class TestWindowedAttention:
 
     def test_a_window_ignored_is_caught(self):
         q, k, v = qkv()
-        got = qn.blocked_causal_gqa(q, k, v, 32 ** -0.5, 16, None)
+        got = attention.blocked_causal_gqa(q, k, v, 32 ** -0.5, 16, None)
         want = masked_attention(q, k, v, 32 ** -0.5, 24)
         assert float(jnp.max(jnp.abs(got - want)[:, 24:])) > 1e-2
         np.testing.assert_allclose(got[:, :24], want[:, :24], rtol=2e-5,
@@ -240,7 +239,7 @@ class TestWindowedAttention:
         q, k, v = qkv()
         k = k.at[:, :9].set(jnp.nan)
         v = v.at[:, :9].set(jnp.nan)
-        got = qn.blocked_causal_gqa(q, k, v, 32 ** -0.5, 16, 24)
+        got = attention.blocked_causal_gqa(q, k, v, 32 ** -0.5, 16, 24)
         assert bool(jnp.all(jnp.isfinite(got[:, 32:])))
         assert not bool(jnp.any(jnp.isfinite(got[:, :9])))
 
@@ -320,14 +319,14 @@ class TestRouterAndGate:
         every token's experts as they were, another ``h`` does not."""
         d, f, e, k = 128, 64, 16, 4
         full = moe_params(d, f, e)
-        moe = ds.MoE(e, tuple(range(e)), k, f, 0, 1.0, True, jnp.float32,
-                     hidden_act="relu")
+        layer = moe.MoE(e, tuple(range(e)), k, f, 0, 1.0, True, jnp.float32,
+                        hidden_act="relu")
         h, h2, h3 = (jax.random.normal(jax.random.PRNGKey(s), (96, d))
                      for s in (1, 2, 3))
-        y, rows = moe.apply({"params": full}, h2, router_input=h)
-        _, same = moe.apply({"params": full}, h3, router_input=h)
-        _, other = moe.apply({"params": full}, h2, router_input=h3)
-        _, own = moe.apply({"params": full}, h2)
+        y, rows = layer.apply({"params": full}, h2, router_input=h)
+        _, same = layer.apply({"params": full}, h3, router_input=h)
+        _, other = layer.apply({"params": full}, h2, router_input=h3)
+        _, own = layer.apply({"params": full}, h2)
         assert np.array_equal(rows, same)
         assert not np.array_equal(rows, other)
         assert not np.array_equal(rows, own)
@@ -358,7 +357,7 @@ class TestRouterAndGate:
         assert np.array_equal(np.asarray(jnp.sum(w > 0, axis=1)),
                               np.full(96, k))
         np.testing.assert_allclose(jnp.sum(w, axis=1), 1.0, rtol=1e-6)
-        scores = jax.nn.softmax(jnp.dot(h, w_r, precision=ds.HIGHEST), -1)
+        scores = jax.nn.softmax(jnp.dot(h, w_r, precision=layers.HIGHEST), -1)
         top = jnp.where(w > 0, scores, 0.0)
         np.testing.assert_allclose(
             w, top / jnp.sum(top, axis=1, keepdims=True), rtol=1e-5)
@@ -368,9 +367,9 @@ class TestRouterAndGate:
         d, f, e, k = 128, 64, 16, 4
         full = moe_params(d, f, e)
         h = jax.random.normal(jax.random.PRNGKey(5), (96, d))
-        moe = ds.MoE(e, tuple(range(e)), k, f, 0, 1.0, True, jnp.float32,
-                     hidden_act=act)
-        y, _ = moe.apply({"params": full}, h)
+        layer = moe.MoE(e, tuple(range(e)), k, f, 0, 1.0, True, jnp.float32,
+                        hidden_act=act)
+        y, _ = layer.apply({"params": full}, h)
         w = REF.routing(h, full["kernel"], {
             "moe_num_active_primary_experts": k})
         relu = REF.experts(full, h, w, {"held_experts": range(e)})
@@ -391,11 +390,11 @@ class TestRouterAndGate:
         wts = jnp.sum(jnp.where(hit, top_w[..., None], 0.0), axis=1)
         stacks = [full[n]["experts"] for n in (
             "routed_gate", "routed_up", "routed_down")]
-        fn = ds.ACTIVATIONS[act]
-        grouped, _ = ds.routed_experts(x, wts, routed, *stacks, 128, k, fn)
-        rows, _ = ds.routed_experts(x, wts, routed, *stacks, 64, k, fn)
+        fn = layers.ACTIVATIONS[act]
+        grouped, _ = moe.routed_experts(x, wts, routed, *stacks, 128, k, fn)
+        rows, _ = moe.routed_experts(x, wts, routed, *stacks, 64, k, fn)
         np.testing.assert_allclose(grouped, rows, rtol=1e-4, atol=1e-5)
-        want = sum(ds.swiglu(x, *(s[i] for s in stacks), fn)
+        want = sum(layers.swiglu(x, *(s[i] for s in stacks), fn)
                    * wts[:, i:i + 1] for i in range(e))
         np.testing.assert_allclose(grouped, want, rtol=1e-4, atol=1e-5)
 
@@ -437,7 +436,7 @@ class TestShare:
 
     def test_capacity_of_the_cells_share(self):
         # 16,384 tokens, 6 of 64 a token, 8 held: 12,288 pairs on average
-        assert ds.expert_capacity(16384, 8, 6, 64) == 18432
+        assert moe.expert_capacity(16384, 8, 6, 64) == 18432
 
 
 class TestRegistryAndScopes:
