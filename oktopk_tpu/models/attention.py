@@ -3,7 +3,7 @@ tables. No model file is imported here. Two entry points, one a head layout:
 
 * :func:`blocked_causal_gqa`, grouped heads (``models/qwen3_next.py``'s
   gated attention, ``models/smallthinker.py`` and ``models/laguna.py`` with
-  a window in some layers, ``models/ouro.py`` at a group of one head);
+  a window, ``models/ouro.py`` at a group of one, ``models/lfm2.py``);
 * :func:`blocked_causal_attention`, MLA's split heads
   (``models/deepseek_v2.py``): a score is the sum of two products, and the
   rotary part's key is one head shared by all.

@@ -1,8 +1,10 @@
 """What the decoder models, their expert layer (``models/moe.py``) and
 their attention (``models/attention.py``) share and that is neither: the
 plain-gain RMSNorm, a bias-free projection's kernel for whoever applies it
-as a plain function, and the gated feed-forward block. No model file is
-imported here.
+as a plain function, the gated feed-forward block and the depthwise causal
+convolution (``models/qwen3_next.py``'s delta-rule inputs,
+``models/lfm2.py``'s short-convolution mixer). No model file is imported
+here.
 
 Flax names a parameter by the attribute and class names on its path, not by
 the Python module a class lives in: ``RMSNorm`` is ``RMSNorm`` in every
@@ -43,6 +45,14 @@ class Kernel(nn.Module):
     def __call__(self, fan_in: int):
         return self.param("kernel", nn.initializers.lecun_normal(),
                           (fan_in, self.features))
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution, left-padded, no bias: ``y_t = sum_j
+    w[j] x[t - (K - 1) + j]``. x [T, C]; w [K, C]."""
+    taps, t = w.shape[0], x.shape[0]
+    padded = jnp.pad(x, ((taps - 1, 0), (0, 0)))
+    return sum(padded[j:j + t] * w[j] for j in range(taps))
 
 
 # a gated expert's activation, by the published ``hidden_act``

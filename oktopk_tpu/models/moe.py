@@ -1,16 +1,21 @@
 """The routed-expert layer of the decoder models (:class:`MoE`), told which
-experts this chip holds. Four models build on it, each with its own
+experts this chip holds. Five models build on it, each with its own
 settings: ``models/deepseek_v2.py`` (softmax scores, two ungated shared
 experts), ``models/qwen3_next.py`` (``shared_gate``),
 ``models/smallthinker.py`` (``router_input``, ``hidden_act="relu"``, no
-shared expert) and ``models/laguna.py`` (``scoring="sigmoid"``). No model
-file is imported here.
+shared expert), ``models/laguna.py`` (``scoring="sigmoid"``) and
+``models/lfm2.py`` (sigmoid scores under a selection bias,
+``expert_bias``, and the published block's ``norm_eps``). No model file is
+imported here.
 
 **Routing**: ``s = softmax(h W_r)`` over ALL ``n_routed_experts`` in float32
 at ``highest`` precision (``scoring`` ``"sigmoid"``: each expert's own
 sigmoid), greedy top-k, weights unrenormalised unless ``norm_topk_prob``
-(then over the k, held or not); the shared experts sit behind a sigmoid
-gate where ``shared_gate``. ``held_experts`` says which experts this chip
+(then over the k, held or not, their sum plus ``norm_eps``); where
+``expert_bias``, the k are the largest of ``s + b`` and their weights
+still the unbiased ``s`` (``b`` a ``bias`` leaf that only chooses: no
+gradient reaches it); the shared experts sit behind a sigmoid gate where
+``shared_gate``. ``held_experts`` says which experts this chip
 holds (expert parallelism: the others live on other chips); the layer
 computes ``sum_{e in topk, e held} s_e E_e(h)`` plus the shared experts,
 and what the absent experts would add is left out: no code stands in for
@@ -29,8 +34,9 @@ experts' products run twice (nothing in a recomputed decoder layer's
 backward pass needs their output, so the layer's own recomputation of them
 is dead code).
 
-Parameter leaves are ``kernel`` (the router's, the shared experts') and
-``experts`` (a stack of kernels, expert axis first). The scopes ``router``,
+Parameter leaves are ``kernel`` (the router's, the shared experts'),
+``bias`` (the router's selection bias, where it has one) and ``experts``
+(a stack of kernels, expert axis first). The scopes ``router``,
 ``experts`` and ``shared`` are what readers of a trace look for
 (``obs/anatomy.SUB_SCOPES["fwd_bwd"]``).
 """
@@ -147,6 +153,17 @@ SCORINGS = {"softmax": partial(jax.nn.softmax, axis=-1),
             "sigmoid": jax.nn.sigmoid}
 
 
+def choose(scores, k: int, bias=None):
+    """(a token's ``k`` chosen experts' scores, their ids) from ``scores``
+    [T, experts]: the k largest, or, under a selection ``bias`` [experts],
+    the k largest of ``scores + bias`` with their UNBIASED scores (the bias
+    chooses and no more: no gradient reaches it)."""
+    if bias is None:
+        return lax.top_k(scores, k)
+    _, top_i = lax.top_k(scores + lax.stop_gradient(bias), k)
+    return jnp.take_along_axis(scores, top_i, axis=-1), top_i
+
+
 class ExpertStack(nn.Module):
     """One projection of every held expert: ``experts`` [held, in, out]."""
     held: int
@@ -175,6 +192,13 @@ class MoE(nn.Module):
     hidden_act: str = "silu"
     # what turns the router's logits into scores, a key of SCORINGS
     scoring: str = "softmax"
+    # a selection bias: the k are the largest of scores + ``bias`` [experts]
+    # (a buffer in the published block, zeros at initialisation, moved by
+    # a balancing rule outside the gradient), the weights the chosen's
+    # unbiased scores
+    expert_bias: bool = False
+    # what the renormalised weights' sum is added to
+    norm_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, h, router_input=None):
@@ -191,9 +215,13 @@ class MoE(nn.Module):
             r = x if router_input is None else router_input.reshape(-1, d)
             scores = SCORINGS[self.scoring](
                 jnp.dot(r.astype(jnp.float32), w_r, precision=HIGHEST))
-            top_w, top_i = lax.top_k(scores, k)
+            bias = (self.param("bias", nn.initializers.zeros,
+                               (self.n_routed_experts,))
+                    if self.expert_bias else None)
+            top_w, top_i = choose(scores, k, bias)
             if self.norm_topk_prob:
-                top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)
+                top_w = top_w / (jnp.sum(top_w, -1, keepdims=True)
+                                 + self.norm_eps)
             top_w = top_w * self.routed_scaling_factor
             # [T, k, H] -> this chip's experts only
             hit = top_i[..., None] == jnp.asarray(self.held_experts,

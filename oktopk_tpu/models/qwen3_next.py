@@ -80,7 +80,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from oktopk_tpu.models.attention import (ATTN_OUT, blocked_causal_gqa,
                                          rotate_half_partial)
-from oktopk_tpu.models.layers import HIGHEST, Kernel
+from oktopk_tpu.models.layers import HIGHEST, Kernel, causal_conv
 from oktopk_tpu.models.moe import MoE, held_ids
 from oktopk_tpu.obs.anatomy import phase_scope
 from oktopk_tpu.ops import delta_rule
@@ -281,14 +281,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int, segment: int):
     _, o = lax.scan(one_segment, jnp.zeros((b, hv, dk, dv), jnp.float32),
                     tuple(cut(x) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(o, 0, 1).reshape(b, whole, hv, dv)[:, :t]
-
-
-def causal_conv(x, w):
-    """Depthwise causal convolution, left-padded, no bias: ``y_t = sum_j
-    w[j] x[t - (K - 1) + j]``. x [T, C]; w [K, C]."""
-    taps, t = w.shape[0], x.shape[0]
-    padded = jnp.pad(x, ((taps - 1, 0), (0, 0)))
-    return sum(padded[j:j + t] * w[j] for j in range(taps))
 
 
 def _l2norm(x):

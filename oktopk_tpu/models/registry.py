@@ -12,6 +12,7 @@ from oktopk_tpu.models.alexnet import AlexNet
 from oktopk_tpu.models.caffe_cifar import CaffeCifar
 from oktopk_tpu.models.densenet import DenseNet
 from oktopk_tpu.models.laguna import Laguna, LagunaConfig
+from oktopk_tpu.models.lfm2 import Lfm2, Lfm2Config
 from oktopk_tpu.models.ouro import Ouro, OuroConfig
 from oktopk_tpu.models.preresnet import PreResNet
 from oktopk_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
@@ -53,6 +54,8 @@ TOKEN_LMS: Dict[str, Tuple[int, int]] = {
     "laguna_tiny": (64, 512),
     "ouro_2_6b": (4096, 49152),
     "ouro_tiny": (64, 512),
+    "lfm2_24b_a2b": (8192, 65536),
+    "lfm2_tiny": (64, 512),
 }
 
 
@@ -119,6 +122,13 @@ MODELS: Dict[str, Callable[..., Tuple[Any, Callable]]] = {
         Ouro(OuroConfig(**kw)), _tokens(64, 49152)),
     "ouro_tiny": lambda **kw: (
         Ouro(OuroConfig.tiny(**kw)), _tokens(*TOKEN_LMS["ouro_tiny"])),
+    # LFM2-24B-A2B at its published config.json (short-convolution and
+    # attention layers, routed experts under a selection bias, a tied
+    # head); a chip's share comes as model_kwargs, as for deepseek_v2_lite.
+    "lfm2_24b_a2b": lambda **kw: (
+        Lfm2(Lfm2Config(**kw)), _tokens(64, 65536)),
+    "lfm2_tiny": lambda **kw: (
+        Lfm2(Lfm2Config.tiny(**kw)), _tokens(*TOKEN_LMS["lfm2_tiny"])),
     "lstman4": lambda **kw: (DeepSpeech(**kw),
                              lambda bs: jnp.zeros((bs, 161, 201, 1),
                                                   jnp.float32)),

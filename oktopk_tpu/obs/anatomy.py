@@ -96,12 +96,16 @@ SUB_FEEDBACK = "feedback"        # select: controller feedback
 # ``window_attention``, whichever the layer is. ``exit_gate`` is a looped
 # model's alone (models/ouro.py): the exit gate's product inside a block of
 # the head, and the exits' distribution, mixing and entropy after the loop.
+# ``short_conv`` is models/lfm2.py's convolution mixer (its two products,
+# gates and taps; the norm before it excluded) and ``gated_conv`` inside it
+# the elementwise part alone (``B * z``, the taps, ``C *``).
 SUB_SCOPES = {
     "select": (SUB_THRESHOLD, SUB_SWEEP, SUB_GLOBAL, SUB_FEEDBACK),
     "stage": (SUB_REPARTITION, SUB_FINALIZE),
     "fwd_bwd": ("attention", "router", "experts", "shared", "mlp", "head",
                 "linear_attention", "delta_rule", "window_attention",
-                "window_scores", "full_scores", "attn_gate", "exit_gate"),
+                "window_scores", "full_scores", "attn_gate", "exit_gate",
+                "short_conv", "gated_conv"),
 }
 
 # phases whose time is wire time; everything else in the contract is

@@ -204,13 +204,13 @@ def _record(kernel: bool, own: int, t: int, window: Optional[int], tq: int,
 def on_this_platform(t: int, h: int, g: int, d: int, window: Optional[int],
                      block: int) -> bool:
     """Whether the kernels run a call of ``h`` query heads grouped over
-    ``g`` key-value heads here (:func:`_kernels_here`: a head is whole lane
+    ``g`` key-value heads here (:func:`_kernels_here`: a PACK is whole lane
     rows, a tile of k being [tk, own x d] of [T, G x d], which Mosaic cuts
-    by 128 lanes), and the call's record. Otherwise the caller's plain form
-    does, ``block`` queries at a time against ``block``-wide key tiles in
-    the record."""
-    kernel = _kernels_here(d)
+    by 128 lanes; a head of 64 rides two a step, a 64-lane cut of the tile
+    as MLA's rotary part is), and the call's record. Otherwise the caller's
+    plain form does, ``block``-wide blocks and key tiles in the record."""
     own = kv_heads_a_step(h, g, d)
+    kernel = _kernels_here(own * d)
     tq, tk = (tile_rule(t, own * (h // g), d, own=own) if kernel
               else (block, block))
     return _record(kernel, own, t, window, tq, tk, h, g, d)

@@ -399,11 +399,16 @@ def snapshot() -> Dict[str, Any]:
       ``heads_a_step`` and ``chunks_a_block``, the heads and chunks of one
       grid step of the kernels (0: the scan) (static, from shapes); empty
       for a model that has no such layer;
+    - ``short_conv``: every distinct call of the gated short convolution
+      traced in this process (``models/lfm2.ShortConv``): the ``tokens`` of
+      a sequence, its ``channels`` and the convolution's ``taps`` (static,
+      from shapes); empty for a model that has no such layer;
     - ``sub_scopes``: the named steps under the ``select`` and ``stage``
       phase scopes (obs/anatomy.SUB_SCOPES), for whoever reads a trace.
     """
     from oktopk_tpu.collectives.state import BRANCHES, COUNTERS
     from oktopk_tpu.obs.anatomy import SUB_SCOPES
+    from oktopk_tpu.models import lfm2
     from oktopk_tpu.ops import delta_rule, flash_gqa
     from oktopk_tpu.utils.compile_cache import compile_counters
 
@@ -421,6 +426,7 @@ def snapshot() -> Dict[str, Any]:
         "capacities": [c for src in sources for c in src.capacities()],
         "attention": flash_gqa.calls(),
         "delta_rule": delta_rule.calls(),
+        "short_conv": lfm2.short_conv_calls(),
         "sub_scopes": {ph: list(subs) for ph, subs in SUB_SCOPES.items()},
         "step_counters": [{"step": s, "counters": c}
                           for s, c in fetch_counters(pairs)],

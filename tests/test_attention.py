@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oktopk_tpu.models import attention, laguna, qwen3_next, smallthinker
+from oktopk_tpu.models import (attention, laguna, lfm2, qwen3_next,
+                               smallthinker)
 
 
 def _f32(*shape):
@@ -75,12 +76,15 @@ def _tiny(family):
     if family == "laguna":
         cfg = laguna.LagunaConfig.tiny(held_experts=(0, 1, 2, 3))
         return laguna.Laguna(cfg), 5
+    if family == "lfm2":        # one attention layer among four convs
+        cfg = lfm2.Lfm2Config.tiny(held_experts=(0, 1, 2, 3))
+        return lfm2.Lfm2(cfg), 1
     cfg = qwen3_next.Qwen3NextConfig.tiny(held_experts=(0, 1, 2, 3))
     return qwen3_next.Qwen3Next(cfg), 1
 
 
 class TestModelsThroughTheKernels:
-    @pytest.fixture(params=["smallthinker", "qwen3_next", "laguna"])
+    @pytest.fixture(params=["smallthinker", "qwen3_next", "laguna", "lfm2"])
     def job(self, request):
         model, layers = _tiny(request.param)
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 512)

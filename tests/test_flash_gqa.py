@@ -57,6 +57,9 @@ NARROW = {
 # small groups, whose key-value heads ride a grid step several at a time:
 # (R, G) -> the pack that ``kv_heads_a_step`` gives at d = 128
 PACKS = {(1, 16): 8, (1, 3): 3, (2, 4): 4, (3, 4): 2, (1, 9): 3}
+# ... and at d = 64, where only a pack is whole lane rows: lfm2's groups of
+# four over eight key-value heads, groups of two and of one
+NARROW_PACKS = {(4, 8): 2, (2, 4): 4, (1, 16): 8, (1, 6): 6}
 
 
 def through(fn, q, k, v, w):
@@ -103,6 +106,28 @@ class TestKernelsAgainstTheBlockFunction:
         got = through(lambda q, k, v: flash_gqa.flash_gqa(
             q, k, v, 0.09, window, interpret=True, tiles=tiles), q, k, v, w)
         want = through(lambda q, k, v: oracle(q, k, v, 0.09, window),
+                       q, k, v, w)
+        for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("mask", ["causal", "window_under_t",
+                                      "t_no_whole_tiles"])
+    @pytest.mark.parametrize("r,g", list(NARROW_PACKS))
+    def test_a_64_wide_head_rides_in_packs_of_whole_lane_rows(self, r, g,
+                                                              mask):
+        """A head narrower than a lane row (models/lfm2.py: 32 query heads
+        over 8 key-value heads of 64): two and more key-value heads ride a
+        step, their tile [tk, own x 64] whole lane rows and a head a
+        64-lane cut of it, in the slab of q, in the output and in the
+        pack's dk and dv. Forward and the three gradients."""
+        t, tiles, window = MASKS[mask]
+        q, k, v, w = inputs(2, t, r, 64, g=g)
+        own = flash_gqa.kv_heads_a_step(r * g, g, 64)
+        assert own == NARROW_PACKS[r, g] and (own * 64) % 128 == 0
+        got = through(lambda q, k, v: flash_gqa.flash_gqa(
+            q, k, v, 0.125, window, interpret=True, tiles=tiles), q, k, v, w)
+        want = through(lambda q, k, v: oracle(q, k, v, 0.125, window),
                        q, k, v, w)
         for name, a, e in zip(("out", "dq", "dk", "dv"), got, want):
             np.testing.assert_allclose(a, e, rtol=2e-5, atol=2e-5,
@@ -372,6 +397,26 @@ class TestSnapshot:
     def test_empty_without_such_a_layer(self, fresh_calls):
         assert profiling.snapshot()["attention"] == []
 
+    @pytest.mark.parametrize("h,g,d,want", [
+        (32, 8, 64, True),      # lfm2: two key-value heads of 64 a step
+        (16, 16, 64, True), (6, 6, 64, True),
+        (3, 3, 64, False),      # no pack is whole lane rows: the XLA form
+        (8, 1, 64, False), (4, 2, 32, False),
+        (28, 4, 128, True), (16, 2, 256, True), (16, 16, 128, True),
+        (48, 8, 96, False)])
+    def test_compiled_for_a_tpu_the_kernels_want_a_pack_of_whole_lane_rows(
+            self, fresh_calls, monkeypatch, h, g, d, want):
+        """The admission rule: a step's tile of keys [tk, own x d] is whole
+        lane rows. Every head of 128 or 256 is admitted as before (a pack
+        of them is whole rows where one is); a head of 64 where two or more
+        ride a step."""
+        monkeypatch.delenv("OKTOPK_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert flash_gqa.on_this_platform(8192, h, g, d, None, 512) is want
+        call, = profiling.snapshot()["attention"]
+        assert call["kernel"] is want
+        assert (call["kv_heads_a_step"] > 0) is want
+
 
 # ---- compiled for a described v5e (nothing runs) ---------------------------
 
@@ -389,14 +434,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# the benchmark's six call shapes: (B, T, H, G, d, window); the last is a
-# group of ONE head (models/ouro.py: 16 query heads over 16 key-value heads)
+# the benchmark's seven call shapes: (B, T, H, G, d, window); the last but
+# one is a group of ONE head (models/ouro.py: 16 query heads over 16
+# key-value heads), the last a head of 64 (models/lfm2.py)
 CALLS = {"smallthinker_window": (1, 16384, 28, 4, 128, 4096),
          "smallthinker_global": (1, 16384, 28, 4, 128, None),
          "qwen3next_full": (2, 8192, 16, 2, 256, None),
          "laguna_full": (1, 16384, 48, 8, 128, None),
          "laguna_sliding": (1, 16384, 64, 8, 128, 512),
-         "ouro_full": (2, 4096, 16, 16, 128, None)}
+         "ouro_full": (2, 4096, 16, 16, 128, None),
+         "lfm2_full": (2, 8192, 32, 8, 64, None)}
+# ... and the key-value heads that ride a grid step there
+OWN = {"ouro_full": 8, "lfm2_full": 2}
 
 
 @pytest.mark.parametrize("call", list(CALLS))
@@ -404,11 +453,12 @@ def test_key_value_heads_a_step_at_the_benchmarks_calls(call):
     """A group of six, seven or eight query heads fills a grid step: one
     key-value head a step, the program those cells ran before there were
     packs. ouro's group of one head: eight key-value heads a step, two
-    packs a sequence. Either way the rule's tiles, with no halving (inside
+    packs a sequence; lfm2's groups of four at a head of 64: two a step,
+    four packs. Either way the rule's tiles, with no halving (inside
     ``VMEM_PLAN``), and a tile of k whole lane rows."""
     _, t, h, g, d, _ = CALLS[call]
     own = flash_gqa.kv_heads_a_step(h, g, d)
-    assert own == (8 if call == "ouro_full" else 1)
+    assert own == OWN.get(call, 1)
     assert g % own == 0 and (own * d) % flash_gqa.LANES == 0
     assert own == 1 or own * (h // g) <= flash_gqa.HEADS_A_STEP
     assert flash_gqa.tile_rule(t, own * (h // g), d, own=own) == (512, 512)

@@ -20,7 +20,8 @@ def nparams(params):
 
 # the decoder models, one file a model, and what they are built from: the
 # arrows point one way (ops/ <- shared parts <- a model <- registry.py)
-DECODERS = ("deepseek_v2", "qwen3_next", "smallthinker", "laguna", "ouro")
+DECODERS = ("deepseek_v2", "qwen3_next", "smallthinker", "laguna", "ouro",
+            "lfm2")
 SHARED = ("attention", "moe", "layers")
 
 

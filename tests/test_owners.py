@@ -259,6 +259,11 @@ TRAINERS = {
     # a model that lives in a loop's body (one stack run four times)
     "ouro_tiny": (dict(dnn="ouro_tiny", dataset="ptb", batch_size=2,
                        lr=0.05, compressor="dense", grad_clip=1.0), {}),
+    # a sequence mixer that is no attention (short convolutions, three
+    # layers in four) beside one attention layer and routed experts
+    "lfm2_tiny": (dict(dnn="lfm2_tiny", dataset="ptb", batch_size=2,
+                       lr=0.05, compressor="dense", grad_clip=1.0),
+                  {"held_experts": [0, 1, 2, 3]}),
 }
 
 
@@ -324,6 +329,21 @@ class TestCompiledStep:
         assert sum(got[n].how == "none" for n in inside) == 0
         owned = [n for n in inside if got[n].how != "own"]
         assert owned and all(got[n].phase for n in owned)
+
+    def test_a_conv_mixers_instructions_carry_their_sub_scopes(self, built):
+        """``lfm2_tiny``: the short-convolution operator and the gated
+        convolution inside it are named beside the attention layer's, the
+        dense layer's and the experts' scopes, and what the compiler fused
+        across them still gets an owner."""
+        tr, _, _, _, got = built
+        if tr.cfg.dnn != "lfm2_tiny":
+            pytest.skip("the other trainers' models have no conv mixer")
+        mine = [o for o in got.values() if o.phase == "fwd_bwd"]
+        assert {o.sub for o in mine} >= {
+            "short_conv", "gated_conv", "attention", "full_scores", "mlp",
+            "head", "router", "experts"}
+        for sub in ("short_conv", "gated_conv"):
+            assert any(o.sub == sub and o.how == "own" for o in mine), sub
 
 
 # (instruction, start, end) in seconds: a loop that spans two leaves and a

@@ -237,7 +237,7 @@ class TestDeltaRule:
     def test_causal_convolution_is_left_padded(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (10, 3))
         w = jax.random.normal(jax.random.PRNGKey(1), (4, 3))
-        got = qn.causal_conv(x, w)
+        got = layers.causal_conv(x, w)
         for t in range(10):
             want = sum(w[j] * x[t - 3 + j] for j in range(4) if t - 3 + j >= 0)
             np.testing.assert_allclose(got[t], want, rtol=1e-5, atol=1e-6)
